@@ -363,6 +363,8 @@ class TpuShuffleExchangeExec(UnaryTpuExec):
         tm = TaskMetrics.get()
         tm.mesh_exchanges += 1
         tm.mesh_ici_bytes += ici_bytes
+        tm.mesh_out_devices = sorted(set(tm.mesh_out_devices).union(
+            d.id for leaf in out_leaves for d in leaf.devices()))
         self.num_partitions.set(ndev)
         from .. import telemetry
         telemetry.inc("tpu_mesh_exchanges_total")

@@ -27,7 +27,7 @@ from ..compile import sjit
 from ..expr.base import Expression, Vec, bind_references
 from ..expr.hashing import hash_vecs
 from ..expr.predicates import string_equal
-from ..ops.rowops import compact_vecs, gather_vecs
+from ..ops.rowops import compact_vecs, gather_vecs, stable_lexsort
 from ..utils import metrics as M
 from .base import (StaticExpr as _StaticExpr, TpuExec, batch_vecs,
                    device_ctx, vecs_to_batch)
@@ -69,10 +69,14 @@ def _probe_counts(probe: ColumnarBatch, build: ColumnarBatch,
     bvalid = _keys_valid(xp, bkeys) & bmask
 
     ph = hash_vecs(xp, pkeys).astype(np.int64)
-    bh = hash_vecs(xp, bkeys).astype(np.int64)
+    bh32 = hash_vecs(xp, bkeys)
     # exile invalid build rows to a hash bucket no valid probe can hit
-    bh = xp.where(bvalid, bh, np.int64(2 ** 62))
-    order = xp.argsort(bh)
+    bh = xp.where(bvalid, bh32.astype(np.int64), np.int64(2 ** 62))
+    # the order of argsort(bh), from two 32-bit keys (a 64-bit sort costs the
+    # chip's compiler twice as long): valid rows by hash, then the exiled
+    # rows, ties in row order
+    order = stable_lexsort(xp, [(~bvalid).astype(np.int8),
+                                xp.where(bvalid, bh32, 0)])
     bh_sorted = bh[order]
     lo = xp.searchsorted(bh_sorted, ph, side="left")
     hi = xp.searchsorted(bh_sorted, ph, side="right")
@@ -563,7 +567,7 @@ def _nl_expand(probe: ColumnarBatch, bchunk: ColumnarBatch, out_cap: int,
     P, C = probe.capacity, bchunk.capacity
     pi = xp.repeat(xp.arange(P, dtype=np.int32), C)
     bi = xp.tile(xp.arange(C, dtype=np.int32), P)
-    order = xp.argsort(~matched, stable=True)[:out_cap]
+    order = stable_lexsort(xp, [(~matched).astype(np.int8)])[:out_cap]
     n = xp.sum(matched).astype(np.int32)
     left_out = gather_vecs(xp, batch_vecs(probe), pi[order])
     right_out = gather_vecs(xp, batch_vecs(bchunk), bi[order])

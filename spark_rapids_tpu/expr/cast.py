@@ -387,27 +387,23 @@ def _parse_float_device(xp, c: Vec, first, last, any_c, dst):
     for word in (b"infinity", b"inf"):
         is_inf = is_inf | word_eq(word, 0) | word_eq(word, off0)
 
-    # numeric state machine
+    # numeric state machine: one lax.scan step per byte column. The loop
+    # must NOT be unrolled in Python: every state variable feeds several
+    # selects of the next column, and XLA's loop fusion then recomputes
+    # the whole chain once per use — exponential in the width (a 40-byte
+    # column never returned on the CPU backend). A scan materialises the
+    # carry at every step and compiles the body once.
+    import jax
+    from .floatparse import mul10_add
     PH_SIGN, PH_INT, PH_FRAC, PH_ESIGN, PH_EXP = 0, 1, 2, 3, 4
-    phase = xp.full(n, PH_SIGN, np.int8)
-    mhi = xp.zeros(n, np.uint64)      # mantissa, 128-bit exact
-    mlo = xp.zeros(n, np.uint64)
-    msticky = xp.zeros(n, dtype=bool)  # nonzero digit dropped past 38
-    mdigits = xp.zeros(n, np.int32)   # significant digits kept
-    idigits = xp.zeros(n, np.int32)   # integer digits beyond the kept 38
-    fdigits = xp.zeros(n, np.int32)   # fraction digits kept
-    any_digit = xp.zeros(n, dtype=bool)
-    neg = xp.zeros(n, dtype=bool)
-    seen_sign = xp.zeros(n, dtype=bool)
-    seen_esign = xp.zeros(n, dtype=bool)
-    eneg = xp.zeros(n, dtype=bool)
-    eval_ = xp.zeros(n, np.int32)
-    any_edigit = xp.zeros(n, dtype=bool)
-    bad = xp.zeros(n, dtype=bool)
-    rows = xp.arange(n)
-    for j in range(w):
-        ch = lower[:, j]
-        act = inside[:, j]
+    zb = xp.zeros(n, dtype=bool)
+    zi = xp.zeros(n, np.int32)
+    zu = xp.zeros(n, np.uint64)
+
+    def step(st, col):
+        (phase, mhi, mlo, msticky, mdigits, idigits, fdigits, any_digit,
+         neg, seen_sign, seen_esign, eneg, eval_, any_edigit, bad) = st
+        ch, act = col
         d = ch - np.uint8(ord("0"))
         is_digit = (d <= 9) & act  # uint8 wraps negatives above 9
         is_dot = (ch == np.uint8(ord("."))) & act
@@ -430,7 +426,6 @@ def _parse_float_device(xp, c: Vec, first, last, any_c, dst):
         keep = in_mant & ~lead_zero & (mdigits < 38)  # 38 digits fill
         # the 128-bit exact mantissa; further digits fold into the
         # exponent with a sticky bit for correct rounding
-        from .floatparse import mul10_add
         thi, tlo = mul10_add(xp, mhi, mlo, d.astype(np.uint64))
         mhi = xp.where(keep, thi, mhi)
         mlo = xp.where(keep, tlo, mlo)
@@ -457,6 +452,18 @@ def _parse_float_device(xp, c: Vec, first, last, any_c, dst):
         phase = xp.where(is_e & (phase <= PH_FRAC),
                          np.int8(PH_ESIGN), phase)
         phase = xp.where(in_exp, np.int8(PH_EXP), phase)
+        return (phase, mhi, mlo, msticky, mdigits, idigits, fdigits,
+                any_digit, neg, seen_sign, seen_esign, eneg, eval_,
+                any_edigit, bad), None
+
+    # mhi/mlo: mantissa, 128-bit exact; msticky: nonzero digit dropped past
+    # 38; mdigits: significant digits kept; idigits: integer digits beyond
+    # the kept 38; fdigits: fraction digits kept
+    init = (xp.full(n, PH_SIGN, np.int8), zu, zu, zb, zi, zi, zi, zb,
+            zb, zb, zb, zb, zi, zb, zb)
+    (phase, mhi, mlo, msticky, _, idigits, fdigits, any_digit, neg, _, _,
+     eneg, eval_, any_edigit, bad), _ = jax.lax.scan(
+        step, init, (lower.T, inside.T))
     bad = bad | ~any_digit
     bad = bad | (((phase == PH_ESIGN) | (phase == PH_EXP)) & ~any_edigit)
     dexp = xp.where(eneg, -eval_, eval_) + idigits - fdigits

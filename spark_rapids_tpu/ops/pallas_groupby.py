@@ -31,6 +31,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..compile import sjit
+from . import pallas_mode
 
 __all__ = ["segment_sum_i64", "fused_segment_sum", "MAX_SEGMENTS"]
 
@@ -142,7 +143,7 @@ def segment_sum_i64(values, segment_ids, num_segments: int):
         in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * 5,
         out_specs=pl.BlockSpec(memory_space=pl.ANY),
         out_shape=jax.ShapeDtypeStruct((nb, 4 * SUB, g), jnp.float32),
-        interpret=jax.default_backend() != "tpu",
+        interpret=pallas_mode.interpret(),
     )(ids.reshape(nb * SUB, LANES),
       *[l.reshape(nb * SUB, LANES) for l in limbs])
     # per-dot f32 partials are exact integers < 2^24; everything after is

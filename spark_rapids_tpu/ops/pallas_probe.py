@@ -27,6 +27,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .. import types as T
 from ..compile import sjit
+from . import pallas_mode
 
 __all__ = ["hash_int_rows", "hash_long_rows", "hash_vecs_pallas",
            "candidate_counts"]
@@ -154,7 +155,7 @@ def _run(n: int, words, seed):
         in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * len(arrs),
         out_specs=pl.BlockSpec(memory_space=pl.ANY),
         out_shape=jax.ShapeDtypeStruct((nb, SUB, LANES), jnp.int32),
-        interpret=jax.default_backend() != "tpu",
+        interpret=pallas_mode.interpret(),
     )(*[a.reshape(nb * SUB, LANES) for a in arrs])
     return out.reshape(nb * CHUNK)[:n]
 

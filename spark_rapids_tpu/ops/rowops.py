@@ -11,7 +11,9 @@ Design notes (ARCHITECTURE.md #4):
     argsort on the keep-mask, which XLA lowers to a single sort+gather;
   * multi-key sort builds a key list per SortOrder (null indicator + transformed
     data) and lexsorts; descending integer keys use bitwise-not (no INT_MIN
-    overflow), descending floats negate, strings contribute their byte columns;
+    overflow), descending floats negate, strings contribute big-endian words;
+  * every device sort is a chain of single-key stable sorts with int32 indices
+    (`stable_lexsort`): the chip's compiler takes minutes over one variadic sort;
   * grouping = sort by keys + boundary detection + segment_{sum,min,max} with the
     static capacity as num_segments.
 """
@@ -38,12 +40,51 @@ def gather_vecs(xp, vecs: Sequence[Vec], idx) -> List[Vec]:
     return [v.gather(xp, idx) for v in vecs]
 
 
+def stable_lexsort(xp, keys: Sequence):
+    """Stable sort permutation over `keys`, MOST-significant first.
+
+    On device this is NOT one variadic sort: the v5e compiler's time over a
+    sort grows steeply with its operand count and with 64-bit operands (a
+    10-operand sort of 131,072 rows took it 574 s, a 64-bit iota alone doubles
+    a one-key sort). A chain of single-key stable sorts, least significant
+    key first, each carrying one int32 index operand, is the same permutation
+    and compiled in 22 s for nine keys."""
+    if xp is np:
+        return np.lexsort(tuple(keys[::-1]))
+    import jax
+    perm = None
+    for key in reversed(keys):
+        if perm is None:
+            perm = jax.lax.iota(np.int32, key.shape[0])
+        else:
+            key = key[perm]
+        _, perm = jax.lax.sort((key, perm), num_keys=1, is_stable=True)
+    return perm
+
+
 def compact_vecs(xp, vecs: Sequence[Vec], keep_mask) -> Tuple[List[Vec], any]:
     """Stable-move rows where keep_mask (bool[cap]) to the front; returns
     (columns, new_count). Padding tail contents are unspecified."""
-    order = xp.argsort(~keep_mask, stable=True)
+    order = stable_lexsort(xp, [(~keep_mask).astype(np.int8)])
     new_count = xp.sum(keep_mask).astype(np.int32)
     return gather_vecs(xp, vecs, order), new_count
+
+
+def _string_words(xp, data) -> List:
+    """A [n, width] byte matrix as big-endian uint32 words, most significant
+    first: unsigned word order IS byte-lexicographic order, with a quarter
+    of the sort operands. One operand per byte made the chip's compiler take
+    minutes over a 16-byte key (a variadic sort's cost grows with its
+    operand count)."""
+    n, width = data.shape
+    pad = -width % 4
+    if pad:
+        data = xp.pad(data, ((0, 0), (0, pad)))
+    # explicit word count: -1 cannot be inferred for a zero-row batch
+    q = data.reshape(n, (width + pad) // 4, 4).astype(np.uint32)
+    words = ((q[:, :, 0] << np.uint32(24)) | (q[:, :, 1] << np.uint32(16))
+             | (q[:, :, 2] << np.uint32(8)) | q[:, :, 3])
+    return [words[:, i] for i in range(words.shape[1])]
 
 
 def sort_keys_for(xp, v: Vec, ascending: bool, nulls_first: bool) -> List:
@@ -60,12 +101,12 @@ def sort_keys_for(xp, v: Vec, ascending: bool, nulls_first: bool) -> List:
     keys: List = [null_key]
     if v.is_string:
         lens = v.lengths.astype(np.int32)
+        words = _string_words(xp, v.data)
         if ascending:
-            keys.extend(v.data[:, b] for b in range(v.data.shape[1]))
+            keys.extend(words)
             keys.append(lens)  # trailing-NUL tiebreak (cf. string_compare)
         else:
-            keys.extend(np.uint8(255) - v.data[:, b]
-                        for b in range(v.data.shape[1]))
+            keys.extend(~w for w in words)
             keys.append(~lens)
     elif isinstance(dt, T.DecimalType) and \
             dt.precision > T.DecimalType.MAX_LONG_DIGITS:
@@ -97,10 +138,7 @@ def lexsort_indices(xp, key_groups: Sequence[List], cap: int):
     flat: List = []
     for grp in key_groups:
         flat.extend(grp)
-    if xp is np:
-        return np.lexsort(tuple(flat[::-1]))
-    import jax.numpy as jnp
-    return jnp.lexsort(tuple(flat[::-1]))
+    return stable_lexsort(xp, flat)
 
 
 def sort_batch_vecs(xp, vecs: Sequence[Vec], sort_cols: Sequence[int],

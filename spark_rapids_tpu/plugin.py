@@ -24,6 +24,7 @@ class TpuSession:
         self._device_initialized = False
         self._last_profile = None
         self._last_stats = None
+        self._last_plan = None
         TpuSession._active = self
 
     # ------------------------------------------------------------------ device
@@ -227,6 +228,7 @@ class TpuSession:
             self.initialize_device()
             ov = Overrides(self.conf)
             result = ov.apply(plan)
+            self._last_plan = result
             self._last_explain = ov.explain_string()
             if self._last_explain:
                 print(self._last_explain)
@@ -475,6 +477,12 @@ class TpuSession:
         return self.from_arrow(
             host_batch_to_arrow(device_batch_to_host(batch)),
             label="device-handoff")
+
+    @property
+    def last_plan(self):
+        """The rewritten plan the most recent device query executed, with
+        its operators' live metrics (None before the first query)."""
+        return self._last_plan
 
     @property
     def last_profile(self):

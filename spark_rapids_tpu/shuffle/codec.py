@@ -10,6 +10,7 @@ reports unavailable otherwise."""
 
 from __future__ import annotations
 
+import threading
 from typing import Dict, Optional
 
 # ---------------------------------------------------------------------------
@@ -81,15 +82,28 @@ class ZstdCodec(Codec):
     name = "zstd"
 
     def __init__(self, level: int = 1):
-        import zstandard
-        self._c = zstandard.ZstdCompressor(level=level)
-        self._d = zstandard.ZstdDecompressor()
+        import zstandard  # noqa: F401 — absent wheel fails HERE, not per call
+        self._level = level
+        # zstandard contexts are not thread-safe and the shuffle writer's
+        # pool shares one codec: a context per thread ("Src size is
+        # incorrect" under concurrent compress otherwise)
+        self._tls = threading.local()
+
+    def _contexts(self):
+        ctx = getattr(self._tls, "ctx", None)
+        if ctx is None:
+            import zstandard
+            ctx = self._tls.ctx = (
+                zstandard.ZstdCompressor(level=self._level),
+                zstandard.ZstdDecompressor())
+        return ctx
 
     def compress(self, data: bytes) -> bytes:
-        return self._c.compress(data)
+        return self._contexts()[0].compress(data)
 
     def decompress(self, data: bytes, uncompressed_len: int) -> bytes:
-        return self._d.decompress(data, max_output_size=uncompressed_len)
+        return self._contexts()[1].decompress(
+            data, max_output_size=uncompressed_len)
 
 
 class ZlibCodec(Codec):

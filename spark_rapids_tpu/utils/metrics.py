@@ -10,7 +10,7 @@ from __future__ import annotations
 import contextlib
 import threading
 import time
-from typing import Dict
+from typing import Dict, List
 
 ESSENTIAL = 0
 MODERATE = 1
@@ -219,11 +219,15 @@ class TaskMetrics:
         # post-exchange slot plane — the data that would otherwise ride
         # the host shuffle), scan shards produced across mesh positions,
         # and exchanges that degraded to the host data plane on a
-        # shard-count vs partition-count mismatch
+        # shard-count vs partition-count mismatch; mesh_out_devices is the
+        # sorted ids of the devices that held the collectives' output shards
+        # (code that never met more than one real chip may put them all on
+        # the first)
         self.mesh_exchanges = 0
         self.mesh_ici_bytes = 0
         self.mesh_shards = 0
         self.mesh_degraded = 0
+        self.mesh_out_devices: List[int] = []
         # whole-stage fusion (plan/fusion.py + exec/fused.py):
         # device_dispatches counts every host-side program launch at the
         # compile-service execute seam (cached-executable calls AND the

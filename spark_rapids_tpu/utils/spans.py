@@ -53,7 +53,7 @@ __all__ = ["SCHEMA_VERSION", "Span", "QueryProfile", "span",
 # valid: `validate_record` accepts both versions.
 SCHEMA_VERSION = 2
 
-# span kinds — the phase taxonomy the report tool aggregates by
+# span kinds — the phase classes the report tool aggregates by
 KIND_QUERY = "query"
 KIND_OPERATOR = "operator"
 KIND_COMPILE = "compile"
@@ -271,8 +271,8 @@ def format_adaptive_decision(d: Dict[str, Any]) -> str:
 
 
 def task_metrics_dict(tm) -> Dict[str, Any]:
-    """Flatten a TaskMetrics instance to a JSON-safe dict (ints + the
-    backoff list)."""
+    """Flatten a TaskMetrics instance to a JSON-safe dict (ints, the
+    backoff list and the mesh device-id list)."""
     out: Dict[str, Any] = {}
     for k in dir(tm):
         if k.startswith("_"):
@@ -283,7 +283,7 @@ def task_metrics_dict(tm) -> Dict[str, Any]:
         if isinstance(v, int):
             out[k] = v
         elif isinstance(v, list):
-            out[k] = [float(x) for x in v]
+            out[k] = [x if isinstance(x, int) else float(x) for x in v]
     return out
 
 

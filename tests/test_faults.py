@@ -679,10 +679,10 @@ class TestDeviceInitFaults:
         conf = TpuConf({"spark.rapids.tpu.device.startupTimeoutSec": 5.0})
         try:
             with inject(faults.DEVICE_INIT, "error",
-                        error=RuntimeError("tunnel down")):
+                        error=RuntimeError("runtime down")):
                 with pytest.raises(DeviceStartupError) as ei:
                     DeviceManager.initialize(conf)
-            assert "tunnel down" in str(ei.value.diagnostics.get("cause", ""))
+            assert "runtime down" in str(ei.value.diagnostics.get("cause", ""))
         finally:
             DeviceManager.shutdown()
 

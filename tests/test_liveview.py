@@ -666,7 +666,7 @@ class TestSatelliteTools:
         # an errored run (null headline) always fails the gate
         pe = tmp_path / "BENCH_err.json"
         pe.write_text(json.dumps({"metric": "m", "value": None,
-                                  "error": "wedged tunnel",
+                                  "error": "runtime hung at first touch",
                                   "detail": {}}))
         assert bc.main([str(pb), str(pe), "--fail-below", "0.1"]) == 2
         # json model shape (drain the earlier renders first)

@@ -93,6 +93,8 @@ class TestShardedScan:
         assert tm.mesh_exchanges > 0
         assert tm.mesh_shards >= NDEV, "scan was not sharded"
         assert tm.mesh_ici_bytes > 0
+        assert len(tm.mesh_out_devices) == NDEV, \
+            f"exchange output sits on devices {tm.mesh_out_devices}"
         assert tm.shuffle_bytes_written == 0, \
             "mesh run moved bytes over the host shuffle data plane"
         assert "meshExchanges=" in tm.explain_string()

@@ -159,6 +159,30 @@ class TestSortQueries:
             tpu, cpu = q.collect(), q.collect_cpu()
             assert tpu.column("id").to_pylist() == cpu.column("id").to_pylist()
 
+    @pytest.mark.parametrize("op", ["sort", "sort_desc", "window"])
+    @pytest.mark.parametrize("source", ["all_filtered", "zero_rows"])
+    def test_zero_row_batch_on_string_key(self, session, op, source):
+        """A string sort key on a batch with no rows: the word-packed key
+        builder must not infer its word count from an empty array (it raised
+        on both engines' CPU paths)."""
+        from spark_rapids_tpu.expr import RowNumber
+        n = 0 if source == "zero_rows" else 50
+        t = pa.table({"k": pa.array([f"key{i % 7}" for i in range(n)],
+                                    pa.string()),
+                      "v": pa.array(list(range(n)), pa.int64())})
+        df = session.from_arrow(t)
+        if source == "all_filtered":
+            df = df.filter(col("v") > 1000)
+        if op == "window":
+            q = df.window(partition_by=[col("k")], order_by=[col("v")],
+                          rn=RowNumber())
+        else:
+            asc = op == "sort"
+            q = df.sort((col("k"), asc, asc), (col("v"), True, True))
+        cpu, tpu = q.collect_cpu(), q.collect()
+        assert cpu.num_rows == 0 and tpu.num_rows == 0
+        assert tpu.schema.equals(cpu.schema)
+
 
 class TestJoinQueries:
     def _tables(self, session, rng):

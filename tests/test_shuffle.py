@@ -74,6 +74,28 @@ class TestCodecs:
             comp = c.compress(data)
             assert c.decompress(comp, len(data)) == data
 
+    @pytest.mark.parametrize("codec", ["zstd", "lz4xla"])
+    def test_shared_codec_under_concurrent_use(self, codec):
+        """`get_codec` hands every caller ONE cached object and the shuffle
+        writer's pool calls it from several threads at once. zstandard
+        contexts are not thread-safe: a shared compressor gave 'Src size is
+        incorrect' or another thread's bytes."""
+        from concurrent.futures import ThreadPoolExecutor
+        c = get_codec(codec)
+
+        def work(seed):
+            r = np.random.default_rng(seed)
+            for _ in range(60):
+                data = r.bytes(int(r.integers(1, 200_000))) + \
+                    b"pad" * int(r.integers(0, 50_000))
+                comp = c.compress(data)
+                if c.decompress(comp, len(data)) != data:
+                    return False
+            return True
+
+        with ThreadPoolExecutor(8) as pool:
+            assert all(pool.map(work, range(8)))
+
 
 class TestWindowedBlockIterator:
     def test_splits_large_block(self):
