@@ -17,6 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import types as T
+from ..utils import spans
 from .column import Column, from_arrow as col_from_arrow, from_numpy as col_from_numpy, \
     to_arrow as col_to_arrow
 from .padding import row_bucket
@@ -100,8 +101,17 @@ class ColumnarBatch:
         return 0
 
     def row_count(self) -> int:
-        """Host-synchronizing logical row count (use only on host paths)."""
-        return int(self.num_rows)
+        """Host-synchronizing logical row count (use only on host paths).
+        On a device scalar the host blocks until every program queued before
+        it has run: that wait is the `sync.row_count` span and
+        `TaskMetrics.host_sync_ns/_count`. A host int costs and records
+        nothing."""
+        n = self.num_rows
+        if not isinstance(n, jax.Array):
+            return int(n)
+        with spans.timed("sync.row_count", "host_sync_ns",
+                         add={"host_sync_count": 1}):
+            return int(n)
 
     def row_mask(self) -> jnp.ndarray:
         """bool[cap]: True for live (non-padding) rows. Fused away by XLA."""

@@ -28,9 +28,13 @@ Architecture (see ARCHITECTURE.md "Compile service"):
     program.
   * single-flight: concurrent service threads asking for the same key wait
     on the first thread's compile instead of compiling twice.
-  * observability: global per-op `CompileStats` plus per-task counters in
-    `TaskMetrics` (surfaced by `explain_string()`), and a
-    ``compile:<op>`` `trace_range` span around every real compile.
+  * observability: every program runs under `program_name(op)`, so a
+    profiler trace names it by its operator family; global per-op
+    `CompileStats` (a compile's wall time split into its trace, lower and
+    backend stages) plus per-task counters in `TaskMetrics` (surfaced by
+    `explain_string()`); a ``compile:<op>`` span around every real compile
+    and, on the profiler's clock only, ``dispatch.<op>`` around every call
+    and ``compile.<stage>.<op>`` around each stage.
   * faults: the ``compile`` injection point (faults.py) fires before a
     compile (error/wedge) and over persisted bytes on read (corrupt).
     ANY service failure degrades to a direct `jax.jit` call under a
@@ -47,6 +51,7 @@ it on every hit — flag/message pairing survives executable reuse.
 from __future__ import annotations
 
 import collections
+import contextlib
 import hashlib
 import json
 import os
@@ -59,9 +64,11 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from ..errors import CompileServiceWarning
+from ..utils import spans
+from ..utils.tracing import trace_range
 
 __all__ = ["CompileService", "CompileStats", "ServiceJit", "sjit",
-           "instance_jit", "kernel_key"]
+           "instance_jit", "kernel_key", "program_name"]
 
 _MAGIC = b"SRTC1"
 _HDR = struct.Struct("<5sBII")  # magic, format, crc32c, meta length
@@ -161,6 +168,22 @@ def kernel_key(*parts, conf=None) -> str:
     return hashlib.sha256(text.encode()).hexdigest()[:24]
 
 
+def program_name(op: str) -> str:
+    """The name a program of operator family `op` is jitted under: the XLA
+    module is `jit_<name>` and a profiler trace prints it so. Part of the
+    program's digest, and of JAX's persistent-cache key."""
+    return op
+
+
+def _named(fn: Callable, op: str) -> Callable:
+    """A fresh wrapper of `fn` called `program_name(op)`, for `jax.jit` to
+    take the module's name from. `fn` itself is shared and keeps its name."""
+    def program(*args):
+        return fn(*args)
+    program.__name__ = program.__qualname__ = program_name(op)
+    return program
+
+
 class _Entry:
     __slots__ = ("compiled", "msgs", "op", "source")
 
@@ -175,8 +198,10 @@ class _Entry:
 class CompileStats:
     """Process-wide compile accounting, per op and total."""
 
+    # trace_ns + lower_ns + backend_ns: the AOT stages inside compile_ns
     _FIELDS = ("compiles", "compile_ns", "hits", "misses", "persist_hits",
-               "persist_stores", "persist_errors", "poisoned", "fallbacks")
+               "persist_stores", "persist_errors", "poisoned", "fallbacks",
+               "trace_ns", "lower_ns", "backend_ns")
 
     def __init__(self):
         self._mu = threading.Lock()
@@ -262,7 +287,7 @@ class ServiceJit:
         the service is disabled or wounded)."""
         if self._direct is None:
             import jax
-            self._direct = jax.jit(self.fn,
+            self._direct = jax.jit(_named(self.fn, self.op),
                                    static_argnums=self.static_argnums)
         return self._direct
 
@@ -384,6 +409,13 @@ class CompileService:
 
     # ------------------------------------------------------------------
     def call(self, sj: ServiceJit, args: tuple):
+        # digest, lookup and launch, on the profiler's clock only: one per
+        # batch per kernel is too many for a query profile, where the
+        # opt-in `kernel:<op>` span below stays what it was
+        with trace_range(f"dispatch.{sj.op}"):
+            return self._call(sj, args)
+
+    def _call(self, sj: ServiceJit, args: tuple):
         if not self._enabled:
             self._count_dispatch(args)
             return sj.direct(*args)
@@ -413,7 +445,6 @@ class CompileService:
         self._restore_boxes(entry, boxes)
         try:
             if self._kernel_spans:
-                from ..utils import spans
                 with spans.span(f"kernel:{sj.op}", kind=spans.KIND_KERNEL,
                                 op=sj.op):
                     return entry.compiled(*dyn)
@@ -441,7 +472,8 @@ class CompileService:
                 treedef) -> str:
         import jax
         text = "\x1f".join((
-            sj.op, sj.key, sj.code_fingerprint, jax.__version__,
+            sj.op, program_name(sj.op), sj.key, sj.code_fingerprint,
+            jax.__version__,
             "|".join(_static_sig(s) for s in statics),
             repr(tuple(_leaf_sig(l) for l in leaves)),
             str(treedef),
@@ -519,16 +551,18 @@ class CompileService:
         import jax
 
         from .. import faults
-        from ..utils import spans
-        from ..utils.tracing import trace_range
         try:
             faults.fire(faults.COMPILE)
             t0 = time.monotonic_ns()
-            with trace_range(f"compile:{sj.op}"), \
-                    spans.span(f"compile:{sj.op}", kind=spans.KIND_COMPILE,
-                               op=sj.op):
-                jitted = jax.jit(self._dyn_fn(sj, statics))
-                compiled = jitted.lower(*dyn).compile()
+            with spans.span(f"compile:{sj.op}", kind=spans.KIND_COMPILE,
+                            op=sj.op):
+                jitted = jax.jit(_named(self._dyn_fn(sj, statics), sj.op))
+                with self._stage("trace", sj.op):
+                    traced = jitted.trace(*dyn)
+                with self._stage("lower", sj.op):
+                    lowered = traced.lower()
+                with self._stage("backend", sj.op):
+                    compiled = lowered.compile()
             dt = time.monotonic_ns() - t0
         except Exception as e:
             # tracing errors are user errors and reproduce identically on
@@ -543,6 +577,18 @@ class CompileService:
         entry = _Entry(compiled, [list(b) for b in boxes], sj.op, "compile")
         self._persist(digest, sj, jitted, dyn, entry)
         return entry
+
+    @contextlib.contextmanager
+    def _stage(self, stage: str, op: str):
+        """One AOT stage of a compile (`trace`: the Python body to a jaxpr,
+        `lower`: to StableHLO, `backend`: XLA's compile or its persistent
+        cache's load), on the profiler's clock and in `CompileStats`."""
+        t0 = time.monotonic_ns()
+        try:
+            with trace_range(f"compile.{stage}.{op}"):
+                yield
+        finally:
+            self.stats.bump(op, **{f"{stage}_ns": time.monotonic_ns() - t0})
 
     @staticmethod
     def _restore_boxes(entry: _Entry, boxes: List[list]) -> None:
@@ -636,7 +682,8 @@ class CompileService:
             # degraded read: recompile from scratch (warn, count, continue)
             self._fallback(sj, f"injected persistent-read fault: {e}")
             return None
-        entry = self._decode_entry(blob, digest, sj)
+        with trace_range(f"compile.reload.{sj.op}"):
+            entry = self._decode_entry(blob, digest, sj)
         if entry is None:
             # poisoned/torn/stale entry: delete so the recompile re-persists
             # a good one, and treat as a plain miss
@@ -670,11 +717,12 @@ class CompileService:
             import jax.export as jex
             _register_export_serialization()
             exported = jex.deserialize(bytearray(payload))
+            op = meta.get("op", sj.op)
             # jit around the exported call so the backend compile of the
             # restored StableHLO caches instead of recurring per dispatch
-            compiled = jax.jit(exported.call)
+            compiled = jax.jit(_named(exported.call, op))
             msgs = [list(m) for m in meta.get("msgs", [])]
-            return _Entry(compiled, msgs, meta.get("op", sj.op), "persist")
+            return _Entry(compiled, msgs, op, "persist")
         except Exception:
             return None
 

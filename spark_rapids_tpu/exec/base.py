@@ -25,7 +25,8 @@ from ..sched import context as _qctx
 from .. import live as _live
 from ..utils import metrics as M
 from ..utils import spans
-from ..utils.tracing import trace_range
+
+_END = object()
 
 
 class TpuExec:
@@ -57,51 +58,55 @@ class TpuExec:
 
     def execute(self) -> Iterator[ColumnarBatch]:
         """Produce output batches (single-partition stream; exchange operators
-        introduce partitioned streams)."""
+        introduce partitioned streams). Each PULL runs under its own
+        `op.<ExecName>` span, not the stream: a stream-long span stays open
+        while the consumer works, a pull is open only while this operator
+        (or a child it pulls) does. Pulls nest, so the innermost open one
+        is the operator at work."""
         prof = spans.current_profile()
-        if prof is None and not (self.spill_time.live
-                                 or self.semaphore_wait_time.live
-                                 or self.peak_dev_memory.live):
-            # disabled path: one global read + three attribute reads per
-            # operator per query — no span objects, no per-batch syncs.
-            # Each pull is a cancellation point (sched.context.checkpoint
-            # is one module-global read with no context active): a
-            # cancelled/deadline-exceeded query unwinds between batches
-            # with the typed error, through every operator's finally.
-            with trace_range(self.name):
-                for batch in self.do_execute():
-                    _qctx.checkpoint()
-                    # live-introspection observer (one module-global bool
-                    # when off): stamps this op as the query's current
-                    # position — rows/batches come from the MetricsSet
-                    _live.note_pull(self)
-                    yield batch
+        if prof is not None or self.spill_time.live \
+                or self.semaphore_wait_time.live or self.peak_dev_memory.live:
+            yield from self._instrumented_execute(prof)
             return
-        yield from self._instrumented_execute(prof)
+        # disabled path: one global read + three attribute reads per
+        # operator per query, an annotation per pull (an atomic load with no
+        # profiler session) — no span objects, no per-batch syncs.
+        # Each pull is a cancellation point (sched.context.checkpoint
+        # is one module-global read with no context active): a
+        # cancelled/deadline-exceeded query unwinds between batches
+        # with the typed error, through every operator's finally.
+        name = "op." + self.name
+        it = self.do_execute()
+        while True:
+            with spans.span(name, kind=spans.KIND_OPERATOR):
+                batch = next(it, _END)
+            if batch is _END:
+                return
+            _qctx.checkpoint()
+            # live-introspection observer (one module-global bool
+            # when off): stamps this op as the query's current
+            # position — rows/batches come from the MetricsSet
+            _live.note_pull(self)
+            yield batch
 
     def _instrumented_execute(self, prof) -> Iterator[ColumnarBatch]:
-        """Profiling/DEBUG-metrics path: an operator span wraps the whole
-        stream and per-pull deltas of the task-level accumulators are
-        charged to this operator (inclusive of children, like opTime)."""
+        """Profiling/DEBUG-metrics path: an operator span per pull, and
+        per-pull deltas of the task-level accumulators are charged to this
+        operator (inclusive of children, like opTime)."""
         from ..memory.budget import MemoryBudget
         tm = M.TaskMetrics.get()
         budget = MemoryBudget.get()
-        sp_cm = spans.NOOP_SPAN
-        if prof is not None:
-            op_id = prof.ensure_operator(self)
-            sp_cm = spans.span(self.name, kind=spans.KIND_OPERATOR,
-                               op_id=op_id)
-        with trace_range(self.name), sp_cm as sp:
-            it = self.do_execute()
-            while True:
-                _qctx.checkpoint()  # per-pull cancellation point
-                spill0 = (tm.spill_to_host_ns + tm.spill_to_disk_ns
-                          + tm.read_spill_ns)
-                sem0 = tm.semaphore_wait_ns
+        name = "op." + self.name
+        attrs = {} if prof is None else {"op_id": prof.ensure_operator(self)}
+        it = self.do_execute()
+        while True:
+            _qctx.checkpoint()  # per-pull cancellation point
+            spill0 = (tm.spill_to_host_ns + tm.spill_to_disk_ns
+                      + tm.read_spill_ns)
+            sem0 = tm.semaphore_wait_ns
+            with spans.span(name, kind=spans.KIND_OPERATOR, **attrs) as sp:
                 try:
-                    batch = next(it)
-                except StopIteration:
-                    return
+                    batch = next(it, _END)
                 finally:
                     self.spill_time.add(tm.spill_to_host_ns
                                         + tm.spill_to_disk_ns
@@ -112,11 +117,13 @@ class TpuExec:
                     # inside the pull must still register (the budget
                     # resets its peak at query start)
                     self.peak_dev_memory.set_max(budget.peak_used)
-                _live.note_pull(self)
+                if batch is _END:
+                    return
                 if prof is not None:  # attr computation syncs; skip if off
                     sp.inc(batches=1, rows=int(batch.row_count()),
                            bytes=int(batch.device_memory_size()))
-                yield batch
+            _live.note_pull(self)
+            yield batch
 
     def do_execute(self) -> Iterator[ColumnarBatch]:
         raise NotImplementedError

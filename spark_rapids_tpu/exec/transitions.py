@@ -19,6 +19,7 @@ from ..columnar.column import Column
 from ..columnar.padding import row_bucket, width_bucket
 from ..cpu.hostbatch import HostBatch
 from ..expr.base import Vec
+from ..utils import spans
 from .base import TpuExec, batch_vecs
 
 
@@ -67,22 +68,26 @@ def host_batch_to_device(hb: HostBatch) -> ColumnarBatch:
 
 
 def device_batch_to_host(b: ColumnarBatch) -> HostBatch:
-    n = b.row_count()
-    vecs = []
-    for c in b.columns:
-        if c.children is not None:
-            from ..cpu.hostbatch import vec_map_arrays
-            vecs.append(vec_map_arrays(Vec.from_column(c),
-                                       lambda a: np.asarray(a)[:n]))
-            continue
-        valid = np.asarray(c.validity[:n])
-        if c.is_string:
-            from ..columnar.strings import assemble_matrix
-            mat, lens = assemble_matrix(c.data, c.lengths, c.overflow, n)
-            vecs.append(Vec(c.dtype, mat, valid, lens))
-        else:
-            vecs.append(Vec(c.dtype, np.asarray(c.data[:n]), valid))
-    return HostBatch(b.schema, vecs, n)
+    """The sink's device -> host copy of one batch, under the `sink.d2h`
+    span and `TaskMetrics.d2h_ns`. Its own row-count sync is part of it, so
+    it reads `num_rows` directly and adds nothing to `host_sync_ns`."""
+    with spans.timed("sink.d2h", "d2h_ns", kind=spans.KIND_IO):
+        n = int(b.num_rows)
+        vecs = []
+        for c in b.columns:
+            if c.children is not None:
+                from ..cpu.hostbatch import vec_map_arrays
+                vecs.append(vec_map_arrays(Vec.from_column(c),
+                                           lambda a: np.asarray(a)[:n]))
+                continue
+            valid = np.asarray(c.validity[:n])
+            if c.is_string:
+                from ..columnar.strings import assemble_matrix
+                mat, lens = assemble_matrix(c.data, c.lengths, c.overflow, n)
+                vecs.append(Vec(c.dtype, mat, valid, lens))
+            else:
+                vecs.append(Vec(c.dtype, np.asarray(c.data[:n]), valid))
+        return HostBatch(b.schema, vecs, n)
 
 
 class TpuFromCpuExec(TpuExec):

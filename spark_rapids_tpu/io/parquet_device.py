@@ -61,6 +61,7 @@ import numpy as np
 
 from .. import types as T
 from ..columnar.padding import row_bucket
+from ..utils import spans
 
 __all__ = ["DeviceDecodeUnsupported", "columns_supported",
            "decode_row_group", "decode_row_groups_fused",
@@ -74,6 +75,18 @@ def _note_dispatches(n: int = 1) -> None:
     bench.py reports dispatches-per-scan-batch from it."""
     from ..utils.metrics import TaskMetrics
     TaskMetrics.get().scan_dispatches += n
+
+
+def _ship(buffers):
+    """The scan's one batched host -> device transfer of `buffers` (an array
+    or a list of them): the `scan.h2d` span, and `TaskMetrics.h2d_ns` and
+    `h2d_bytes`."""
+    import jax
+    nbytes = sum(a.nbytes for a in buffers) if isinstance(buffers, list) \
+        else buffers.nbytes
+    with spans.timed("scan.h2d", "h2d_ns", kind=spans.KIND_IO,
+                     add={"h2d_bytes": nbytes}, bytes=nbytes):
+        return jax.device_put(buffers)
 
 
 class DeviceDecodeUnsupported(Exception):
@@ -916,14 +929,15 @@ def _device_phase(pf, rg: int, schema, works, nrows: int, host_cols=None):
     fused = [w for w in works.values() if w.ship is not None]
     fused_cols = {}
     if fused:
-        flat: List[np.ndarray] = []
-        for w in fused:
-            if w.defruns is not None:
-                flat.extend(w.defruns)
-            flat.extend(w.ship)
-        sig = tuple(_col_sig(w) for w in fused)
-        program = _fused_decode_program(sig, cap)
-        outs = program(np.int64(nrows), *jax.device_put(flat))
+        with spans.span("scan.pack", kind=spans.KIND_IO):
+            flat: List[np.ndarray] = []
+            for w in fused:
+                if w.defruns is not None:
+                    flat.extend(w.defruns)
+                flat.extend(w.ship)
+            sig = tuple(_col_sig(w) for w in fused)
+            program = _fused_decode_program(sig, cap)
+        outs = program(np.int64(nrows), *_ship(flat))
         # one buffer per flat array + the nrows scalar + one program
         _note_dispatches(len(flat) + 2)
         for w, (data, validity) in zip(fused, outs):
@@ -976,7 +990,8 @@ def decode_row_group(pf, f, rg: int, schema, host_cols=None):
     (pf.read_row_group) — per-row-group granularity keeps the stream lazy
     (one device batch live at a time, the reference's chunked-reader
     discipline) with no double decode."""
-    works, nrows = _host_phase(pf, f, rg, schema, host_cols)
+    with spans.span("scan.walk", kind=spans.KIND_IO):
+        works, nrows = _host_phase(pf, f, rg, schema, host_cols)
     out = _device_phase(pf, rg, schema, works, nrows, host_cols)
     from ..utils.metrics import TaskMetrics
     TaskMetrics.get().scan_chunks += 1
@@ -1799,10 +1814,11 @@ def _read_chunks(pf, f, rgs, schema, host_cols=None):
     once -> ([(rg, works, nrows)], total rows)."""
     chunks = []
     total = 0
-    for rg in rgs:
-        works, nrows = _host_phase(pf, f, rg, schema, host_cols)
-        chunks.append((rg, works, nrows))
-        total += nrows
+    with spans.span("scan.walk", kind=spans.KIND_IO):
+        for rg in rgs:
+            works, nrows = _host_phase(pf, f, rg, schema, host_cols)
+            chunks.append((rg, works, nrows))
+            total += nrows
     return chunks, total
 
 
@@ -1903,14 +1919,15 @@ def _decode_chunks_fused(pf, rgs, schema, chunks, total, host_cols=None):
         return _per_rg_batches(pf, schema, chunks, host_cols)
     cap_total = row_bucket(total, op="scan.parquet")
 
-    sig = _group_signatures(chunks, dev_names)
+    with spans.span("scan.pack", kind=spans.KIND_IO):
+        sig = _group_signatures(chunks, dev_names)
     if sig is None:
         return _per_rg_batches(pf, schema, chunks, host_cols)
     groups_sig, caps, packed, _ = sig
 
     program = _fused_multi_program(groups_sig, tuple(caps), cap_total)
     nrows_arr = np.asarray([n for _, _, n in chunks], np.int64)
-    outs = program(nrows_arr, jax.device_put(packed))
+    outs = program(nrows_arr, _ship(packed))
     _note_dispatches(3)  # nrows buffer + packed buffer + one program
     TaskMetrics.get().scan_chunks += len(rgs)
 
@@ -2443,7 +2460,8 @@ def decode_row_groups_pushdown(pf, f, rgs, schema, host_cols, dev):
     tried_sig = not host_set and dev.pred_device_ok and bool(dev_names) \
         and total > 0
     if tried_sig:
-        sig = _group_signatures(chunks, dev_names)
+        with spans.span("scan.pack", kind=spans.KIND_IO):
+            sig = _group_signatures(chunks, dev_names)
     if sig is None:
         return _pushdown_degrade(pf, rgs, schema, chunks, total,
                                  host_cols, dev, sig_declined=tried_sig)
@@ -2451,7 +2469,7 @@ def decode_row_groups_pushdown(pf, f, rgs, schema, host_cols, dev):
     cap_total = row_bucket(total, op="scan.parquet")
     dt_by_name = dict(zip(schema.names, schema.types))
     nrows_arr = np.asarray([n for _, _, n in chunks], np.int64)
-    packed_dev = jax.device_put(packed)
+    packed_dev = _ship(packed)
     select = _pushdown_select_program(groups_sig, tuple(caps), cap_total,
                                       dev, dt_by_name, tuple(dev_names))
     TaskMetrics.get().scan_chunks += len(rgs)

@@ -644,6 +644,7 @@ class TpuFileScanExec(_TpuExec):
         device-phase surprises fall just that row group back to pyarrow —
         the same narrow net as before."""
         from ..columnar.batch import batch_from_arrow
+        from ..utils import spans
         from ..utils.metrics import TaskMetrics
         from .parquet_device import (DeviceDecodeUnsupported, _device_phase,
                                      _host_phase, decode_row_groups_fused)
@@ -701,8 +702,9 @@ class TpuFileScanExec(_TpuExec):
                         continue
                 for rg in chunk_rgs:
                     try:
-                        works, nrows = _host_phase(pf, f, rg, scan.output,
-                                                   host_cols)
+                        with spans.span("scan.walk", kind=spans.KIND_IO):
+                            works, nrows = _host_phase(
+                                pf, f, rg, scan.output, host_cols)
                         b, nrows = _device_phase(pf, rg, scan.output,
                                                  works, nrows, host_cols)
                         tm.scan_batches += 1

@@ -219,15 +219,44 @@ class TpuSession:
         return out
 
     def _run_rewritten(self, plan: PhysicalPlan, enabled: bool):
+        """`_run_plan`, and for a device query one entry in the process's
+        ring of recent queries (`recent_queries()`), whatever its end."""
+        if not enabled:
+            return self._run_plan(plan, enabled)
+        import time
+        from .utils import metrics, spans
+        t0 = time.perf_counter()
+        try:
+            return self._run_plan(plan, enabled)
+        finally:
+            metrics.note_query(
+                time.perf_counter() - t0,
+                getattr(self._last_plan, "name", plan.name),
+                spans.task_metrics_dict(metrics.TaskMetrics.get()))
+
+    @staticmethod
+    def recent_queries():
+        """`(wall_s, label, task_metrics)` of the device queries this
+        PROCESS finished last, oldest first, at most
+        `utils.metrics.RECENT_QUERIES` (64) of them: with profiling off too,
+        what says whether a slow query waited on the chip (`host_sync_ns`,
+        `d2h_ns`) or on the host. A longer window is covered in its last 64
+        only."""
+        from .utils import metrics
+        return metrics.recent_queries()
+
+    def _run_plan(self, plan: PhysicalPlan, enabled: bool):
         from .cpu.hostbatch import host_batch_to_arrow
         from .exec.base import TpuExec
         from .exec.transitions import device_batch_to_host
         from .plan.nodes import _concat_host
+        from .utils import spans
 
         if enabled:
             self.initialize_device()
             ov = Overrides(self.conf)
-            result = ov.apply(plan)
+            with spans.span("plan.rewrite"):
+                result = ov.apply(plan)
             self._last_plan = result
             self._last_explain = ov.explain_string()
             if self._last_explain:
@@ -241,7 +270,6 @@ class TpuSession:
                                  InjectedFault, QueryCancelledError,
                                  QueryRejectedError, RetryOOM,
                                  SplitAndRetryOOM)
-            from .utils import spans
             from .utils.metrics import TaskMetrics
             # per-query counter reset happens in _execute_rewritten, BEFORE
             # the rescache lookup (a TpuExec result implies enabled, which
@@ -437,8 +465,11 @@ class TpuSession:
                                 RuntimeWarning, stacklevel=2)
         else:
             host_batches = list(result.execute_cpu())
-        merged = _concat_host(host_batches, plan.output)
-        return host_batch_to_arrow(merged)
+        # host batches -> the answer's rows (decimals become Python objects
+        # one by one here), the chip idle
+        with spans.span("sink.rows"):
+            merged = _concat_host(host_batches, plan.output)
+            return host_batch_to_arrow(merged)
 
     def execute_plan_device_batches(self, plan: PhysicalPlan):
         """Run a plan fully on the TPU engine and return the DEVICE batches

@@ -7,10 +7,11 @@ wall-clock nanoseconds (reference createNanoTimingMetric)."""
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import threading
 import time
-from typing import Dict, List
+from typing import Any, Dict, List, Tuple
 
 ESSENTIAL = 0
 MODERATE = 1
@@ -238,6 +239,19 @@ class TaskMetrics:
         self.device_dispatches = 0
         self.fused_stages = 0
         self.fused_ops = 0
+        # where the host waited for the device or moved bytes, counted at
+        # the seams that open the spans of the same name (utils/spans.timed,
+        # always on): `ColumnarBatch.row_count()` on a device scalar
+        # (`sync.row_count`: the host blocks until every program queued
+        # before it has run), the scan's batched transfers (`scan.h2d`) and
+        # the sink's device -> host copies (`sink.d2h`, its own row-count
+        # sync included). A prefetch producer shares its consumer's
+        # instance, so overlapping waits of the two threads both count.
+        self.host_sync_ns = 0
+        self.host_sync_count = 0
+        self.h2d_ns = 0
+        self.h2d_bytes = 0
+        self.d2h_ns = 0
 
     @classmethod
     def get(cls) -> "TaskMetrics":
@@ -336,3 +350,24 @@ class TaskMetrics:
                    f"fusedOps={self.fused_ops}"
                    if self.fused_stages else ""))
         return "" if not parts else "TaskMetrics: " + "; ".join(parts)
+
+
+# The last queries this process finished, with profiling off too: the "why
+# was that one slow" record (did it wait on the chip or on the host?).
+# Process-wide because whoever asks (an operator's console, a benchmark
+# reader) holds no session; bounded, so a serving process keeps 64.
+RECENT_QUERIES = 64
+_recent: "collections.deque" = collections.deque(maxlen=RECENT_QUERIES)
+_recent_mu = threading.Lock()
+
+
+def note_query(wall_s: float, label: str, task_metrics: Dict[str, Any]) -> None:
+    with _recent_mu:
+        _recent.append((wall_s, label, task_metrics))
+
+
+def recent_queries() -> List[Tuple[float, str, Dict[str, Any]]]:
+    """`(wall_s, label, task_metrics_dict)` of the last `RECENT_QUERIES`
+    finished device queries of this process, oldest first."""
+    with _recent_mu:
+        return list(_recent)

@@ -20,10 +20,17 @@ Three layers:
     file) and `explain_profile()`, the SQL-UI analogue: the operator tree
     rendered with live metric values inline plus a phase rollup.
 
-Disabled-path contract: when no profile is active, `span()` returns a
-shared no-op object (one module-global read, no allocation) and
-`TpuExec.execute` takes its untraced fast path — profiling costs nothing
-until `spark.rapids.tpu.metrics.eventLog.dir` or
+One primitive, two clocks: `span()` always opens a profiler annotation
+(`utils/tracing.trace_range`, name prefixed `SPAN_PREFIX`), so every engine
+span reaches a `jax.profiler` trace on the device trace's clock, and records
+a `Span` into the `QueryProfile` only when one is active. `timed()` is the
+same span with its wall time added to a `TaskMetrics` counter, profile or
+not: the always-on counters live at the seams the spans mark.
+
+Disabled-path contract: when no profile is active, `span()` yields the
+shared no-op span (no `Span` is allocated) and, with no profiler session,
+its annotation is an atomic load — profiling costs nothing until
+`spark.rapids.tpu.metrics.eventLog.dir` or
 `spark.rapids.tpu.metrics.profile.enabled` turns it on.
 """
 
@@ -37,7 +44,11 @@ import time
 import uuid
 from typing import Any, Dict, List, Optional
 
-__all__ = ["SCHEMA_VERSION", "Span", "QueryProfile", "span",
+from .metrics import TaskMetrics
+from .tracing import SPAN_PREFIX, trace_range
+
+__all__ = ["SCHEMA_VERSION", "SPAN_PREFIX", "Span", "QueryProfile", "span",
+           "timed",
            "current_profile", "begin_profile", "end_profile",
            "write_event_log", "validate_record", "task_metrics_dict",
            "new_trace_id", "current_trace", "trace_scope",
@@ -81,7 +92,7 @@ class Span:
     """One finished (or open) trace region."""
 
     __slots__ = ("span_id", "parent_id", "name", "kind", "start_ns",
-                 "end_ns", "attrs")
+                 "start_unix_ns", "end_ns", "attrs")
 
     def __init__(self, span_id: int, parent_id: int, name: str, kind: str,
                  attrs: Optional[Dict[str, Any]] = None):
@@ -90,6 +101,8 @@ class Span:
         self.name = name
         self.kind = kind
         self.start_ns = time.monotonic_ns()
+        # the wall clock too, so a JSONL record can be laid beside a trace
+        self.start_unix_ns = time.time_ns()
         self.end_ns: Optional[int] = None
         self.attrs: Dict[str, Any] = dict(attrs) if attrs else {}
 
@@ -150,20 +163,33 @@ def _stack() -> list:
     return s
 
 
-class _LiveSpan:
-    """Context manager creating a real Span inside the active profile."""
+class _Scope:
+    """One open `span()` or `timed()`: the profiler annotation, a real Span
+    inside the active profile when there is one, and for `timed()` the
+    wall time into a TaskMetrics counter."""
 
-    __slots__ = ("_prof", "_name", "_kind", "_attrs", "_span")
+    __slots__ = ("_range", "_prof", "_name", "_kind", "_attrs", "_span",
+                 "_field", "_add", "_t0")
 
-    def __init__(self, prof: "QueryProfile", name: str, kind: str,
-                 attrs: Dict[str, Any]):
+    def __init__(self, prof: Optional["QueryProfile"], name: str, kind: str,
+                 attrs: Dict[str, Any], field: Optional[str] = None,
+                 add: Optional[Dict[str, int]] = None):
+        self._range = trace_range(name)
         self._prof = prof
         self._name = name
         self._kind = kind
         self._attrs = attrs
         self._span: Optional[Span] = None
+        self._field = field
+        self._add = add
+        self._t0 = 0
 
-    def __enter__(self) -> Span:
+    def __enter__(self):
+        if self._field is not None:
+            self._t0 = time.perf_counter_ns()
+        self._range.__enter__()
+        if self._prof is None:
+            return NOOP_SPAN
         stack = _stack()
         parent = stack[-1].span_id if stack else QueryProfile.ROOT_SPAN_ID
         self._span = self._prof._open_span(self._name, self._kind, parent,
@@ -173,27 +199,51 @@ class _LiveSpan:
 
     def __exit__(self, *exc) -> bool:
         sp = self._span
-        sp.end_ns = time.monotonic_ns()
-        stack = _stack()
-        # tolerate interleaved generator frames: pop this span wherever it is
-        if stack and stack[-1] is sp:
-            stack.pop()
-        elif sp in stack:
-            stack.remove(sp)
-        self._prof._record(sp)
-        hook = _flight_hook
-        if hook is not None:  # telemetry flight recorder (late-bound)
-            hook(sp, self._prof)
+        if sp is not None:
+            sp.end_ns = time.monotonic_ns()
+            stack = _stack()
+            # tolerate interleaved generator frames: pop this span wherever
+            # it is
+            if stack and stack[-1] is sp:
+                stack.pop()
+            elif sp in stack:
+                stack.remove(sp)
+            self._prof._record(sp)
+            hook = _flight_hook
+            if hook is not None:  # telemetry flight recorder (late-bound)
+                hook(sp, self._prof)
+        self._range.__exit__(*exc)
+        if self._field is not None:
+            tm = TaskMetrics.get()
+            setattr(tm, self._field, getattr(tm, self._field)
+                    + time.perf_counter_ns() - self._t0)
+            for field, delta in (self._add or {}).items():
+                setattr(tm, field, getattr(tm, field) + delta)
         return False
 
 
-def span(name: str, kind: str = KIND_PHASE, **attrs):
-    """Open a span under the active query profile; a no-op when none is
-    active. Usage: ``with span("spill:to_host", kind="spill") as sp: ...``"""
+def _active_profile() -> Optional["QueryProfile"]:
     prof = _current
     if prof is None or prof.closed or getattr(_tls, "suppress", False):
-        return NOOP_SPAN
-    return _LiveSpan(prof, name, kind, attrs)
+        return None
+    return prof
+
+
+def span(name: str, kind: str = KIND_PHASE, **attrs) -> _Scope:
+    """Open a span: always the profiler annotation `SPAN_PREFIX + name`, and
+    a `Span` under the active query profile when there is one (else the
+    `with` yields the shared no-op). Usage:
+    ``with span("spill:to_host", kind="spill") as sp: ...``"""
+    return _Scope(_active_profile(), name, kind, attrs)
+
+
+def timed(name: str, ns_field: str, kind: str = KIND_PHASE,
+          add: Optional[Dict[str, int]] = None, **attrs) -> _Scope:
+    """`span()` whose wall time is also added to `TaskMetrics.<ns_field>`,
+    with or without a profile; `add` maps further TaskMetrics counters to
+    their increments (a byte count, a call count). One code path for the
+    span and the counter that mark one seam."""
+    return _Scope(_active_profile(), name, kind, attrs, ns_field, add)
 
 
 def suppress_in_thread() -> None:
@@ -454,6 +504,7 @@ class QueryProfile:
                 "span_id": sp.span_id,
                 "parent_id": sp.parent_id, "name": sp.name, "kind": sp.kind,
                 "start_ns": sp.start_ns, "dur_ns": sp.dur_ns,
+                "start_unix_ns": sp.start_unix_ns,
                 "attrs": dict(sp.attrs),
             })
         return recs

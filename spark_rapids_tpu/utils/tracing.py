@@ -1,12 +1,16 @@
 """Tracing ranges (reference `NvtxWithMetrics.scala`; NVTX → jax.profiler).
 
-`trace_range` wraps operator regions in `jax.profiler.TraceAnnotation` so xprof captures
-per-operator timelines the way Nsight consumed NVTX ranges, and optionally feeds a timing
-metric at the same time."""
+`trace_range` is the one place that touches `jax.profiler`: it opens a
+`TraceAnnotation`, so an engine region lands in the profiler's own trace, on
+the device trace's clock, the way Nsight consumed NVTX ranges. Every engine
+annotation carries `SPAN_PREFIX`, so a trace reader can tell an engine span
+from a traced Python frame. With no profiler session an annotation is one
+atomic load. `utils/spans.span` opens every span through it; the compile
+service uses it directly for the regions that are too many for a query
+profile (`dispatch.<op>`, `compile.<stage>.<op>`)."""
 
 from __future__ import annotations
 
-import contextlib
 import time
 
 try:
@@ -16,31 +20,35 @@ except Exception:  # pragma: no cover
     _profiler = None
     _HAVE_PROFILER = False
 
+__all__ = ["SPAN_PREFIX", "trace_range"]
 
-@contextlib.contextmanager
-def trace_range(name: str, metric=None):
-    t0 = time.monotonic_ns() if metric is not None else 0
-    try:
-        if _HAVE_PROFILER:
-            with _profiler.TraceAnnotation(name):
-                yield
-        else:  # pragma: no cover
-            yield
-    finally:
-        # in a finally: an exception inside the region (ANSI violation,
-        # OOM-retry) must still charge the elapsed time to the metric
-        if metric is not None:
-            metric.add(time.monotonic_ns() - t0)
+SPAN_PREFIX = "srt:"
 
 
-def start_profile(logdir: str) -> None:
-    """Start an xprof trace (reference docs/dev/nvtx_profiling.md workflow)."""
-    if not _HAVE_PROFILER:  # pragma: no cover
-        raise RuntimeError("jax.profiler unavailable in this environment")
-    _profiler.start_trace(logdir)
+class trace_range:
+    """`with trace_range(name, metric=None)`: the annotation
+    `SPAN_PREFIX + name`, optionally feeding a timing metric as well."""
 
+    __slots__ = ("_ann", "_metric", "_t0")
 
-def stop_profile() -> None:
-    if not _HAVE_PROFILER:  # pragma: no cover
-        raise RuntimeError("jax.profiler unavailable in this environment")
-    _profiler.stop_trace()
+    def __init__(self, name: str, metric=None):
+        self._ann = _profiler.TraceAnnotation(SPAN_PREFIX + name) \
+            if _HAVE_PROFILER else None
+        self._metric = metric
+        self._t0 = 0
+
+    def __enter__(self) -> "trace_range":
+        if self._metric is not None:
+            self._t0 = time.monotonic_ns()
+        if self._ann is not None:
+            self._ann.__enter__()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        # also on an exception inside the region (ANSI violation, OOM-retry):
+        # the elapsed time must still be charged to the metric
+        if self._metric is not None:
+            self._metric.add(time.monotonic_ns() - self._t0)
+        return False
