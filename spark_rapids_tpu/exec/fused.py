@@ -33,7 +33,7 @@ Mechanics worth knowing:
     only replaced them in the plan.
   * Pallas kernels (`ops/pallas_probe.py`, `ops/pallas_groupby.py`) serve
     the two hot inner loops when engaged (`spark.rapids.tpu.fusion.pallas
-    .mode`): the murmur3 hash feeding the join's sizing counts, and the
+    .mode`): the murmur3 hash feeding the join's one probe, and the
     exact int64 group-by accumulate. Both are bit-exact integer paths with
     jnp fallbacks, so fusion on/off identity is preserved either way.
 """
@@ -49,11 +49,11 @@ from ..columnar.padding import row_bucket
 from ..compile import instance_jit, kernel_key
 from ..utils.metrics import TaskMetrics
 from .aggregate import TpuHashAggregateExec
-from .base import (StaticExpr, TpuExec, batch_vecs, raise_kernel_errors,
-                   vecs_to_batch)
+from .base import StaticExpr, TpuExec, raise_kernel_errors, vecs_to_batch
 from .basic import TpuFilterExec, TpuProjectExec
 from .coalesce import colocate_batches, concat_batches
-from .joins import TpuBroadcastHashJoinExec, _expand_join, _probe_counts
+from .joins import (TpuBroadcastHashJoinExec, _expand_join, _probe_counts,
+                    _slot_counts)
 
 __all__ = ["TpuFusedStageExec"]
 
@@ -99,6 +99,12 @@ class TpuFusedStageExec(TpuExec):
         import jax
         self._pallas = mode == "force" or (
             mode == "auto" and jax.default_backend() == "tpu")
+        # under pallas mode the probe's murmur3 row hash runs through
+        # ops/pallas_probe (bit-exact); None keeps `expr.hashing.hash_vecs`
+        self._hash_rows = None
+        if self._pallas:
+            from ..ops.pallas_probe import hash_vecs_pallas
+            self._hash_rows = hash_vecs_pallas
 
     @staticmethod
     def _exprs_of(m) -> list:
@@ -153,26 +159,6 @@ class TpuFusedStageExec(TpuExec):
 
     # ---- the fused program -------------------------------------------------
 
-    def _probe_total(self, m, probe, build):
-        """Exact expand-slot total for one join member, computed in-trace
-        (the unfused `_join_pair_core` sizing formula). Under pallas mode
-        the murmur3 row-hash runs through ops/pallas_probe (bit-exact)."""
-        if self._pallas:
-            from ..ops.pallas_probe import candidate_counts
-            pvecs, bvecs = batch_vecs(probe), batch_vecs(build)
-            counts = candidate_counts(
-                jnp, [pvecs[i] for i in m._lk_ix],
-                [bvecs[i] for i in m._rk_ix],
-                probe.row_mask(), build.row_mask())
-        else:
-            counts = _raw(_probe_counts)(probe, build,
-                                         m._lk_ix, m._rk_ix)[0]
-        outer_left = m.join_type == "left"  # no right/full in fused scope
-        slot = jnp.where(probe.row_mask(),
-                         jnp.maximum(counts, 1) if outer_left else counts,
-                         0)
-        return jnp.sum(slot).astype(jnp.int32)
-
     def _agg_kernel(self, m, batch):
         """Trace the member aggregate kernel; with pallas engaged, the
         exact int64 segmented sum (ops/pallas_groupby) is installed for the
@@ -206,9 +192,15 @@ class TpuFusedStageExec(TpuExec):
             for m in members:
                 if isinstance(m, TpuBroadcastHashJoinExec):
                     probe, build = out, builds[ji]
-                    totals.append(self._probe_total(m, probe, build))
+                    # one probe per join: its arrays size the expand (the
+                    # total the host checks) and feed it
+                    phase1 = _raw(_probe_counts)(
+                        probe, build, m._lk_ix, m._rk_ix, self._hash_rows)
+                    totals.append(jnp.sum(_slot_counts(
+                        jnp, phase1[0], probe.row_mask(),
+                        m.join_type)).astype(jnp.int32))
                     out_vecs, n, _bm, cond_errs = _raw(_expand_join)(
-                        probe, build, m._lk_ix, m._rk_ix, caps[ji],
+                        probe, build, *phase1, m._lk_ix, m._rk_ix, caps[ji],
                         m.join_type, m._bcond, m.conf.is_ansi)
                     out = vecs_to_batch(m._schema, out_vecs, n)
                     errs_all.append(tuple(cond_errs))

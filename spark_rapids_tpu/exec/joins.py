@@ -2,11 +2,17 @@
 `GpuShuffledHashJoinExec.scala`, gather-map composition `JoinGatherer.scala:54-641`).
 
 TPU lowering (ARCHITECTURE.md #4): equi-joins run as hash-sorted probe —
-  1. hash the build-side keys (Spark murmur3), sort build rows by hash;
-  2. per probe row, locate the candidate range via searchsorted(left/right);
+  1. hash the build-side keys (Spark murmur3), sort build rows by hash, and give
+     each sorted position the length of the run of equal hashes it starts
+     (elementwise + one reversed running minimum over the small build side);
+  2. per probe row, locate the start of its candidate range with ONE search
+     (`side=left`) of the 32-bit hashes; the range's length is the run length
+     at that position if the hash there is the probe's, else 0;
   3. expand matches into (probe_idx, build_idx) pairs at a host-chosen output
      capacity (the JoinGatherer chunking analog: counts are computed on device,
-     summed, synced once to pick the bucket — data-dependent sizes never reach XLA);
+     summed, synced once to pick the bucket — data-dependent sizes never reach
+     XLA). Phase 2 consumes phase 1's arrays (counts, range starts, build
+     order, validity masks): nothing of the probe is computed twice;
   4. gather both sides, verify true key equality (hash collisions + null keys),
      compact away false positives.
 Left/right/full outer rows are emitted via the unmatched masks; semi/anti reduce the
@@ -19,6 +25,7 @@ from typing import Iterator, List, Sequence, Tuple
 
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from .. import types as T
 from ..columnar.batch import ColumnarBatch, Schema, join_output_schema
@@ -54,11 +61,44 @@ def _keys_equal(xp, a: List[Vec], b: List[Vec]):
     return eq
 
 
+def _slot_counts(xp, counts, pmask, join_type: str):
+    """Output slots per probe row: its candidate count, and one for a live
+    row without candidates where the join keeps unmatched probe rows. The
+    host sums these to size the expand; the expand lays its pairs out by
+    them."""
+    if join_type in ("left", "full"):
+        counts = xp.maximum(counts, 1)
+    return xp.where(pmask, counts, 0)
+
+
+_HASH_MAX = np.int32(np.iinfo(np.int32).max)
+
+
+def _run_lengths(xp, keys_sorted, n_valid):
+    """For each position of a sorted key array whose first `n_valid` entries
+    count: how many entries from it to the end of its run of equal keys (0
+    past the valid prefix). Elementwise ops and one reversed running minimum;
+    a probe that lands on a run's first position reads the run's length."""
+    n = keys_sorted.shape[0]
+    idx = xp.arange(n, dtype=np.int32)
+    starts = xp.concatenate([xp.ones(1, dtype=bool),
+                             keys_sorted[1:] != keys_sorted[:-1]])
+    # nearest run start at or after each position, then strictly after it
+    nxt = lax.cummin(xp.where(starts, idx, np.int32(n)), reverse=True)
+    nxt = xp.concatenate([nxt[1:], xp.full(1, n, dtype=np.int32)])
+    return xp.maximum(xp.minimum(nxt, n_valid) - idx, 0)
+
+
 @sjit(op="exec.join.probe_counts", static_argnums=(2, 3))
 def _probe_counts(probe: ColumnarBatch, build: ColumnarBatch,
-                  probe_key_ix: Tuple[int, ...], build_key_ix: Tuple[int, ...]):
-    """Phase 1: per-probe candidate counts (by hash range) + sorted build order."""
+                  probe_key_ix: Tuple[int, ...], build_key_ix: Tuple[int, ...],
+                  hash_rows=None):
+    """Phase 1: per-probe candidate counts and range starts (by hash) in the
+    sorted build order. One binary search per probe row; the range's length
+    comes from the build side's run lengths. `hash_rows` stands in for
+    `hash_vecs` (bit-identical; the fused stage's Pallas row hash)."""
     xp = jnp
+    hash_rows = hash_rows or hash_vecs
     pvecs = batch_vecs(probe)
     bvecs = batch_vecs(build)
     pkeys = [pvecs[i] for i in probe_key_ix]
@@ -67,34 +107,39 @@ def _probe_counts(probe: ColumnarBatch, build: ColumnarBatch,
     bmask = build.row_mask()
     pvalid = _keys_valid(xp, pkeys) & pmask
     bvalid = _keys_valid(xp, bkeys) & bmask
+    bcap = build.capacity
 
-    ph = hash_vecs(xp, pkeys).astype(np.int64)
-    bh32 = hash_vecs(xp, bkeys)
-    # exile invalid build rows to a hash bucket no valid probe can hit
-    bh = xp.where(bvalid, bh32.astype(np.int64), np.int64(2 ** 62))
-    # the order of argsort(bh), from two 32-bit keys (a 64-bit sort costs the
-    # chip's compiler twice as long): valid rows by hash, then the exiled
-    # rows, ties in row order
+    ph = hash_rows(xp, pkeys)
+    bh = hash_rows(xp, bkeys)
+    # valid rows by hash, then the invalid rows, ties in row order (two 32-bit
+    # keys: a 64-bit sort costs the chip's compiler twice as long)
     order = stable_lexsort(xp, [(~bvalid).astype(np.int8),
-                                xp.where(bvalid, bh32, 0)])
-    bh_sorted = bh[order]
-    lo = xp.searchsorted(bh_sorted, ph, side="left")
-    hi = xp.searchsorted(bh_sorted, ph, side="right")
-    counts = xp.where(pvalid, hi - lo, 0).astype(np.int32)
-    return counts, lo.astype(np.int32), order.astype(np.int32), pvalid, bvalid
+                                xp.where(bvalid, bh, 0)])
+    n_valid = xp.sum(bvalid).astype(np.int32)
+    # invalid build rows are exiled past the valid prefix under the largest
+    # hash: the array stays sorted, a side=left search never lands beyond the
+    # first of them, and their run length is 0, so no probe can reach them
+    bh_sorted = xp.where(xp.arange(bcap, dtype=np.int32) < n_valid,
+                         bh[order], _HASH_MAX)
+    run_len = _run_lengths(xp, bh_sorted, n_valid)
+    lo = xp.searchsorted(bh_sorted, ph, side="left").astype(np.int32)
+    at = xp.minimum(lo, bcap - 1)
+    hit = pvalid & (bh_sorted[at] == ph)
+    counts = xp.where(hit, run_len[at], 0).astype(np.int32)
+    return counts, lo, order.astype(np.int32), pvalid, bvalid
 
 
-@sjit(op="exec.join.expand", static_argnums=(2, 3, 4, 5, 6, 7))
+@sjit(op="exec.join.expand", static_argnums=(7, 8, 9, 10, 11, 12))
 def _expand_join(probe: ColumnarBatch, build: ColumnarBatch,
+                 counts, lo, order, pvalid, bvalid,
                  probe_key_ix: Tuple[int, ...], build_key_ix: Tuple[int, ...],
                  out_cap: int, join_type: str, condition=None,
                  ansi: bool = False):
-    """Phase 2: expand candidate ranges to pairs, equality-check (plus the
-    optional non-equi join condition evaluated on the gathered pair), compact;
-    attach outer rows. Returns (out_vecs, n, bmatched, cond_errs)."""
+    """Phase 2: expand phase 1's candidate ranges (`_probe_counts`' five
+    arrays, passed in) to pairs, equality-check (plus the optional non-equi
+    join condition evaluated on the gathered pair), compact; attach outer
+    rows. Returns (out_vecs, n, bmatched, cond_errs)."""
     xp = jnp
-    counts, lo, order, pvalid, bvalid = _probe_counts(
-        probe, build, probe_key_ix, build_key_ix)
     pvecs = batch_vecs(probe)
     bvecs = batch_vecs(build)
     pkeys = [pvecs[i] for i in probe_key_ix]
@@ -104,10 +149,7 @@ def _expand_join(probe: ColumnarBatch, build: ColumnarBatch,
     bcap = build.capacity
 
     outer_left = join_type in ("left", "full")
-    # unmatched probe rows still emit one row in outer joins
-    slot_counts = xp.maximum(counts, 1) if outer_left else counts
-    slot_counts = xp.where(pmask, slot_counts, 0)
-    offsets = xp.cumsum(slot_counts)
+    offsets = xp.cumsum(_slot_counts(xp, counts, pmask, join_type))
     total = offsets[-1] if pcap > 0 else xp.asarray(0, np.int32)
     j = xp.arange(out_cap, dtype=np.int32)
     live = j < total
@@ -421,16 +463,15 @@ class TpuShuffledHashJoinExec(TpuExec):
         with self.join_time.timed():
             counts, lo, order, pvalid, bvalid = _probe_counts(
                 probe, build, self._lk_ix, self._rk_ix)
-            outer_left = self.join_type in ("left", "full")
-            slot = jnp.where(probe.row_mask(),
-                             jnp.maximum(counts, 1) if outer_left else counts, 0)
-            total = int(jnp.sum(slot))
+            total = int(jnp.sum(_slot_counts(
+                jnp, counts, probe.row_mask(), self.join_type)))
             if self.join_type in ("semi", "anti", "existence"):
                 out_cap = max(row_bucket(max(total, 1), op="join"), probe.capacity)
             else:
                 out_cap = row_bucket(max(total, 1), op="join")
             out_vecs, n, bmatched, cond_errs = _expand_join(
-                probe, build, self._lk_ix, self._rk_ix, out_cap,
+                probe, build, counts, lo, order, pvalid, bvalid,
+                self._lk_ix, self._rk_ix, out_cap,
                 self.join_type, self._bcond, self.conf.is_ansi)
             if self._bcond is not None:
                 from .base import raise_kernel_errors

@@ -210,9 +210,11 @@ def _keys_valid(xp, keys):
 
 
 def candidate_counts(xp, pkeys, bkeys, pmask, bmask):
-    """Per-probe-row candidate counts — the `_probe_counts` sizing values
-    with the row hash routed through the pallas kernel. Feeds the fused
-    stage's single expand-capacity sync."""
+    """Per-probe-row candidate counts by two searches of the 64-bit hashes:
+    the plain statement of what `exec.joins._probe_counts` computes with
+    one search and run lengths, here with the row hash routed through the
+    pallas kernel. The engine's fused stage hands `hash_vecs_pallas` to
+    `_probe_counts` itself; this stays as the counts it is tested against."""
     pvalid = _keys_valid(xp, pkeys) & pmask
     bvalid = _keys_valid(xp, bkeys) & bmask
     ph = hash_vecs_pallas(xp, pkeys).astype(np.int64)
