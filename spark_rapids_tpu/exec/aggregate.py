@@ -284,6 +284,10 @@ class TpuHashAggregateExec(UnaryTpuExec):
                 data = segment_reduce(xp, "count", v.data, gid, cap, valid)
             return [Vec(T.LONG, data.astype(np.int64),
                         xp.ones(cap, dtype=bool))]
+        if isinstance(func, Average) and \
+                isinstance(func.data_type, T.DecimalType):
+            return self._avg_decimal(xp, func, sbufs, bi, gid, cap, row_mask,
+                                     merging, output_partial)
         if isinstance(func, Average):
             if merging:
                 s, sv = seg("sum", sbufs[bi], np.float64)
@@ -304,8 +308,8 @@ class TpuHashAggregateExec(UnaryTpuExec):
             v = sbufs[bi]
             if isinstance(func.data_type, T.DecimalType) and \
                     (is_dec128(func.data_type) or is_dec128(v.dtype)):
-                return [self._sum_dec128(xp, func, v, gid, cap, row_mask,
-                                         output_partial)]
+                return [self._sum_dec128(xp, func.data_type, v, gid, cap,
+                                         row_mask)]
             out_t = func.data_type if not merging else v.dtype
             acc = np.float64 if T.is_floating(out_t) else np.int64
             data, has = seg("sum", v, acc)
@@ -480,8 +484,51 @@ class TpuHashAggregateExec(UnaryTpuExec):
         data = xp.stack([h_ext, out_lo], axis=1)
         return Vec(v.dtype, data, has)
 
-    def _sum_dec128(self, xp, func, v: Vec, gid, cap: int, row_mask,
-                    output_partial: bool) -> Vec:
+    def _avg_decimal(self, xp, func, sbufs: List[Vec], bi: int, gid,
+                     cap: int, row_mask, merging: bool,
+                     output_partial: bool) -> List[Vec]:
+        """Decimal AVG, exact: the sum as Spark's decimal(p + 10, s) through
+        the decimal SUM path and the count as long (partials merge by sum),
+        then sum / count rounded HALF_UP at the result scale in limbs
+        (decimal128.div_count_half_up). Null for a group without a value,
+        or whose sum left its type."""
+        from ..expr.decimal128 import (div_count_half_up, in_bounds,
+                                       is_dec128, pack_limbs, widen_operand)
+        sum_t, out_t = func.sum_type, func.data_type
+        v = sbufs[bi]
+        valid = v.validity & row_mask
+        if is_dec128(sum_t):
+            s = self._sum_dec128(xp, sum_t, v, gid, cap, row_mask)
+        else:  # <= 18 digits: cannot overflow an int64 accumulator
+            data = segment_reduce(xp, "sum", v.data.astype(np.int64), gid,
+                                  cap, valid)
+            s = Vec(sum_t, data, _seg_sum(xp, valid.astype(np.int64), gid,
+                                          cap) > 0)
+        if merging:
+            cv = sbufs[bi + 1]
+            c = segment_reduce(xp, "sum", cv.data.astype(np.int64), gid, cap,
+                               cv.validity & row_mask)
+            # a partial that counted rows and lost its sum (overflow) makes
+            # the merged sum null, as Spark's sum.left + sum.right does
+            lost = row_mask & ~v.validity & (cv.data > 0)
+            s = Vec(sum_t, s.data, s.validity &
+                    (_seg_sum(xp, lost.astype(np.int64), gid, cap) == 0))
+        else:
+            c = segment_reduce(xp, "count", v.data, gid, cap, valid)
+        c = c.astype(np.int64)
+        if output_partial:
+            return [s, Vec(T.LONG, c, xp.ones(cap, dtype=bool))]
+        hi, lo, fits = div_count_half_up(xp, *widen_operand(xp, s),
+                                         sum_t.precision,
+                                         out_t.scale - sum_t.scale, c)
+        ok = s.validity & (c > 0) & fits & \
+            in_bounds(xp, hi, lo, out_t.precision)
+        if is_dec128(out_t):
+            return [Vec(out_t, pack_limbs(xp, hi, lo), ok)]
+        return [Vec(out_t, lo.astype(np.int64), ok)]
+
+    def _sum_dec128(self, xp, out_t, v: Vec, gid, cap: int,
+                    row_mask) -> Vec:
         """Decimal128 SUM via carry-free chunk sums (decimal128.sum_chunks):
         three independent segment-sums reconstruct the 128-bit total.
         Partial buffers carry the same decimal type, so merge passes rerun
@@ -498,7 +545,6 @@ class TpuHashAggregateExec(UnaryTpuExec):
         s1 = _seg_sum(xp, c1, gid, cap)
         s2 = _seg_sum(xp, c2, gid, cap)
         shi, slo = sum_recombine(xp, s0, s1, s2)
-        out_t = func.data_type
         ok = in_bounds(xp, shi, slo, out_t.precision)
         has = _seg_sum(xp, valid.astype(np.int64), gid, cap) > 0
         if is_dec128(out_t):

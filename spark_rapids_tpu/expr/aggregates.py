@@ -134,8 +134,17 @@ class Average(AggregateFunction):
             return T.DecimalType.bounded(ct.precision + 4, ct.scale + 4)
         return T.DOUBLE
 
+    @property
+    def sum_type(self):
+        """The sum buffer: Spark's decimal(p + 10, s) for a decimal child
+        (exact; the final step divides it by the count), else DOUBLE."""
+        ct = self.child.data_type
+        if isinstance(ct, T.DecimalType):
+            return T.DecimalType.bounded(ct.precision + 10, ct.scale)
+        return T.DOUBLE
+
     def partial_types(self):
-        return [T.DOUBLE, T.LONG]
+        return [self.sum_type, T.LONG]
 
     def evaluate_final(self, xp, partials, counts):
         s, c = partials

@@ -63,15 +63,52 @@ class BinaryExpression(Expression):
         return self.children[1]
 
 
+_INTEGRAL_AS_DECIMAL = {T.ByteType: 3, T.ShortType: 5, T.IntegerType: 10,
+                        T.LongType: 20}
+
+
+def _as_decimal_type(e: Expression):
+    """The decimal type Spark's DecimalPrecision gives an operand of decimal
+    arithmetic: a decimal its own, an integral column decimal(3|5|10|20, 0),
+    an integral literal just its digits; None for anything else."""
+    dt = e.data_type
+    if isinstance(dt, T.DecimalType):
+        return dt
+    if not T.is_integral(dt):
+        return None
+    from .base import Literal
+    if isinstance(e, Literal) and e.value is not None:
+        return T.DecimalType(len(str(abs(int(e.value)))), 0)
+    return T.DecimalType(_INTEGRAL_AS_DECIMAL[type(dt)], 0)
+
+
 class BinaryArithmetic(BinaryExpression):
+    def _decimal_types(self):
+        """(left, right) as decimal types when this is decimal arithmetic
+        with an exact kernel (+, -, * with a decimal on either side and a
+        decimal or an integral on the other), else None."""
+        if type(self) not in (Add, Subtract, Multiply):
+            return None
+        lt, rt = self.left.data_type, self.right.data_type
+        if not (isinstance(lt, T.DecimalType) or
+                isinstance(rt, T.DecimalType)):
+            return None
+        pair = _as_decimal_type(self.left), _as_decimal_type(self.right)
+        return None if None in pair else pair
+
     @property
     def data_type(self) -> T.DataType:
-        lt, rt = self.left.data_type, self.right.data_type
-        if isinstance(lt, T.DecimalType) and isinstance(rt, T.DecimalType) \
-                and type(self) in (Add, Subtract):
-            from .decimal128 import add_result_type
-            return add_result_type(lt, rt)
-        return T.numeric_promote(lt, rt)
+        pair = self._decimal_types()
+        if pair is None:
+            return T.numeric_promote(self.left.data_type,
+                                     self.right.data_type)
+        from .decimal128 import add_result_type, adjust_precision_scale
+        if isinstance(self, Multiply):
+            # Spark: precision p1 + p2 + 1, scale s1 + s2, then bounded
+            return adjust_precision_scale(
+                pair[0].precision + pair[1].precision + 1,
+                pair[0].scale + pair[1].scale)
+        return add_result_type(*pair)
 
     def _decimal_addsub(self, ctx: EvalContext, l: Vec, r: Vec) -> Vec:
         """Decimal +/- computed EXACTLY in 256-bit limbs (the JVM uses
@@ -121,10 +158,56 @@ class BinaryArithmetic(BinaryExpression):
             return Vec(out_t, pack_limbs(xp, hi, lo), validity & ok)
         return Vec(out_t, lo.astype(np.int64), validity & ok)
 
+    def _decimal_mul(self, ctx: EvalContext, l: Vec, r: Vec) -> Vec:
+        """Decimal x decimal, EXACT: the product of the magnitudes in 32-bit
+        limbs (`wide_mul`), HALF_UP rounded where Spark's bound lowered the
+        scale, out of range -> null (non-ANSI) or raise (ANSI). How wide the
+        arithmetic is follows from the operand TYPES at trace time: a result
+        of <= 18 digits is one int64 multiply; an ideal precision <= 38
+        cannot overflow and keeps 128 bits of the limbs; past that the full
+        256-bit product is rounded and bounds-checked."""
+        from .decimal128 import (_join32, _split32, abs128, in_bounds,
+                                 limbs_for, neg128, pack_limbs,
+                                 wide_div_pow10_half_up, wide_mul,
+                                 wide_to128, widen_operand)
+        xp = ctx.xp
+        out_t = self.data_type
+        lt, rt = l.dtype, r.dtype
+        validity = and_validity(xp, l.validity, r.validity)
+        ideal_p = lt.precision + rt.precision + 1
+        if ideal_p <= T.DecimalType.MAX_LONG_DIGITS:
+            return Vec(out_t, l.data.astype(np.int64)
+                       * r.data.astype(np.int64), validity)
+        lhi, llo, lneg = abs128(xp, *widen_operand(xp, l))
+        rhi, rlo, rneg = abs128(xp, *widen_operand(xp, r))
+        prod = wide_mul(xp, _split32(xp, lhi, llo)[:limbs_for(lt.precision)],
+                        _split32(xp, rhi, rlo)[:limbs_for(rt.precision)])
+        prod += [xp.zeros_like(prod[0])] * (8 - len(prod))
+        if ideal_p <= T.DecimalType.MAX_PRECISION:
+            hi, lo = _join32(xp, *prod[:4])
+        else:
+            # a non-negative 256-bit value: the signed helpers round and
+            # narrow the magnitude itself
+            prod = wide_div_pow10_half_up(
+                xp, prod, lt.scale + rt.scale - out_t.scale)
+            hi, lo, fits = wide_to128(xp, prod)
+            ok = fits & in_bounds(xp, hi, lo, out_t.precision)
+            if ctx.ansi:
+                ansi_raise(ctx, ~ok & validity, _overflow_msg(out_t))
+            validity = validity & ok
+        nhi, nlo = neg128(xp, hi, lo)
+        neg = lneg != rneg
+        return Vec(out_t, pack_limbs(xp, xp.where(neg, nhi, hi),
+                                     xp.where(neg, nlo, lo)), validity)
+
     def _compute(self, ctx: EvalContext, l: Vec, r: Vec) -> Vec:
-        if isinstance(l.dtype, T.DecimalType) and \
-                isinstance(r.dtype, T.DecimalType) and \
-                type(self) in (Add, Subtract):
+        pair = self._decimal_types()
+        if pair is not None:
+            # an integral operand IS its decimal(p, 0): same unscaled value
+            l = Vec(pair[0], l.data, l.validity)
+            r = Vec(pair[1], r.data, r.validity)
+            if isinstance(self, Multiply):
+                return self._decimal_mul(ctx, l, r)
             return self._decimal_addsub(ctx, l, r)
         l, r, dt = promote_args(ctx.xp, l, r)
         validity = and_validity(ctx.xp, l.validity, r.validity)

@@ -16,6 +16,8 @@ the exec layer converts `Column` <-> `Vec` zero-copy.
 from __future__ import annotations
 
 import dataclasses
+import datetime as _dt
+import decimal as _decimal
 from typing import Any, List, Optional, Sequence, Tuple
 
 import jax
@@ -458,6 +460,10 @@ class Literal(LeafExpression):
             return Vec(dt, data, xp.ones(n, dtype=bool),
                        xp.full((n,), len(b), dtype=xp.int32))
         v = self.value
+        if isinstance(dt, T.TimestampType) and isinstance(v, _dt.datetime):
+            v = _epoch_micros(v)
+        elif isinstance(dt, T.DateType) and isinstance(v, _dt.date):
+            v = v.toordinal() - _EPOCH_ORDINAL
         if isinstance(dt, T.DecimalType):
             import decimal as _d
             if isinstance(v, _d.Decimal):
@@ -498,7 +504,30 @@ def _infer_literal_type(v) -> T.DataType:
         return T.STRING
     if isinstance(v, np.generic):
         return T.from_arrow(__import__("pyarrow").array([v]).type)
+    if isinstance(v, _dt.datetime):  # before date: a datetime is a date too
+        return T.TIMESTAMP
+    if isinstance(v, _dt.date):
+        return T.DATE
+    if isinstance(v, _decimal.Decimal) and v.is_finite():
+        # Spark's Decimal(BigDecimal) + DecimalType.fromDecimal: a negative
+        # scale becomes 0, and the precision is never under the scale
+        _, digits, exp = v.as_tuple()
+        scale = max(-exp, 0)
+        return T.DecimalType(max(len(digits) + max(exp, 0), scale), scale)
     raise TypeError(f"cannot infer literal type for {v!r}")
+
+
+_EPOCH_ORDINAL = _dt.date(1970, 1, 1).toordinal()
+
+
+def _epoch_micros(v: "_dt.datetime") -> int:
+    """Microseconds since the epoch; a naive datetime is UTC, the engine's
+    session time zone."""
+    if v.tzinfo is None:
+        v = v.replace(tzinfo=_dt.timezone.utc)
+    delta = v - _dt.datetime(1970, 1, 1, tzinfo=_dt.timezone.utc)
+    return (delta.days * 86_400 + delta.seconds) * 1_000_000 \
+        + delta.microseconds
 
 
 class AttributeReference(LeafExpression):

@@ -464,6 +464,31 @@ def _cpu_agg(func: AggregateFunction, ctx, b: HostBatch, gid, ng) -> Vec:
                 has[g] = True
                 limbs[g] = split_int(x)
         return Vec(v.dtype, limbs, has)
+    if name == "Average" and isinstance(out_t, T.DecimalType):
+        # exact, in python ints: sum / count HALF_UP at the result scale
+        # (what exec/aggregate._avg_decimal computes in limbs)
+        from ..expr.decimal128 import join_int, split_int
+        sums, cnts = [0] * ng, [0] * ng
+        for i in np.nonzero(v.validity)[0]:
+            sums[gid[i]] += join_int(int(v.data[i, 0]), int(v.data[i, 1])) \
+                if v.data.ndim == 2 else int(v.data[i])
+            cnts[gid[i]] += 1
+        sum_bound = 10 ** func.sum_type.precision - 1
+        out_bound = 10 ** out_t.precision - 1
+        shift = 10 ** (out_t.scale - func.sum_type.scale)
+        vals, ok = [0] * ng, np.zeros(ng, bool)
+        for g in range(ng):
+            if cnts[g] and abs(sums[g]) <= sum_bound:
+                q = (2 * abs(sums[g]) * shift + cnts[g]) // (2 * cnts[g])
+                vals[g] = -q if sums[g] < 0 else q
+                ok[g] = q <= out_bound
+        if out_t.precision > T.DecimalType.MAX_LONG_DIGITS:
+            limbs = np.zeros((ng, 2), np.int64)
+            for g in np.nonzero(ok)[0]:
+                limbs[g] = split_int(vals[g])
+            return Vec(out_t, limbs, ok)
+        return Vec(out_t, np.array([x if o else 0 for x, o in zip(vals, ok)],
+                                   np.int64), ok)
     if name in ("Sum", "Average"):
         acc_t = np.float64 if T.is_floating(v.dtype) or name == "Average" \
             else np.int64

@@ -104,7 +104,7 @@ for cls in (EB.Literal, EB.AttributeReference, EB.BoundReference, EB.Alias):
     expr_rule(cls, _nested38)
 for cls in (EA.Add, EA.Subtract):
     expr_rule(cls, _num38)  # decimal +/- via 128-bit limb kernels
-expr_rule(EA.Multiply, _num)
+expr_rule(EA.Multiply, _num38)  # decimal x decimal exact in 32-bit limbs
 for cls in (EA.Divide, EA.IntegralDivide, EA.Remainder, EA.Pmod):
     expr_rule(cls, _num)
 for cls in (EA.UnaryMinus, EA.Abs):
