@@ -17,10 +17,12 @@ encodings default pyarrow/Spark output actually uses:
       (native C++, srtpu_byte_array_scan) — each length's position depends
       on all previous lengths, the one genuinely sequential step.
   device (the actual data work):
-    * def-level + index expansion: output slot -> run via searchsorted over
-      the run table, bit-packed runs unpacked with vector shifts (1-bit def
-      levels, up-to-32-bit dictionary indices) — values never exist
-      row-wise on the host;
+    * def-level + index expansion: output slot -> run by one mark per run
+      scattered into the slots and a prefix sum over them (`_run_of_slot`:
+      the slots are all queried, in order, so no table is searched),
+      bit-packed runs unpacked with vector shifts (1-bit def levels,
+      up-to-32-bit dictionary indices) — values never exist row-wise on
+      the host;
     * PLAIN values: the raw little-endian byte buffer is shipped once and
       viewed as int32/int64/float32/float64 lanes;
     * DICT values: dictionary gather by expanded indices;
@@ -60,7 +62,7 @@ from typing import Iterator, List, Optional, Tuple
 import numpy as np
 
 from .. import types as T
-from ..columnar.padding import row_bucket
+from ..columnar.padding import LANE, row_bucket
 from ..utils import spans
 
 __all__ = ["DeviceDecodeUnsupported", "columns_supported",
@@ -290,22 +292,77 @@ def _rle_runs(payload: memoryview, num_values: int, bit_width: int = 1):
 # Device kernels
 # ----------------------------------------------------------------------------
 
+def _prefix_sum_i32(x):
+    """Inclusive prefix sum of int32[n]: log2(128) shifted adds inside rows
+    of a (n / 128, 128) view, then the row totals the same way. The same
+    function as `jnp.cumsum`, which costs the v5e compiler 24-33 s for each
+    1M-slot call (its reduce-window) where this costs it half a second and
+    runs as fast (0.66 against 0.81 ms; PERF.md, PR 30). A decode program
+    holds one of these per run table."""
+    import jax.numpy as jnp
+    n = x.shape[0]
+    rows = jnp.pad(x, (0, -n % LANE)).reshape(-1, LANE)
+    step = 1
+    while step < min(n, LANE):
+        rows = rows + jnp.pad(rows[:, :-step], ((0, 0), (step, 0)))
+        step *= 2
+    if rows.shape[0] > 1:
+        before = jnp.pad(_prefix_sum_i32(rows[:, -1])[:-1], (1, 0))
+        rows = rows + before[:, None]
+    return rows.reshape(-1)[:n]
+
+
+def _run_of_slot(counts, cap: int):
+    """(run int32[cap], ends int32[R]): for every output slot j the run of
+    the table that holds it, `searchsorted(cumsum(counts), j, side="right")`
+    clipped to R - 1, and the runs' exclusive end slots. The query is every
+    slot in order, so nothing is searched: a run ends before slot j exactly
+    when its end marks a slot <= j, which is one mark per RUN scattered into
+    the slots and one prefix sum over them. Zero-count runs (`_pad_runs`'
+    tail, real empty runs) stack their marks on one slot and are stepped
+    over as side="right" steps over them; ends at or past `cap` mark no
+    slot. int32 throughout: the callers' totals are row counts <= cap.
+
+    The barrier makes the map one array, computed once. The searches it
+    replaces were loops, which XLA fuses nothing into; without them it
+    fuses the prefix sum into each of the five gathers that read the map,
+    and the decode programs' code, which lives in device memory, grows
+    from 182 to 279 MB (`star.q3`) and from 189 to 500 MB (`lineitem.q1`;
+    sandbox v5e compiler, PR 30)."""
+    import jax.numpy as jnp
+    from jax import lax
+    assert 0 < cap < 2 ** 31, cap
+    ends = jnp.cumsum(counts.astype(jnp.int32))
+    marks = jnp.zeros(cap, jnp.int32).at[ends].add(
+        1, mode="drop", indices_are_sorted=True)
+    run = jnp.clip(_prefix_sum_i32(marks), 0, counts.shape[0] - 1)
+    return lax.optimization_barrier((run, ends))
+
+
+def _slot_in_packed(run, ends, bitoffs, packed, cap: int, bw: int):
+    """(j, bitpos): the slot numbers and, for slots of bit-packed runs,
+    each slot's bit position in `packed`. int32 where the static shapes
+    allow it (64-bit integers are emulated on the chip), int64 where a bit
+    position, a dead slot's included, could pass 2^31."""
+    import jax.numpy as jnp
+    narrow = packed.shape[0] * 8 + cap * bw < 2 ** 31
+    idt = jnp.int32 if narrow else jnp.int64
+    j = jnp.arange(cap, dtype=jnp.int32)
+    base = jnp.where(run > 0, ends[jnp.maximum(run - 1, 0)], 0)
+    bitpos = bitoffs.astype(idt)[run] + (j - base).astype(idt) * bw
+    return j, bitpos
+
+
 @functools.partial(__import__("jax").jit, static_argnums=(5,))
 def _expand_def_levels(kinds, counts, values, bitoffs, packed, cap: int):
     """Run table -> bool[cap] defined mask, entirely on device."""
     import jax.numpy as jnp
-    ends = jnp.cumsum(counts)
-    j = jnp.arange(cap, dtype=jnp.int64)
-    run = jnp.searchsorted(ends, j, side="right")
-    run = jnp.clip(run, 0, counts.shape[0] - 1)
-    base = jnp.where(run > 0, ends[jnp.maximum(run - 1, 0)], 0)
-    within = j - base
-    bitpos = bitoffs[run] + within
+    run, ends = _run_of_slot(counts, cap)
+    j, bitpos = _slot_in_packed(run, ends, bitoffs, packed, cap, 1)
     byte = packed[jnp.clip(bitpos // 8, 0, packed.shape[0] - 1)]
     bit = (byte >> (bitpos % 8).astype(jnp.uint8)) & 1
     lvl = jnp.where(kinds[run] == 1, bit, values[run])
-    total = ends[-1]
-    return (lvl == 1) & (j < total)
+    return (lvl == 1) & (j < ends[-1])
 
 
 @functools.partial(__import__("jax").jit, static_argnums=(5, 6))
@@ -315,13 +372,8 @@ def _expand_rle_u32(kinds, counts, values, bitoffs, packed, cap: int,
     Multi-bit generalization of _expand_def_levels: each output slot
     gathers a (bw+7)/8+1-byte window and shifts its value out."""
     import jax.numpy as jnp
-    ends = jnp.cumsum(counts)
-    j = jnp.arange(cap, dtype=jnp.int64)
-    run = jnp.clip(jnp.searchsorted(ends, j, side="right"),
-                   0, counts.shape[0] - 1)
-    base = jnp.where(run > 0, ends[jnp.maximum(run - 1, 0)], 0)
-    within = j - base
-    bitpos = bitoffs[run] + within * bw
+    run, ends = _run_of_slot(counts, cap)
+    j, bitpos = _slot_in_packed(run, ends, bitoffs, packed, cap, bw)
     b0 = bitpos // 8
     window = jnp.zeros(cap, jnp.uint64)
     for k in range((bw + 7) // 8 + 1):  # bw bits at offset<=7 span this many
@@ -1752,6 +1804,26 @@ def _unpack_traced(packed, meta):
     return arr.reshape(shape)
 
 
+def _merged_slot_source(nrows_arr, caps, cap_total: int):
+    """(src int32[cap_total], live bool[cap_total]): where, in the
+    concatenation of the chunks' outputs (chunk k padded to caps[k]), the
+    row of merged slot j lies, and whether j is a row at all. Slot j
+    belongs to the chunk after every chunk whose rows end at or before j
+    (the last chunk takes the dead tail), and each of those chunks puts
+    its padding, caps[k] - nrows[k], between j and its source. The chunk
+    count is static and small, so that is a compare and a select per
+    chunk, no table to search."""
+    import jax.numpy as jnp
+    assert sum(caps) < 2 ** 31 and cap_total < 2 ** 31, (caps, cap_total)
+    nrows = nrows_arr.astype(jnp.int32)
+    cum = jnp.cumsum(nrows)
+    j = jnp.arange(cap_total, dtype=jnp.int32)
+    src = j
+    for k in range(len(caps) - 1):
+        src = src + jnp.where(j >= cum[k], caps[k] - nrows[k], 0)
+    return src, j < cum[-1]
+
+
 @functools.lru_cache(maxsize=64)
 def _fused_multi_program(groups_sig, caps, cap_total: int):
     """One compiled program decoding SEVERAL row-group chunks and merging
@@ -1760,12 +1832,14 @@ def _fused_multi_program(groups_sig, caps, cap_total: int):
     Takes (nrows int64[nchunks], packed uint8) — two buffers, one program:
     the whole dispatch group costs 3 dispatch events regardless of column
     or chunk count. Chunk results merge by a rank gather: global row j
-    maps to (chunk, within) via searchsorted over the traced cumulative
-    row counts, so tail chunks of any size share the program."""
+    reads the slot `_merged_slot_source` gives it from the traced row
+    counts, so tail chunks of any size share the program. The program
+    holds no loop: every slot -> run and slot -> chunk map is a prefix
+    sum or a compare, never a search (tests/test_parquet_device.py holds
+    that line on the lowering)."""
     import jax.numpy as jnp
     nchunks = len(groups_sig)
     ncols = len(groups_sig[0][0])
-    chunk_base = np.concatenate(([0], np.cumsum(caps)[:-1])).astype(np.int64)
 
     def fn(nrows_arr, packed):
         per_col = [[] for _ in range(ncols)]
@@ -1775,14 +1849,7 @@ def _fused_multi_program(groups_sig, caps, cap_total: int):
             for ci, colsig in enumerate(colsigs):
                 per_col[ci].append(_traced_decode_col(
                     colsig, caps[c_i], nrows_arr[c_i], it))
-        cum = jnp.cumsum(nrows_arr)
-        total = cum[-1]
-        j = jnp.arange(cap_total, dtype=jnp.int64)
-        c_of_j = jnp.clip(jnp.searchsorted(cum, j, side="right"),
-                          0, nchunks - 1)
-        base = jnp.where(c_of_j > 0, cum[jnp.maximum(c_of_j - 1, 0)], 0)
-        src = jnp.asarray(chunk_base)[c_of_j] + (j - base)
-        live = j < total
+        src, live = _merged_slot_source(nrows_arr, caps, cap_total)
         outs = []
         for ci in range(ncols):
             datas = [d for d, _, _ in per_col[ci]]
@@ -2246,7 +2313,6 @@ def _pushdown_select_program(groups_sig, caps, cap_total: int, dev,
     import functools as _ft
     import jax.numpy as jnp
     nchunks = len(groups_sig)
-    chunk_base = np.concatenate(([0], np.cumsum(caps)[:-1])).astype(np.int64)
     leaves_by_col, pred_cols, str_w = _pushdown_plan(
         dev, groups_sig, dev_names, dt_by_name)
     aggs = dev.aggs
@@ -2344,16 +2410,9 @@ def _pushdown_select_program(groups_sig, caps, cap_total: int, dev,
                 valid = jnp.zeros(cap1, bool).at[0].set(anyv)
                 outs.append((data, valid))
             return kept_total, tuple(outs)
-        cum = jnp.cumsum(nrows_arr)
-        total = cum[-1]
-        j = jnp.arange(cap_total, dtype=jnp.int64)
-        c_of = jnp.clip(jnp.searchsorted(cum, j, side="right"),
-                        0, nchunks - 1)
-        base = jnp.where(c_of > 0, cum[jnp.maximum(c_of - 1, 0)], 0)
-        src = jnp.asarray(chunk_base)[c_of] + (j - base)
+        src, live = _merged_slot_source(nrows_arr, caps, cap_total)
         keep_cat = keeps[0] if nchunks == 1 else jnp.concatenate(keeps)
-        keep_g = keep_cat[jnp.clip(src, 0, keep_cat.shape[0] - 1)] & \
-            (j < total)
+        keep_g = keep_cat[jnp.clip(src, 0, keep_cat.shape[0] - 1)] & live
         return keep_g, kept_total
 
     from ..compile import sjit

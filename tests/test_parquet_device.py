@@ -486,3 +486,174 @@ class TestDecimalTimestampDeviceDecode:
         back = batch_to_arrow(b)
         assert back.column("d").to_pylist() == [decimal.Decimal("1.500")]
         assert back.column("l").to_pylist() == [3]
+
+
+def _run_table_cases():
+    """(id, counts, cap) for `_run_of_slot`: the run tables a parquet chunk
+    hands the decoder, and the ones it must not trip over."""
+    r = np.random.default_rng(30)
+    big = r.integers(0, 40, 65536)
+    big[r.random(65536) < 0.2] = 0
+    return [
+        ("total_below_cap", [3, 1, 4, 1, 5], 32),
+        ("total_equals_cap", [8, 8, 8, 8], 32),
+        ("total_above_cap", [20, 20, 20], 32),
+        ("end_on_cap_then_more", [16, 16, 5, 5], 32),
+        ("empty_runs_in_the_middle", [4, 0, 0, 6, 0, 3, 0, 0, 0, 2], 24),
+        ("empty_first_run", [0, 0, 7, 2], 16),
+        ("padded_zero_tail", [5, 9, 2, 0, 0, 0, 0, 0], 32),
+        ("all_runs_empty", [0, 0, 0, 0], 8),
+        ("one_run", [11], 16),
+        ("one_run_past_cap", [40], 16),
+        ("one_slot", [1, 1], 1),
+        ("65536_runs_a_fifth_empty", big, 1 << 20),
+        ("65536_runs_cap_cuts_them", big, 700_001),
+        ("cap_no_multiple_of_a_lane", r.integers(0, 5, 3000), 5000),
+    ]
+
+
+_RUN_TABLES = _run_table_cases()
+
+
+class TestRunOfSlot:
+    """The slot -> run map of every def-level and dictionary-index
+    expansion is `searchsorted(cumsum(counts), arange(cap), "right")`; the
+    decoder computes it without a search (one mark per run, one prefix
+    sum), and the decode programs must stay free of loops."""
+
+    @pytest.mark.parametrize("dtype", [np.int64, np.int32])
+    @pytest.mark.parametrize(
+        "counts,cap", [c[1:] for c in _RUN_TABLES],
+        ids=[c[0] for c in _RUN_TABLES])
+    def test_equals_searchsorted(self, counts, cap, dtype):
+        import jax
+        import jax.numpy as jnp
+        from spark_rapids_tpu.io.parquet_device import _run_of_slot
+        counts = np.asarray(counts, dtype)
+        run, ends = jax.jit(_run_of_slot, static_argnums=1)(
+            jnp.asarray(counts), cap)
+        want_ends = np.cumsum(counts.astype(np.int64))
+        want = np.clip(np.searchsorted(want_ends, np.arange(cap),
+                                       side="right"), 0, len(counts) - 1)
+        assert run.dtype == jnp.int32 and ends.dtype == jnp.int32
+        assert run.shape == (cap,)
+        assert np.array_equal(np.asarray(ends), want_ends)
+        assert np.array_equal(np.asarray(run), want)
+        # and it is jnp.searchsorted's own answer too, not only numpy's
+        got = jnp.clip(jnp.searchsorted(jnp.cumsum(jnp.asarray(counts)),
+                                        jnp.arange(cap), side="right"),
+                       0, len(counts) - 1)
+        assert np.array_equal(np.asarray(run), np.asarray(got))
+
+    @pytest.mark.parametrize("n", [1, 7, 128, 129, 4096, 5000, 16385,
+                                   (1 << 20) + 3])
+    def test_prefix_sum_equals_cumsum(self, n):
+        import jax
+        from spark_rapids_tpu.io.parquet_device import _prefix_sum_i32
+        x = np.random.default_rng(n).integers(0, 4, n).astype(np.int32)
+        got = jax.jit(_prefix_sum_i32)(x)
+        assert got.dtype == np.int32
+        assert np.array_equal(np.asarray(got), np.cumsum(x, dtype=np.int32))
+
+    @pytest.mark.parametrize("nrows,caps,cap_total", [
+        ((700, 300), (1024, 512), 1024),
+        ((1024, 512), (1024, 512), 2048),
+        ((5, 0, 9), (128, 128, 128), 128),
+        ((0, 0), (128, 128), 128),
+        ((77,), (128,), 128),
+    ])
+    def test_merged_slot_source_equals_searchsorted(self, nrows, caps,
+                                                    cap_total):
+        import jax.numpy as jnp
+        from spark_rapids_tpu.io.parquet_device import _merged_slot_source
+        src, live = _merged_slot_source(jnp.asarray(nrows, jnp.int64), caps,
+                                        cap_total)
+        cum = np.cumsum(nrows)
+        j = np.arange(cap_total)
+        c = np.clip(np.searchsorted(cum, j, side="right"), 0, len(caps) - 1)
+        base = np.where(c > 0, cum[np.maximum(c - 1, 0)], 0)
+        chunk_base = np.concatenate(([0], np.cumsum(caps)[:-1]))
+        assert src.dtype == jnp.int32
+        assert np.array_equal(np.asarray(src), chunk_base[c] + (j - base))
+        assert np.array_equal(np.asarray(live), j < cum[-1])
+
+    @staticmethod
+    def _two_row_group_file(tmp_path):
+        """Nulls in every column, a dictionary that grows page by page (so
+        one chunk's index pages come in more than one bit width), a string
+        column, two row groups."""
+        r = np.random.default_rng(7)
+        n = 6000
+        mask = r.random(n) < 0.1
+        grow = np.minimum(np.arange(n) % 3000, r.integers(0, 3000, n))
+        t = pa.table({
+            "k": pa.array(grow * 7, mask=mask),
+            "v": pa.array(r.integers(0, 50, n).astype(np.int32), mask=mask),
+            "s": pa.array(["w%d" % (i % 37) for i in range(n)], mask=mask),
+        })
+        path = str(tmp_path / "two_rg.parquet")
+        pq.write_table(t, path, use_dictionary=True, row_group_size=3000,
+                       data_page_size=512)
+        return path, t
+
+    @staticmethod
+    def _open(session, path):
+        from spark_rapids_tpu.io.parquet_device import file_supported
+        df = session.read_parquet(path)
+        session.initialize_device()
+        return df, df.plan.output, file_supported(path, df.plan.output)
+
+    def test_fused_multi_program_lowers_without_a_loop(self, session,
+                                                       tmp_path):
+        from spark_rapids_tpu.io import parquet_device as pd
+        path, t = self._two_row_group_file(tmp_path)
+        df, schema, pf = self._open(session, path)
+        with open(path, "rb") as f:
+            chunks, total = pd._read_chunks(pf, f, [0, 1], schema)
+        groups_sig, caps, packed, _ = pd._group_signatures(
+            chunks, list(schema.names))
+        widths = {bw for colsigs, _ in groups_sig for cs in colsigs
+                  for bw, _, has_runs in (cs[4] if cs[0] == "string"
+                                          else cs[7]) if has_runs}
+        assert len(widths) > 1, widths      # the file is what it says
+        assert any(cs[1] if cs[0] == "string" else cs[4]
+                   for cs in groups_sig[0][0])      # def levels present
+        program = pd._fused_multi_program(
+            groups_sig, tuple(caps), pd.row_bucket(total))
+        nrows = np.asarray([n for _, _, n in chunks], np.int64)
+        text = program.direct.lower(nrows, packed).as_text()
+        assert "stablehlo.while" not in text
+        assert "stablehlo.scatter" in text      # the marks, one per table
+        # and the batch it decodes is still pyarrow's
+        got = df.collect()
+        for name in t.schema.names:
+            assert got.column(name).to_pylist() == \
+                t.column(name).to_pylist(), name
+
+    def test_fused_decode_program_lowers_without_a_loop(self, session,
+                                                        tmp_path):
+        from spark_rapids_tpu.io import parquet_device as pd
+        path, _ = self._two_row_group_file(tmp_path)
+        _, schema, pf = self._open(session, path)
+        with open(path, "rb") as f:
+            works, nrows = pd._host_phase(pf, f, 0, schema)
+        fused = [w for w in works.values() if w.ship is not None]
+        assert len(fused) >= 2
+        flat = []
+        for w in fused:
+            assert w.defruns is not None
+            flat.extend(w.defruns)
+            flat.extend(w.ship)
+        cap = pd.row_bucket(nrows)
+        program = pd._fused_decode_program(
+            tuple(pd._col_sig(w) for w in fused), cap)
+        text = program.direct.lower(np.int64(nrows), *flat).as_text()
+        assert "stablehlo.while" not in text
+        assert "stablehlo.scatter" in text
+        outs = program(np.int64(nrows), *flat)
+        exact = pq.read_table(path).slice(0, nrows)
+        for w, (data, validity) in zip(fused, outs):
+            want = exact.column(w.name).to_pylist()
+            got = [int(d) if v else None for d, v in
+                   zip(np.asarray(data)[:nrows], np.asarray(validity)[:nrows])]
+            assert got == want, w.name
