@@ -23,11 +23,13 @@ from ..expr.base import Expression, Vec, bind_references, output_name
 from ..expr.aggregates import (AggregateFunction, ApproximatePercentile,
                                Average, CollectList, CollectSet, Count, First,
                                Last, Max, Min, Sum, _VarianceFamily)
-from ..ops.rowops import (compact_vecs, gather_vecs, group_ids_from_sorted,
-                          lexsort_indices, segment_reduce, sort_keys_for)
+from ..ops.rowops import (SortedSegments, compaction_order, gather_vecs,
+                          group_ids_from_sorted, lexsort_indices,
+                          segment_reduce, segment_sum_count, sort_keys_for)
 from ..plan.nodes import AggExpr
 from ..utils import metrics as M
-from .base import TpuExec, UnaryTpuExec, batch_vecs, device_ctx, vecs_to_batch
+from .base import (TpuExec, UnaryTpuExec, batch_vecs, device_ctx,
+                   kernel_errors, vecs_to_batch)
 from .coalesce import concat_batches
 
 
@@ -49,25 +51,65 @@ def _sorted_by_keys(xp, key_vecs: List[Vec], all_vecs: List[Vec], row_mask):
     return gather_vecs(xp, all_vecs, order), row_mask[order], order
 
 
-def _seg_sum(xp, data, gid, cap: int):
-    """Segmented sum supporting 1D and 2D (rows along axis 0) inputs."""
-    import jax
+def _group_segments(xp, skeys: List[Vec], sorted_mask):
+    """(segments, representative key rows) of rows sorted by `skeys` with the
+    live rows first; no keys is one group over the live rows. One sort of a
+    one-byte flag compacts the group-start rows: its permutation gathers
+    the representatives and gives every group's end."""
+    cap = sorted_mask.shape[0]
+    if not skeys:
+        return SortedSegments(xp, xp.zeros(cap, dtype=np.int32),
+                              xp.asarray(1, dtype=np.int32), sorted_mask), []
+    gid, ng, starts = group_ids_from_sorted(xp, skeys, sorted_mask)
+    order = compaction_order(xp, starts)
+    return (SortedSegments(xp, gid, ng, sorted_mask, order),
+            gather_vecs(xp, skeys, order))
+
+
+def _kernel_notes(ctx, box: list, segs: SortedSegments):
+    """What a kernel's trace leaves its host side, in the kernel's box (which
+    the compile service restores when the program comes from a cache): the
+    ANSI messages, one per returned error flag, then the two counts of
+    segmented reductions by route (`_run` adds them to the metrics)."""
+    flags = kernel_errors(ctx, box)
+    box.extend((segs.prefix_routed, segs.scattered))
+    return flags
+
+
+def _seg_sum(xp, data, segs: SortedSegments):
+    """Segmented sum supporting 1D and 2D (rows along axis 0) inputs; dead
+    rows hold zero."""
     if xp is np:
-        out = np.zeros((cap,) + data.shape[1:], dtype=data.dtype)
-        np.add.at(out, gid, data)
+        out = np.zeros((segs.cap,) + data.shape[1:], dtype=data.dtype)
+        np.add.at(out, segs.gid, data)
         return out
-    return jax.ops.segment_sum(data, gid, num_segments=cap)
+    return segs.sum(data)
 
 
-def _seg_minmax_2d(xp, op: str, data, gid, cap: int, neutral):
+def _seg_count(xp, flags, segs: SortedSegments):
+    """Per-group count of the rows where `flags` (bool, false on dead rows),
+    int64."""
+    if xp is np:
+        return _seg_sum(xp, flags.astype(np.int64), segs)
+    return segs.count(flags)
+
+
+def _seg_sums(xp, segs: SortedSegments, *contribs):
+    """Per-group int64 totals of several integer or bool (cap,)
+    contributions over the same rows, zero / false on dead rows: one stacked
+    reduction on the device."""
+    if xp is np:
+        return tuple(_seg_sum(xp, c.astype(np.int64), segs) for c in contribs)
+    return segs.sums(*contribs)
+
+
+def _seg_minmax_2d(xp, op: str, data, segs: SortedSegments, neutral):
     """Segmented min/max over a 2D matrix (invalid rows pre-neutralized)."""
-    import jax
     if xp is np:
-        out = np.full((cap, data.shape[1]), neutral, dtype=data.dtype)
-        (np.minimum if op == "min" else np.maximum).at(out, gid, data)
+        out = np.full((segs.cap, data.shape[1]), neutral, dtype=data.dtype)
+        (np.minimum if op == "min" else np.maximum).at(out, segs.gid, data)
         return out
-    f = jax.ops.segment_min if op == "min" else jax.ops.segment_max
-    return f(data, gid, num_segments=cap)
+    return segs.minmax(op, data)
 
 
 class TpuHashAggregateExec(UnaryTpuExec):
@@ -106,6 +148,10 @@ class TpuHashAggregateExec(UnaryTpuExec):
                 f = f.with_children([bind_references(f.child, bind_schema)])
             self._bound_aggs.append(AggExpr(f, a.name))
         self.agg_time = self.metrics.create(M.AGG_TIME, M.MODERATE)
+        self.prefix_reductions = self.metrics.create(
+            M.NUM_PREFIX_REDUCTIONS, M.MODERATE)
+        self.scatter_reductions = self.metrics.create(
+            M.NUM_SCATTER_REDUCTIONS, M.MODERATE)
         self._sp_maxes_jit = None
         self._sp_kernel_jit: dict = {}
 
@@ -164,7 +210,6 @@ class TpuHashAggregateExec(UnaryTpuExec):
 
     # ------------------------------------------------------------------
     def _make_kernel(self, input_partial: bool, output_partial: bool):
-        from .base import kernel_errors
         bound_groups = self._bound_groups
         bound_aggs = self._bound_aggs
         out_schema = self._partial_schema if output_partial else self._schema
@@ -206,29 +251,21 @@ class TpuHashAggregateExec(UnaryTpuExec):
                     xp, keys, all_vecs, mask)
                 skeys = sorted_vecs[:len(keys)]
                 sbufs = sorted_vecs[len(keys):]
-                gid, ng, starts = group_ids_from_sorted(xp, skeys, sorted_mask)
             else:
                 sorted_vecs, sorted_mask = (
                     [v for grp in buf_vecs for v in grp], mask)
                 skeys, sbufs = [], sorted_vecs
-                gid = xp.zeros(cap, dtype=np.int32)
-                ng = xp.asarray(1, dtype=np.int32)
-                starts = xp.arange(cap) == 0
+            segs, reps = _group_segments(xp, skeys, sorted_mask)
 
-            out_vecs: List[Vec] = []
-            # representative key rows: compact group-start rows to the front
-            if skeys:
-                reps, _ = compact_vecs(xp, skeys, starts)
-                out_vecs.extend(reps)
-
+            out_vecs: List[Vec] = list(reps)
             bi = 0
             for a in bound_aggs:
-                out_vecs.extend(self._agg_one(xp, a.func, sbufs, bi, gid, cap,
+                out_vecs.extend(self._agg_one(xp, a.func, sbufs, bi, segs,
                                               sorted_mask, input_partial,
                                               output_partial, ctx=ctx))
                 bi += len(a.func.partial_types()) if input_partial else 1
-            return vecs_to_batch(out_schema, out_vecs, ng), \
-                kernel_errors(ctx, msgs_box)
+            return vecs_to_batch(out_schema, out_vecs, segs.num_groups), \
+                _kernel_notes(ctx, msgs_box, segs)
 
         # merge/final kernels (input_partial) only read partial buffers —
         # never the black-box expressions — so they stay jitted even in
@@ -254,12 +291,14 @@ class TpuHashAggregateExec(UnaryTpuExec):
         (single-pass kernels share self._err_msgs; see __init__)."""
         from .base import raise_kernel_errors
         out, errs = kernel(batch)
-        raise_kernel_errors(errs, self._kernel_boxes.get(kernel,
-                                                         self._err_msgs))
+        box = self._kernel_boxes.get(kernel, self._err_msgs)
+        raise_kernel_errors(errs, box)
+        self.prefix_reductions.add(box[-2])     # _kernel_notes' tail
+        self.scatter_reductions.add(box[-1])
         return out
 
     def _agg_one(self, xp, func: AggregateFunction, sbufs: List[Vec], bi: int,
-                 gid, cap: int, row_mask, input_partial: bool,
+                 segs: SortedSegments, row_mask, input_partial: bool,
                  output_partial: bool, ctx=None) -> List[Vec]:
         """Produce output vecs for one aggregate (list of partial buffers when
         output_partial, single final value otherwise). `ctx` (when given)
@@ -267,12 +306,16 @@ class TpuHashAggregateExec(UnaryTpuExec):
         reports through it (Spark ANSI raises on BIGINT sum overflow; the
         reference checks the accumulator the same way)."""
         merging = input_partial
+        cap = segs.cap
 
         def seg(op, v: Vec, acc_dtype=None):
             valid = v.validity & row_mask
             data = v.data if acc_dtype is None else v.data.astype(acc_dtype)
-            out = segment_reduce(xp, op, data, gid, cap, valid)
-            cnt = segment_reduce(xp, "count", data, gid, cap, valid)
+            if op == "sum":
+                out, cnt = segment_sum_count(xp, data, segs, valid)
+            else:
+                out = segment_reduce(xp, op, data, segs, valid)
+                cnt = segment_reduce(xp, "count", data, segs, valid)
             return out, cnt > 0
 
         if isinstance(func, Count):
@@ -281,12 +324,12 @@ class TpuHashAggregateExec(UnaryTpuExec):
                 data, _ = seg("sum", v, np.int64)
             else:
                 valid = v.validity & row_mask
-                data = segment_reduce(xp, "count", v.data, gid, cap, valid)
+                data = segment_reduce(xp, "count", v.data, segs, valid)
             return [Vec(T.LONG, data.astype(np.int64),
                         xp.ones(cap, dtype=bool))]
         if isinstance(func, Average) and \
                 isinstance(func.data_type, T.DecimalType):
-            return self._avg_decimal(xp, func, sbufs, bi, gid, cap, row_mask,
+            return self._avg_decimal(xp, func, sbufs, bi, segs, row_mask,
                                      merging, output_partial)
         if isinstance(func, Average):
             if merging:
@@ -296,7 +339,7 @@ class TpuHashAggregateExec(UnaryTpuExec):
                 v = sbufs[bi]
                 s, sv = seg("sum", v, np.float64)
                 valid = v.validity & row_mask
-                c = segment_reduce(xp, "count", v.data, gid, cap, valid)
+                c = segment_reduce(xp, "count", v.data, segs, valid)
             if output_partial:
                 return [Vec(T.DOUBLE, s, c > 0),
                         Vec(T.LONG, c.astype(np.int64),
@@ -308,8 +351,8 @@ class TpuHashAggregateExec(UnaryTpuExec):
             v = sbufs[bi]
             if isinstance(func.data_type, T.DecimalType) and \
                     (is_dec128(func.data_type) or is_dec128(v.dtype)):
-                return [self._sum_dec128(xp, func.data_type, v, gid, cap,
-                                         row_mask)]
+                return [self._sum_dec128(xp, func.data_type, v, segs,
+                                         row_mask)[0]]
             out_t = func.data_type if not merging else v.dtype
             acc = np.float64 if T.is_floating(out_t) else np.int64
             data, has = seg("sum", v, acc)
@@ -335,9 +378,9 @@ class TpuHashAggregateExec(UnaryTpuExec):
             op = "min" if isinstance(func, Min) else "max"
             v = sbufs[bi]
             if v.is_string:
-                return [self._minmax_string(xp, op, v, gid, cap, row_mask)]
+                return [self._minmax_string(xp, op, v, segs, row_mask)]
             if is_dec128(v.dtype):
-                return [self._minmax_dec128(xp, op, v, gid, cap, row_mask)]
+                return [self._minmax_dec128(xp, op, v, segs, row_mask)]
             data, has = seg(op, v)
             return [Vec(v.dtype, data.astype(v.dtype.np_dtype), has)]
         if isinstance(func, _VarianceFamily):
@@ -352,7 +395,7 @@ class TpuHashAggregateExec(UnaryTpuExec):
                 s, _ = seg("sum", Vec(T.DOUBLE, x, v.validity), np.float64)
                 s2, _ = seg("sum", Vec(T.DOUBLE, x * x, v.validity),
                             np.float64)
-                c = segment_reduce(xp, "count", x, gid, cap,
+                c = segment_reduce(xp, "count", x, segs,
                                    v.validity & row_mask).astype(np.int64)
             if output_partial:
                 return [Vec(T.DOUBLE, s, c > 0), Vec(T.DOUBLE, s2, c > 0),
@@ -376,7 +419,7 @@ class TpuHashAggregateExec(UnaryTpuExec):
                 data, _ = seg("sum", v, np.int64)
             else:
                 hit = v.validity & row_mask & v.data.astype(bool)
-                data = _seg_sum(xp, hit.astype(np.int64), gid, cap)
+                data = _seg_count(xp, hit, segs)
             return [Vec(T.LONG, data.astype(np.int64),
                         xp.ones(cap, dtype=bool))]
         if isinstance(func, (BoolAnd, BoolOr)):
@@ -386,8 +429,8 @@ class TpuHashAggregateExec(UnaryTpuExec):
             contrib = xp.where(valid, v.data.astype(np.int8),
                                np.int8(1 if is_and else 0))
             out = segment_reduce(xp, "min" if is_and else "max", contrib,
-                                 gid, cap, row_mask)
-            has = _seg_sum(xp, valid.astype(np.int64), gid, cap) > 0
+                                 segs, row_mask)
+            has = _seg_count(xp, valid, segs) > 0
             return [Vec(T.BOOLEAN, out.astype(bool), has)]
         if isinstance(func, _BitAgg):
             v = sbufs[bi]
@@ -398,15 +441,15 @@ class TpuHashAggregateExec(UnaryTpuExec):
             bits = ((x[:, None] >> shifts) & 1).astype(np.int8)
             if func.op == "and":
                 bits = xp.where(valid[:, None], bits, np.int8(1))
-                red = _seg_minmax_2d(xp, "min", bits, gid, cap, np.int8(1))
+                red = _seg_minmax_2d(xp, "min", bits, segs, np.int8(1))
             elif func.op == "or":
                 bits = xp.where(valid[:, None], bits, np.int8(0))
-                red = _seg_minmax_2d(xp, "max", bits, gid, cap, np.int8(0))
+                red = _seg_minmax_2d(xp, "max", bits, segs, np.int8(0))
             else:  # xor = per-bit parity
                 bits = xp.where(valid[:, None], bits, np.int8(0))
-                red = _seg_sum(xp, bits.astype(np.int64), gid, cap) & 1
+                red = _seg_sum(xp, bits.astype(np.int32), segs) & 1
             val = (red.astype(np.int64) << shifts).sum(axis=1)
-            has = _seg_sum(xp, valid.astype(np.int64), gid, cap) > 0
+            has = _seg_count(xp, valid, segs) > 0
             return [Vec(func.data_type,
                         val.astype(func.data_type.np_dtype), has)]
         if isinstance(func, _MomentFamily):
@@ -426,8 +469,7 @@ class TpuHashAggregateExec(UnaryTpuExec):
                     pows.append(seg("sum", Vec(T.DOUBLE, x ** p, vv),
                                     np.float64)[0])
                 s1, s2, s3, s4 = pows
-                c = _seg_sum(xp, (vv & row_mask).astype(np.int64), gid,
-                             cap)
+                c = _seg_count(xp, vv & row_mask, segs)
             if output_partial:
                 ones = xp.ones(cap, dtype=bool)
                 return [Vec(T.DOUBLE, s1, c > 0), Vec(T.DOUBLE, s2, c > 0),
@@ -455,15 +497,15 @@ class TpuHashAggregateExec(UnaryTpuExec):
             idx = xp.arange(cap, dtype=np.int64)
             sentinel = np.int64(cap)
             key = xp.where(valid, idx, sentinel if is_first else np.int64(-1))
-            pick = segment_reduce(xp, "min" if is_first else "max", key, gid,
-                                  cap, row_mask)
+            pick = segment_reduce(xp, "min" if is_first else "max", key,
+                                  segs, row_mask)
             got = (pick != sentinel) if is_first else (pick >= 0)
             safe = xp.clip(pick, 0, cap - 1)
             out = gather_vecs(xp, [v], safe)[0]
             return [Vec(out.dtype, out.data, out.validity & got, out.lengths)]
         raise NotImplementedError(type(func).__name__)
 
-    def _minmax_dec128(self, xp, op: str, v: Vec, gid, cap: int,
+    def _minmax_dec128(self, xp, op: str, v: Vec, segs: SortedSegments,
                        row_mask) -> Vec:
         """128-bit extremum in two ordered passes: segment-extreme of the
         high limb, then of the unsigned low order among rows matching it —
@@ -475,17 +517,17 @@ class TpuHashAggregateExec(UnaryTpuExec):
         info = np.iinfo(np.int64)
         neutral = info.max if op == "min" else info.min
         hi_m = xp.where(valid, hi, neutral)
-        h_ext = segment_reduce(xp, op, hi_m, gid, cap, row_mask)
-        cand = valid & (hi == h_ext[gid])
+        h_ext = segment_reduce(xp, op, hi_m, segs, row_mask)
+        cand = valid & (hi == h_ext[segs.gid])
         lo_m = xp.where(cand, lo_key, neutral)
-        l_ext = segment_reduce(xp, op, lo_m, gid, cap, row_mask)
+        l_ext = segment_reduce(xp, op, lo_m, segs, row_mask)
         out_lo = _s(xp, _u(xp, l_ext) ^ np.uint64(1 << 63))
-        has = _seg_sum(xp, valid.astype(np.int64), gid, cap) > 0
+        has = _seg_count(xp, valid, segs) > 0
         data = xp.stack([h_ext, out_lo], axis=1)
         return Vec(v.dtype, data, has)
 
-    def _avg_decimal(self, xp, func, sbufs: List[Vec], bi: int, gid,
-                     cap: int, row_mask, merging: bool,
+    def _avg_decimal(self, xp, func, sbufs: List[Vec], bi: int,
+                     segs: SortedSegments, row_mask, merging: bool,
                      output_partial: bool) -> List[Vec]:
         """Decimal AVG, exact: the sum as Spark's decimal(p + 10, s) through
         the decimal SUM path and the count as long (partials merge by sum),
@@ -496,26 +538,30 @@ class TpuHashAggregateExec(UnaryTpuExec):
                                        is_dec128, pack_limbs, widen_operand)
         sum_t, out_t = func.sum_type, func.data_type
         v = sbufs[bi]
+        cap = segs.cap
         valid = v.validity & row_mask
-        if is_dec128(sum_t):
-            s = self._sum_dec128(xp, sum_t, v, gid, cap, row_mask)
-        else:  # <= 18 digits: cannot overflow an int64 accumulator
-            data = segment_reduce(xp, "sum", v.data.astype(np.int64), gid,
-                                  cap, valid)
-            s = Vec(sum_t, data, _seg_sum(xp, valid.astype(np.int64), gid,
-                                          cap) > 0)
+        merged = ()
         if merging:
+            # the partial counts, and the partials that counted rows and
+            # lost their sum (overflow): they make the merged sum null, as
+            # Spark's sum.left + sum.right does
             cv = sbufs[bi + 1]
-            c = segment_reduce(xp, "sum", cv.data.astype(np.int64), gid, cap,
-                               cv.validity & row_mask)
-            # a partial that counted rows and lost its sum (overflow) makes
-            # the merged sum null, as Spark's sum.left + sum.right does
-            lost = row_mask & ~v.validity & (cv.data > 0)
-            s = Vec(sum_t, s.data, s.validity &
-                    (_seg_sum(xp, lost.astype(np.int64), gid, cap) == 0))
+            merged = (xp.where(cv.validity & row_mask, cv.data, 0),
+                      row_mask & ~v.validity & (cv.data > 0))
+        # the count (over raw rows the average's own) rides with the sum
+        if is_dec128(sum_t):
+            s, n, *merged = self._sum_dec128(xp, sum_t, v, segs, row_mask,
+                                             merged)
+        else:  # <= 18 digits: cannot overflow an int64 accumulator
+            data, n, *merged = _seg_sums(
+                xp, segs, xp.where(valid, v.data.astype(np.int64), 0), valid,
+                *merged)
+            s = Vec(sum_t, data, n > 0)
+        if merging:
+            c, lost = merged
+            s = Vec(sum_t, s.data, s.validity & (lost == 0))
         else:
-            c = segment_reduce(xp, "count", v.data, gid, cap, valid)
-        c = c.astype(np.int64)
+            c = n
         if output_partial:
             return [s, Vec(T.LONG, c, xp.ones(cap, dtype=bool))]
         hi, lo, fits = div_count_half_up(xp, *widen_operand(xp, s),
@@ -527,12 +573,15 @@ class TpuHashAggregateExec(UnaryTpuExec):
             return [Vec(out_t, pack_limbs(xp, hi, lo), ok)]
         return [Vec(out_t, lo.astype(np.int64), ok)]
 
-    def _sum_dec128(self, xp, out_t, v: Vec, gid, cap: int,
-                    row_mask) -> Vec:
+    def _sum_dec128(self, xp, out_t, v: Vec, segs: SortedSegments,
+                    row_mask, more=()):
         """Decimal128 SUM via carry-free chunk sums (decimal128.sum_chunks):
         three independent segment-sums reconstruct the 128-bit total.
         Partial buffers carry the same decimal type, so merge passes rerun
-        the identical kernel. Overflow past precision -> null (Spark)."""
+        the identical kernel. Overflow past precision -> null (Spark).
+        Returns (sum, count of valid rows, totals of `more`): the chunks,
+        the count and the caller's further contributions are one stacked
+        reduction."""
         from ..expr.decimal128 import (in_bounds, is_dec128, pack_limbs,
                                        sum_chunks, sum_recombine,
                                        widen_operand)
@@ -540,28 +589,29 @@ class TpuHashAggregateExec(UnaryTpuExec):
         hi, lo = widen_operand(xp, v)
         hi = xp.where(valid, hi, np.int64(0))
         lo = xp.where(valid, lo, np.int64(0))
-        c0, c1, c2 = sum_chunks(xp, hi, lo)
-        s0 = _seg_sum(xp, c0, gid, cap)
-        s1 = _seg_sum(xp, c1, gid, cap)
-        s2 = _seg_sum(xp, c2, gid, cap)
+        s0, s1, s2, count, *more = _seg_sums(
+            xp, segs, *sum_chunks(xp, hi, lo), valid, *more)
         shi, slo = sum_recombine(xp, s0, s1, s2)
-        ok = in_bounds(xp, shi, slo, out_t.precision)
-        has = _seg_sum(xp, valid.astype(np.int64), gid, cap) > 0
-        if is_dec128(out_t):
-            return Vec(out_t, pack_limbs(xp, shi, slo), has & ok)
-        return Vec(out_t, slo.astype(np.int64), has & ok)
+        ok = (count > 0) & in_bounds(xp, shi, slo, out_t.precision)
+        data = pack_limbs(xp, shi, slo) if is_dec128(out_t) else \
+            slo.astype(np.int64)
+        return (Vec(out_t, data, ok), count, *more)
 
-    def _minmax_string(self, xp, op: str, v: Vec, gid, cap: int, row_mask) -> Vec:
+    def _minmax_string(self, xp, op: str, v: Vec, segs: SortedSegments,
+                       row_mask) -> Vec:
         """min/max over strings: segmented argmin via ordering keys is complex;
         use iterative halving? Round 1: order rows by (gid, string) and take the
         group-start (min) / group-end (max) row."""
+        gid, cap = segs.gid, segs.cap
         valid = v.validity & row_mask
         groups = [[gid.astype(np.int32)]]
         groups.append([(~valid).astype(np.int8)])  # invalid rows last
         groups.append(sort_keys_for(xp, v, op == "min", False)[1:])
         order = lexsort_indices(xp, groups, cap)
         sv = gather_vecs(xp, [v], order)[0]
-        sgid = gid[order]
+        # gid is sorted already and leads the keys: the order moves rows
+        # inside their groups only, so gid[order] is gid and `segs` holds
+        sgid = gid
         svalid = valid[order]
         # first row of each gid run in this ordering is the min (or max)
         first_of_gid = xp.concatenate(
@@ -570,8 +620,8 @@ class TpuHashAggregateExec(UnaryTpuExec):
         out = segment_reduce(xp, "max", xp.where(first_of_gid,
                                                  xp.arange(cap, dtype=np.int64),
                                                  np.int64(-1)),
-                             sgid, cap, xp.ones(cap, dtype=bool))
-        has = segment_reduce(xp, "count", sv.data[:, 0], sgid, cap, svalid) > 0
+                             segs, xp.ones(cap, dtype=bool))
+        has = segment_reduce(xp, "count", sv.data[:, 0], segs, svalid) > 0
         safe = xp.clip(out, 0, cap - 1)
         res = gather_vecs(xp, [sv], safe)[0]
         return Vec(v.dtype, res.data, has, res.lengths)
@@ -622,14 +672,14 @@ class TpuHashAggregateExec(UnaryTpuExec):
         """Phase 1: max per-group valid count for each single-pass aggregate
         (host picks the fanout bucket from these)."""
         xp = jnp
-        _, svals, gid, ng, starts, smask, _ = self._sp_prepare(xp, batch)
+        _, svals, segs, _, smask, _ = self._sp_prepare(xp, batch)
         cap = batch.capacity
         out = []
         for a, v in zip(self._bound_aggs, svals):
             if not a.func.single_pass:
                 continue
             data = v.data if v.data.ndim == 1 else v.lengths
-            counts = segment_reduce(xp, "count", data, gid, cap,
+            counts = segment_reduce(xp, "count", data, segs,
                                     v.validity & smask)
             out.append(xp.max(counts).astype(np.int32))
         return tuple(out)
@@ -637,28 +687,23 @@ class TpuHashAggregateExec(UnaryTpuExec):
     def _sp_kernel(self, batch: ColumnarBatch, ks: tuple):
         """Phase 2: full output kernel with static fanout buckets per
         single-pass aggregate; normal aggregates ride along."""
-        from .base import kernel_errors
         xp = jnp
-        skeys, svals, gid, ng, starts, smask, ctx = \
-            self._sp_prepare(xp, batch)
+        _, svals, segs, reps, smask, ctx = self._sp_prepare(xp, batch)
         cap = batch.capacity
-        out_vecs: List[Vec] = []
-        if skeys:
-            reps, _ = compact_vecs(xp, skeys, starts)
-            out_vecs.extend(reps)
+        out_vecs: List[Vec] = list(reps)
         ki = 0
         for a, v in zip(self._bound_aggs, svals):
             if a.func.single_pass:
-                out_vecs.extend(self._sp_agg_one(xp, a.func, v, gid, cap,
-                                                 smask, ks[ki]))
+                out_vecs.extend(self._sp_agg_one(xp, a.func, v, segs, smask,
+                                                 ks[ki]))
                 ki += 1
             else:
                 buf = [v] if v is not None else \
                     [Vec(T.LONG, xp.ones(cap, dtype=np.int64), smask)]
-                out_vecs.extend(self._agg_one(xp, a.func, buf, 0, gid, cap,
+                out_vecs.extend(self._agg_one(xp, a.func, buf, 0, segs,
                                               smask, False, False, ctx=ctx))
-        return vecs_to_batch(self._schema, out_vecs, ng), \
-            kernel_errors(ctx, self._err_msgs)
+        return vecs_to_batch(self._schema, out_vecs, segs.num_groups), \
+            _kernel_notes(ctx, self._err_msgs, segs)
 
     def _sp_prepare(self, xp, batch: ColumnarBatch):
         """Evaluate keys + agg children and sort everything by the keys; the
@@ -678,27 +723,26 @@ class TpuHashAggregateExec(UnaryTpuExec):
             skeys = sorted_vecs[:len(keys)]
             rest = iter(sorted_vecs[len(keys):])
             svals = [None if v is None else next(rest) for v in vals]
-            gid, ng, starts = group_ids_from_sorted(xp, skeys, sorted_mask)
         else:
             skeys, svals, sorted_mask = [], vals, mask
-            gid = xp.zeros(cap, dtype=np.int32)
-            ng = xp.asarray(1, dtype=np.int32)
-            starts = xp.arange(cap) == 0
-        return skeys, svals, gid, ng, starts, sorted_mask, ctx
+        segs, reps = _group_segments(xp, skeys, sorted_mask)
+        return skeys, svals, segs, reps, sorted_mask, ctx
 
-    def _sp_agg_one(self, xp, func, v: Vec, gid, cap, row_mask, k: int):
+    def _sp_agg_one(self, xp, func, v: Vec, segs: SortedSegments, row_mask,
+                    k: int):
         """One single-pass aggregate over key-sorted rows: re-sort its rows by
         (gid, validity, value) and build the per-group result."""
+        gid, cap = segs.gid, segs.cap
         valid = v.validity & row_mask
         groups = [[gid.astype(np.int32)], [(~valid).astype(np.int8)]]
         groups.append(sort_keys_for(xp, v, True, False)[1:])
         order = lexsort_indices(xp, groups, cap)
         sv = gather_vecs(xp, [v], order)[0]
-        sgid = gid[order]
+        sgid = gid      # == gid[order], as in _minmax_string
         svalid = valid[order]
 
         counts = segment_reduce(xp, "count", sv.data if sv.data.ndim == 1
-                                else sv.lengths, sgid, cap, svalid) \
+                                else sv.lengths, segs, svalid) \
             .astype(np.int32)
         if isinstance(func, CollectSet):
             prev_same = xp.concatenate(
@@ -707,14 +751,14 @@ class TpuHashAggregateExec(UnaryTpuExec):
             svalid = svalid & ~prev_same
             counts = segment_reduce(
                 xp, "count", sv.data if sv.data.ndim == 1 else sv.lengths,
-                sgid, cap, svalid).astype(np.int32)
+                segs, svalid).astype(np.int32)
         if isinstance(func, (CollectList, CollectSet)):
             # rank of each kept row within its group (segmented cumsum)
             cs = xp.cumsum(svalid.astype(np.int32))
             base = segment_reduce(
                 xp, "min", xp.where(svalid, cs - 1,
                                     np.int32(2**31 - 1)).astype(np.int64),
-                sgid, cap, xp.ones(cap, dtype=bool)).astype(np.int32)
+                segs, xp.ones(cap, dtype=bool)).astype(np.int32)
             rank = cs - 1 - base[sgid]
             # invalid rows scatter out of bounds and are DROPPED (mode=drop) —
             # scatter-set keeps negative values intact (a scatter-max over a
@@ -736,8 +780,7 @@ class TpuHashAggregateExec(UnaryTpuExec):
         # approx_percentile: nearest-rank selection over the sorted values
         first_pos = segment_reduce(
             xp, "min", xp.where(svalid, xp.arange(cap, dtype=np.int64),
-                                np.int64(cap)), sgid, cap,
-            xp.ones(cap, dtype=bool))
+                                np.int64(cap)), segs, xp.ones(cap, dtype=bool))
         vals = sv.data.astype(np.float64)
         outs = []
         for q in func.percentages:

@@ -14,8 +14,10 @@ Design notes (ARCHITECTURE.md #4):
     overflow), descending floats negate, strings contribute big-endian words;
   * every device sort is a chain of single-key stable sorts with int32 indices
     (`stable_lexsort`): the chip's compiler takes minutes over one variadic sort;
-  * grouping = sort by keys + boundary detection + segment_{sum,min,max} with the
-    static capacity as num_segments.
+  * grouping = sort by keys + boundary detection + segmented reductions over the
+    static capacity: integer sums and counts as a prefix sum and a difference at
+    the group ends (`SortedSegments`, no scatter), floating sums and min/max as
+    segment_{sum,min,max} with the capacity as num_segments.
 """
 
 from __future__ import annotations
@@ -28,6 +30,7 @@ from .. import types as T
 from ..expr.base import Vec
 
 BIG_I32 = np.int32(2 ** 31 - 1)
+LANE = 128
 
 
 def _take(xp, arr, idx):
@@ -62,10 +65,15 @@ def stable_lexsort(xp, keys: Sequence):
     return perm
 
 
+def compaction_order(xp, keep_mask):
+    """Stable permutation that moves the rows where keep_mask to the front."""
+    return stable_lexsort(xp, [(~keep_mask).astype(np.int8)])
+
+
 def compact_vecs(xp, vecs: Sequence[Vec], keep_mask) -> Tuple[List[Vec], any]:
     """Stable-move rows where keep_mask (bool[cap]) to the front; returns
     (columns, new_count). Padding tail contents are unspecified."""
-    order = stable_lexsort(xp, [(~keep_mask).astype(np.int8)])
+    order = compaction_order(xp, keep_mask)
     new_count = xp.sum(keep_mask).astype(np.int32)
     return gather_vecs(xp, vecs, order), new_count
 
@@ -174,6 +182,30 @@ def key_change_flags(xp, key_vecs: Sequence[Vec], n: int):
     return change
 
 
+def prefix_sum(x):
+    """Inclusive prefix sum along the last axis of an int32 or int64 array,
+    (n,) or (K, n): log2(128) shifted adds inside rows of a (n / 128, 128)
+    view, then the row totals the same way. Device only. The same function
+    as `jnp.cumsum`, whose reduce-window costs the v5e compiler 24-33 s for
+    each 1M-slot call (PERF.md, PR 30) where this costs it half a second;
+    `io/parquet_device._prefix_sum_i32` is the int32 (n,) case of it."""
+    import jax.numpy as jnp
+    n = x.shape[-1]
+    if n == 0:
+        return x
+    lead = [(0, 0)] * (x.ndim - 1)
+    rows = jnp.pad(x, lead + [(0, -n % LANE)]).reshape(
+        x.shape[:-1] + (-1, LANE))
+    step = 1
+    while step < min(n, LANE):
+        rows = rows + jnp.pad(rows[..., :-step], lead + [(0, 0), (step, 0)])
+        step *= 2
+    if rows.shape[-2] > 1:
+        before = jnp.pad(prefix_sum(rows[..., -1])[..., :-1], lead + [(1, 0)])
+        rows = rows + before[..., None]
+    return rows.reshape(x.shape[:-1] + (-1,))[..., :n]
+
+
 def group_ids_from_sorted(xp, key_vecs: Sequence[Vec], row_mask):
     """After sorting by keys, compute (group_id[cap], num_groups, starts_mask).
     Padding rows get group_id == cap-1 sentinel region handled by callers via
@@ -183,10 +215,122 @@ def group_ids_from_sorted(xp, key_vecs: Sequence[Vec], row_mask):
     starts = change | (xp.arange(n) == 0)
     starts = starts & row_mask
     # rows beyond the live region belong to no group
-    gid = xp.cumsum(starts.astype(np.int32)) - 1
+    flags = starts.astype(np.int32)
+    gid = (np.cumsum(flags) if xp is np else prefix_sum(flags)) - 1
     gid = xp.where(row_mask, gid, n - 1)
     num_groups = xp.sum(starts).astype(np.int32)
     return gid, num_groups, starts
+
+
+def segment_ends(start_order, num_groups, live):
+    """int32[cap]: the position of the last row of every group of rows that
+    are sorted by group with the `live` live ones first. `start_order` is
+    the stable permutation that compacts the group-start rows
+    (`compaction_order(starts)`), so `start_order[g]` is where group g
+    starts and group g ends one row before group g + 1 does; the last group
+    ends at row live - 1. `None` means one group over all live rows. Entries
+    at g >= num_groups repeat the last live row (row 0 of an empty batch),
+    so the vector is non-decreasing."""
+    import jax
+    import jax.numpy as jnp
+    last = jnp.maximum(live.astype(np.int32) - 1, 0)
+    if start_order is None:
+        return last
+    cap = start_order.shape[0]
+    next_start = jnp.pad(start_order[1:], (0, 1))
+    return jnp.where(jax.lax.iota(np.int32, cap) + 1 < num_groups,
+                     next_start - 1, last)
+
+
+def sorted_segment_sum(contrib, ends, num_groups):
+    """Per-group sums of integer `contrib`, (cap,) or (K, cap) with the rows
+    along the last axis, as `jax.ops.segment_sum(contrib, gid, cap)` returns
+    them (zero at g >= num_groups), without a scatter: an inclusive prefix
+    sum P, its value E at every group's end (`segment_ends`), and
+    total[g] = E[g] - E[g - 1].
+
+    Contract: the rows are sorted so that `gid` is non-decreasing over a
+    live prefix, and dead rows contribute zero. Then group g is the rows
+    ends[g - 1] + 1 .. ends[g] and the difference is its sum.
+
+    Exact: two's-complement addition wraps, so E[g] - E[g - 1] is the
+    segment's true sum modulo 2^64 (2^32) even where the running prefix has
+    wrapped; wherever the scatter-add's total was exact (43-bit decimal
+    chunks, counts, int64 sums under Spark's own wrap / ANSI rule) this is
+    the same value bit for bit. Not for floats: a difference of float
+    prefixes cancels and carries the earlier groups' magnitude."""
+    import jax
+    import jax.numpy as jnp
+    assert np.issubdtype(contrib.dtype, np.integer), contrib.dtype
+    at_ends = prefix_sum(contrib).at[..., ends].get(
+        mode="promise_in_bounds", indices_are_sorted=True)
+    lead = [(0, 0)] * (contrib.ndim - 1)
+    totals = at_ends - jnp.pad(at_ends[..., :-1], lead + [(1, 0)])
+    live_group = jax.lax.iota(np.int32, ends.shape[0]) < num_groups
+    return jnp.where(live_group, totals, contrib.dtype.type(0))
+
+
+class SortedSegments:
+    """Rows sorted by group key, as the segmented reductions of one
+    aggregate kernel share them: `gid` (non-decreasing over the live prefix
+    of `row_mask`, cap - 1 on dead rows), `num_groups`, and on the device
+    the groups' end positions, computed once for every column. The two
+    tallies count, while a kernel is traced, the reductions that took the
+    prefix route and those that lowered to a scatter."""
+
+    def __init__(self, xp, gid, num_groups, row_mask, start_order=None):
+        self.gid = gid
+        self.num_groups = num_groups
+        self.cap = row_mask.shape[0]
+        self.ends = None
+        if xp is not np:
+            live = xp.sum(row_mask).astype(np.int32)
+            self.ends = xp.broadcast_to(
+                segment_ends(start_order, num_groups, live), row_mask.shape)
+        self.prefix_routed = 0
+        self.scattered = 0
+
+    def sum(self, contrib):
+        """Per-group sums of `contrib`, (cap,) or (cap, K), zero at dead
+        rows (device): integers by `sorted_segment_sum`, floats by
+        `jax.ops.segment_sum`."""
+        import jax
+        if not np.issubdtype(contrib.dtype, np.integer):
+            self.scattered += 1
+            return jax.ops.segment_sum(contrib, self.gid,
+                                       num_segments=self.cap)
+        self.prefix_routed += 1
+        if contrib.ndim == 2:
+            return sorted_segment_sum(contrib.T, self.ends,
+                                      self.num_groups).T
+        return sorted_segment_sum(contrib, self.ends, self.num_groups)
+
+    def count(self, valid):
+        """Per-group count of the rows where `valid` (which excludes dead
+        rows), int64[cap]. A count is at most cap < 2^31 and the chip
+        emulates 64-bit integers: summed in int32, widened after."""
+        return self.sum(valid.astype(np.int32)).astype(np.int64)
+
+    def sums(self, *contribs):
+        """Per-group int64 totals of several integer or bool (cap,)
+        contributions over the same rows (zero or false at dead rows), as
+        the rows of one (K, cap) int64 matrix and one reduction. The
+        gather at the group ends costs the chip per index, not per row, up
+        to 8 rows: 22.6 ms for 2 to 8 int64 rows of 2,097,152, 34 ms for
+        one row alone and 19 ms for a lone int32 (PERF.md, PR 33), so a
+        count rides with the sums it belongs to for nothing."""
+        import jax.numpy as jnp
+        self.prefix_routed += len(contribs)
+        rows = jnp.stack([c.astype(np.int64) for c in contribs])
+        return tuple(sorted_segment_sum(rows, self.ends, self.num_groups))
+
+    def minmax(self, op: str, contrib):
+        """Per-group extremum of `contrib` (rows along axis 0, invalid rows
+        at the neutral value): a scatter, as before."""
+        import jax
+        self.scattered += 1
+        seg = jax.ops.segment_min if op == "min" else jax.ops.segment_max
+        return seg(contrib, self.gid, num_segments=self.cap)
 
 
 # Whole-stage fusion hook (exec/fused.py): while a fused stage traces an
@@ -197,19 +341,20 @@ def group_ids_from_sorted(xp, key_vecs: Sequence[Vec], row_mask):
 _FUSED_SEGMENT_SUM = None
 
 
-def segment_reduce(xp, op: str, data, gid, cap: int, valid=None):
-    """Segmented reduction over rows with group ids. Invalid rows are excluded
-    (null-skipping aggregate semantics). Returns per-group array of length cap."""
-    import jax
+def segment_reduce(xp, op: str, data, segs: SortedSegments, valid=None):
+    """Segmented reduction over rows sorted by group. Invalid rows are
+    excluded (null-skipping aggregate semantics); `valid` excludes the dead
+    rows. Returns per-group array of length cap."""
+    gid, cap = segs.gid, segs.cap
     if valid is None:
         valid = xp.ones(data.shape[0], dtype=bool)
     if op == "count":
-        ones = valid.astype(np.int64)
         if xp is np:
-            return np.bincount(gid, weights=ones, minlength=cap).astype(np.int64)
+            return np.bincount(gid, weights=valid.astype(np.int64),
+                               minlength=cap).astype(np.int64)
         if _FUSED_SEGMENT_SUM is not None:
-            return _FUSED_SEGMENT_SUM(ones, gid, cap)
-        return jax.ops.segment_sum(ones, gid, num_segments=cap)
+            return _FUSED_SEGMENT_SUM(valid.astype(np.int64), gid, cap)
+        return segs.count(valid)
     if op == "sum":
         contrib = xp.where(valid, data, data.dtype.type(0))
         if xp is np:
@@ -218,7 +363,7 @@ def segment_reduce(xp, op: str, data, gid, cap: int, valid=None):
             return out
         if _FUSED_SEGMENT_SUM is not None and contrib.ndim == 1:
             return _FUSED_SEGMENT_SUM(contrib, gid, cap)
-        return jax.ops.segment_sum(contrib, gid, num_segments=cap)
+        return segs.sum(contrib)
     if op in ("min", "max"):
         if np.issubdtype(data.dtype, np.floating):
             neutral = data.dtype.type(np.inf if op == "min" else -np.inf)
@@ -234,9 +379,20 @@ def segment_reduce(xp, op: str, data, gid, cap: int, valid=None):
             fn = np.minimum if op == "min" else np.maximum
             getattr(fn, "at")(out, gid, contrib)
             return out
-        seg = jax.ops.segment_min if op == "min" else jax.ops.segment_max
-        return seg(contrib, gid, num_segments=cap)
+        return segs.minmax(op, contrib)
     raise ValueError(f"unknown segmented op {op}")
+
+
+def segment_sum_count(xp, data, segs: SortedSegments, valid):
+    """(per-group sum of `data` over its valid rows, their count): for
+    integers on the device one stacked reduction (`SortedSegments.sums`),
+    else a `segment_reduce` each."""
+    if xp is np or _FUSED_SEGMENT_SUM is not None or \
+            not np.issubdtype(data.dtype, np.integer):
+        return (segment_reduce(xp, "sum", data, segs, valid),
+                segment_reduce(xp, "count", data, segs, valid))
+    total, count = segs.sums(xp.where(valid, data, data.dtype.type(0)), valid)
+    return total.astype(data.dtype), count
 
 
 def sample_mask(xp, n: int, row_offset, fraction: float, seed: int):

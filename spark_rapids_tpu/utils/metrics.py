@@ -29,6 +29,10 @@ COLLECT_TIME = "collectTime"
 CONCAT_TIME = "concatTime"
 SORT_TIME = "sortTime"
 AGG_TIME = "computeAggTime"
+# segmented reductions of the executed aggregate kernels, by how they lowered:
+# a prefix sum and a difference at the group ends, or a scatter
+NUM_PREFIX_REDUCTIONS = "numPrefixSumReductions"
+NUM_SCATTER_REDUCTIONS = "numScatterReductions"
 JOIN_TIME = "joinTime"
 FILTER_TIME = "filterTime"
 BUILD_TIME = "buildTime"
