@@ -112,6 +112,78 @@ def _seg_minmax_2d(xp, op: str, data, segs: SortedSegments, neutral):
     return segs.minmax(op, data)
 
 
+def avg_decimal(xp, func, sbufs: List[Vec], bi: int,
+                segs: SortedSegments, row_mask, merging: bool,
+                output_partial: bool) -> List[Vec]:
+    """Decimal AVG, exact: the sum as Spark's decimal(p + 10, s) through
+    the decimal SUM path and the count as long (partials merge by sum),
+    then sum / count rounded HALF_UP at the result scale in limbs
+    (decimal128.div_count_half_up). Null for a group without a value,
+    or whose sum left its type."""
+    from ..expr.decimal128 import (div_count_half_up, in_bounds,
+                                   is_dec128, pack_limbs, widen_operand)
+    sum_t, out_t = func.sum_type, func.data_type
+    v = sbufs[bi]
+    cap = segs.cap
+    valid = v.validity & row_mask
+    merged = ()
+    if merging:
+        # the partial counts, and the partials that counted rows and
+        # lost their sum (overflow): they make the merged sum null, as
+        # Spark's sum.left + sum.right does
+        cv = sbufs[bi + 1]
+        merged = (xp.where(cv.validity & row_mask, cv.data, 0),
+                  row_mask & ~v.validity & (cv.data > 0))
+    # the count (over raw rows the average's own) rides with the sum
+    if is_dec128(sum_t):
+        s, n, *merged = sum_dec128(xp, sum_t, v, segs, row_mask, merged)
+    else:  # <= 18 digits: cannot overflow an int64 accumulator
+        data, n, *merged = _seg_sums(
+            xp, segs, xp.where(valid, v.data.astype(np.int64), 0), valid,
+            *merged)
+        s = Vec(sum_t, data, n > 0)
+    if merging:
+        c, lost = merged
+        s = Vec(sum_t, s.data, s.validity & (lost == 0))
+    else:
+        c = n
+    if output_partial:
+        return [s, Vec(T.LONG, c, xp.ones(cap, dtype=bool))]
+    hi, lo, fits = div_count_half_up(xp, *widen_operand(xp, s),
+                                     sum_t.precision,
+                                     out_t.scale - sum_t.scale, c)
+    ok = s.validity & (c > 0) & fits & \
+        in_bounds(xp, hi, lo, out_t.precision)
+    if is_dec128(out_t):
+        return [Vec(out_t, pack_limbs(xp, hi, lo), ok)]
+    return [Vec(out_t, lo.astype(np.int64), ok)]
+
+
+def sum_dec128(xp, out_t, v: Vec, segs: SortedSegments,
+               row_mask, more=()):
+    """Decimal128 SUM via carry-free chunk sums (decimal128.sum_chunks):
+    three independent segment-sums reconstruct the 128-bit total.
+    Partial buffers carry the same decimal type, so merge passes rerun
+    the identical kernel. Overflow past precision -> null (Spark).
+    Returns (sum, count of valid rows, totals of `more`): the chunks,
+    the count and the caller's further contributions are one stacked
+    reduction."""
+    from ..expr.decimal128 import (in_bounds, is_dec128, pack_limbs,
+                                   sum_chunks, sum_recombine,
+                                   widen_operand)
+    valid = v.validity & row_mask
+    hi, lo = widen_operand(xp, v)
+    hi = xp.where(valid, hi, np.int64(0))
+    lo = xp.where(valid, lo, np.int64(0))
+    s0, s1, s2, count, *more = _seg_sums(
+        xp, segs, *sum_chunks(xp, hi, lo), valid, *more)
+    shi, slo = sum_recombine(xp, s0, s1, s2)
+    ok = (count > 0) & in_bounds(xp, shi, slo, out_t.precision)
+    data = pack_limbs(xp, shi, slo) if is_dec128(out_t) else \
+        slo.astype(np.int64)
+    return (Vec(out_t, data, ok), count, *more)
+
+
 class TpuHashAggregateExec(UnaryTpuExec):
     """Modes: complete (raw->final), partial (raw->partial buffers),
     final (partial->final). Multi-batch inputs aggregate per batch, park the
@@ -329,8 +401,8 @@ class TpuHashAggregateExec(UnaryTpuExec):
                         xp.ones(cap, dtype=bool))]
         if isinstance(func, Average) and \
                 isinstance(func.data_type, T.DecimalType):
-            return self._avg_decimal(xp, func, sbufs, bi, segs, row_mask,
-                                     merging, output_partial)
+            return avg_decimal(xp, func, sbufs, bi, segs, row_mask,
+                               merging, output_partial)
         if isinstance(func, Average):
             if merging:
                 s, sv = seg("sum", sbufs[bi], np.float64)
@@ -351,8 +423,7 @@ class TpuHashAggregateExec(UnaryTpuExec):
             v = sbufs[bi]
             if isinstance(func.data_type, T.DecimalType) and \
                     (is_dec128(func.data_type) or is_dec128(v.dtype)):
-                return [self._sum_dec128(xp, func.data_type, v, segs,
-                                         row_mask)[0]]
+                return [sum_dec128(xp, func.data_type, v, segs, row_mask)[0]]
             out_t = func.data_type if not merging else v.dtype
             acc = np.float64 if T.is_floating(out_t) else np.int64
             data, has = seg("sum", v, acc)
@@ -525,77 +596,6 @@ class TpuHashAggregateExec(UnaryTpuExec):
         has = _seg_count(xp, valid, segs) > 0
         data = xp.stack([h_ext, out_lo], axis=1)
         return Vec(v.dtype, data, has)
-
-    def _avg_decimal(self, xp, func, sbufs: List[Vec], bi: int,
-                     segs: SortedSegments, row_mask, merging: bool,
-                     output_partial: bool) -> List[Vec]:
-        """Decimal AVG, exact: the sum as Spark's decimal(p + 10, s) through
-        the decimal SUM path and the count as long (partials merge by sum),
-        then sum / count rounded HALF_UP at the result scale in limbs
-        (decimal128.div_count_half_up). Null for a group without a value,
-        or whose sum left its type."""
-        from ..expr.decimal128 import (div_count_half_up, in_bounds,
-                                       is_dec128, pack_limbs, widen_operand)
-        sum_t, out_t = func.sum_type, func.data_type
-        v = sbufs[bi]
-        cap = segs.cap
-        valid = v.validity & row_mask
-        merged = ()
-        if merging:
-            # the partial counts, and the partials that counted rows and
-            # lost their sum (overflow): they make the merged sum null, as
-            # Spark's sum.left + sum.right does
-            cv = sbufs[bi + 1]
-            merged = (xp.where(cv.validity & row_mask, cv.data, 0),
-                      row_mask & ~v.validity & (cv.data > 0))
-        # the count (over raw rows the average's own) rides with the sum
-        if is_dec128(sum_t):
-            s, n, *merged = self._sum_dec128(xp, sum_t, v, segs, row_mask,
-                                             merged)
-        else:  # <= 18 digits: cannot overflow an int64 accumulator
-            data, n, *merged = _seg_sums(
-                xp, segs, xp.where(valid, v.data.astype(np.int64), 0), valid,
-                *merged)
-            s = Vec(sum_t, data, n > 0)
-        if merging:
-            c, lost = merged
-            s = Vec(sum_t, s.data, s.validity & (lost == 0))
-        else:
-            c = n
-        if output_partial:
-            return [s, Vec(T.LONG, c, xp.ones(cap, dtype=bool))]
-        hi, lo, fits = div_count_half_up(xp, *widen_operand(xp, s),
-                                         sum_t.precision,
-                                         out_t.scale - sum_t.scale, c)
-        ok = s.validity & (c > 0) & fits & \
-            in_bounds(xp, hi, lo, out_t.precision)
-        if is_dec128(out_t):
-            return [Vec(out_t, pack_limbs(xp, hi, lo), ok)]
-        return [Vec(out_t, lo.astype(np.int64), ok)]
-
-    def _sum_dec128(self, xp, out_t, v: Vec, segs: SortedSegments,
-                    row_mask, more=()):
-        """Decimal128 SUM via carry-free chunk sums (decimal128.sum_chunks):
-        three independent segment-sums reconstruct the 128-bit total.
-        Partial buffers carry the same decimal type, so merge passes rerun
-        the identical kernel. Overflow past precision -> null (Spark).
-        Returns (sum, count of valid rows, totals of `more`): the chunks,
-        the count and the caller's further contributions are one stacked
-        reduction."""
-        from ..expr.decimal128 import (in_bounds, is_dec128, pack_limbs,
-                                       sum_chunks, sum_recombine,
-                                       widen_operand)
-        valid = v.validity & row_mask
-        hi, lo = widen_operand(xp, v)
-        hi = xp.where(valid, hi, np.int64(0))
-        lo = xp.where(valid, lo, np.int64(0))
-        s0, s1, s2, count, *more = _seg_sums(
-            xp, segs, *sum_chunks(xp, hi, lo), valid, *more)
-        shi, slo = sum_recombine(xp, s0, s1, s2)
-        ok = (count > 0) & in_bounds(xp, shi, slo, out_t.precision)
-        data = pack_limbs(xp, shi, slo) if is_dec128(out_t) else \
-            slo.astype(np.int64)
-        return (Vec(out_t, data, ok), count, *more)
 
     def _minmax_string(self, xp, op: str, v: Vec, segs: SortedSegments,
                        row_mask) -> Vec:

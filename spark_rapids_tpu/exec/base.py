@@ -321,19 +321,30 @@ class PrefetchIterator:
             self._thread.join(timeout=10.0)
 
 
+def start_prefetch(inner: Iterator[ColumnarBatch],
+                   conf: Optional[TpuConf],
+                   name: str = "prefetch") -> Optional[PrefetchIterator]:
+    """A PrefetchIterator that pulls `inner` from this call on, before
+    anyone iterates it; None when pipelined execution is off. Whoever holds
+    it iterates it to its end or calls `close()`: a producer nobody drains
+    parks at the queue's depth for good."""
+    conf = conf or get_default_conf()
+    if not conf.get("spark.rapids.tpu.pipeline.enabled"):
+        return None
+    depth = conf.get("spark.rapids.tpu.pipeline.prefetch.depth")
+    if depth < 1:
+        return None
+    return PrefetchIterator(inner, depth, name)
+
+
 def maybe_prefetch(inner: Iterator[ColumnarBatch],
                    conf: Optional[TpuConf],
                    name: str = "prefetch") -> Iterator[ColumnarBatch]:
     """Wrap `inner` in a PrefetchIterator when pipelined execution is on;
     pipeline-off returns `inner` UNCHANGED (the exact serial path, zero
     threads spawned)."""
-    conf = conf or get_default_conf()
-    if not conf.get("spark.rapids.tpu.pipeline.enabled"):
-        return inner
-    depth = conf.get("spark.rapids.tpu.pipeline.prefetch.depth")
-    if depth < 1:
-        return inner
-    return iter(PrefetchIterator(inner, depth, name))
+    ahead = start_prefetch(inner, conf, name)
+    return inner if ahead is None else iter(ahead)
 
 
 class StaticExpr:

@@ -78,9 +78,15 @@ class TpuProjectExec(UnaryTpuExec):
             ctx.partition_row_offset = row_offset
             vecs = batch_vecs(batch)
             outs = [e.eval(ctx, vecs) for e in bound]
-            return vecs_to_batch(self._schema, outs, batch.num_rows), \
-                kernel_errors(ctx, msgs_box)
+            flags = kernel_errors(ctx, msgs_box)
+            # the box's tail, for `do_execute` (the compile service restores
+            # the box when the program comes from a cache): the exact
+            # decimal divisions this trace lowered
+            msgs_box.append(ctx.decimal_divides)
+            return vecs_to_batch(self._schema, outs, batch.num_rows), flags
 
+        self.decimal_divides = self.metrics.create(M.NUM_DECIMAL_DIVIDES,
+                                                   M.MODERATE)
         # a projection containing a host black box (pandas UDF) cannot be
         # traced: run it eagerly — jnp ops still execute on device, and the
         # UDF sees concrete arrays at the host hop. This is the in-process
@@ -109,6 +115,7 @@ class TpuProjectExec(UnaryTpuExec):
                 out, errs = self._kernel(b, offset)
             offset = offset + jnp.asarray(b.row_count(), jnp.int64)
             raise_kernel_errors(errs, self._err_msgs)
+            self.decimal_divides.add(self._err_msgs[-1])
             self.num_output_rows.add(b.row_count())
             yield self._count_output(out)
 

@@ -289,6 +289,22 @@ class TpuShuffledHashJoinExec(TpuExec):
         if self.zip_partitions:
             yield from self._zipped_execute()
             return
+        # the probe side starts now, on a thread of its own, and this one
+        # builds meanwhile: a scan's page walk and its decode program run
+        # beside the build side's, where the chip used to wait for each
+        # host step in turn (PERF.md, section 5). Not where the probe
+        # side's scan waits for the build side's keys (dpp_filters).
+        from .base import start_prefetch
+        ahead = None if self.dpp_filters else start_prefetch(
+            self._stream_batches(), self.conf, name="join-probe")
+        try:
+            yield from self._build_then_stream(
+                self._stream_batches() if ahead is None else ahead)
+        finally:
+            if ahead is not None:
+                ahead.close()
+
+    def _build_then_stream(self, probes) -> Iterator[ColumnarBatch]:
         with self.build_time.timed():
             build_batches = list(self.children[1].execute())
             if not build_batches and self.join_type in ("inner", "right", "semi"):
@@ -311,9 +327,9 @@ class TpuShuffledHashJoinExec(TpuExec):
 
         threshold = self.conf.get("spark.rapids.sql.join.subPartition.rows")
         if int(build.row_count()) > threshold:
-            yield from self._streamed_sub_partition(build, threshold)
+            yield from self._streamed_sub_partition(build, threshold, probes)
         else:
-            yield from self._streamed_join(build)
+            yield from self._streamed_join(build, probes)
 
     def _stream_batches(self) -> Iterator[ColumnarBatch]:
         """Probe-side stream with streamTime/numInput accounting: the wait
@@ -325,7 +341,8 @@ class TpuShuffledHashJoinExec(TpuExec):
             self.num_input_rows.add(b.row_count())
             yield b
 
-    def _streamed_join(self, build: ColumnarBatch) -> Iterator[ColumnarBatch]:
+    def _streamed_join(self, build: ColumnarBatch,
+                       probes) -> Iterator[ColumnarBatch]:
         """Stream probe batches against the built table (`GpuHashJoin.doJoin`
         `GpuHashJoin.scala:950`): only one probe batch is device-resident at a
         time; the build side parks spillable between batches and the per-batch
@@ -336,7 +353,7 @@ class TpuShuffledHashJoinExec(TpuExec):
         del build
         bmatched = None
         try:
-            for probe in self._stream_batches():
+            for probe in probes:
                 if int(probe.row_count()) == 0:
                     continue
 
@@ -366,8 +383,8 @@ class TpuShuffledHashJoinExec(TpuExec):
         finally:
             sp_build.close()
 
-    def _streamed_sub_partition(self, build: ColumnarBatch,
-                                threshold: int) -> Iterator[ColumnarBatch]:
+    def _streamed_sub_partition(self, build: ColumnarBatch, threshold: int,
+                                probes) -> Iterator[ColumnarBatch]:
         """Oversized build side with a streamed probe
         (`GpuSubPartitionHashJoin.scala` analog): hash-split the build ONCE
         into P spillable key-aligned sub-partitions; each probe batch is split
@@ -384,7 +401,7 @@ class TpuShuffledHashJoinExec(TpuExec):
         del build
         bmatched = [None] * p
         try:
-            for probe in self._stream_batches():
+            for probe in probes:
                 if int(probe.row_count()) == 0:
                     continue
                 for i, pp in enumerate(_hash_split(probe, self._lk_ix, p)):

@@ -30,14 +30,16 @@ import numpy as np
 from .. import types as T
 from ..columnar.batch import ColumnarBatch, Schema
 from ..compile import instance_jit, kernel_key
+from ..expr.aggregates import Average
 from ..expr.base import Expression, Vec, bind_references
+from ..expr.decimal128 import is_dec128
 from ..expr.windowexprs import (CumeDist, DenseRank, Lag, Lead, NTile,
                                 PercentRank, RangeFrame, Rank, RowFrame,
                                 RowNumber, WindowAggregate, WindowFunction,
                                 bind_window_fn, default_frame,
                                 is_value_range_frame)
-from ..ops.rowops import (gather_vecs, key_change_flags, lexsort_indices,
-                          sort_keys_for)
+from ..ops.rowops import (SortedSegments, compaction_order, gather_vecs,
+                          key_change_flags, lexsort_indices, sort_keys_for)
 from ..utils import metrics as M
 from .base import TpuExec, UnaryTpuExec, batch_vecs, device_ctx, vecs_to_batch
 from .coalesce import concat_batches
@@ -234,6 +236,10 @@ class TpuWindowExec(UnaryTpuExec):
         tps = schema.types + tuple(f.data_type for f, _ in self._bound_fns)
         self._schema = Schema(names, tps)
         self.window_time = self.metrics.create(M.WINDOW_TIME, M.MODERATE)
+        self.partitions_closed = self.metrics.create(
+            M.NUM_WINDOW_PARTITIONS, M.MODERATE)
+        self.decimal_aggs = self.metrics.create(
+            M.NUM_DECIMAL_WINDOW_AGGS, M.MODERATE)
         bound_part, bound_order = self._bound_part, self._bound_order
         bound_fns = self._bound_fns
         has_order = bool(order_spec)
@@ -285,8 +291,13 @@ class TpuWindowExec(UnaryTpuExec):
             out = list(svecs)
             for fn, _ in bound_fns:
                 out.append(_eval_device(fn, env))
+            flags = kernel_errors(ctx, msgs_box)
+            # the box's tail, for `do_execute` (the compile service restores
+            # the box when the program comes from a cache): the exact
+            # decimal aggregates this trace lowered
+            msgs_box.append(env.decimal_aggs)
             return vecs_to_batch(self._schema, out, batch.num_rows), \
-                kernel_errors(ctx, msgs_box)
+                flags, jnp.sum(part_start)
 
         self._kernel = instance_jit(
             kernel, op="exec.window",
@@ -312,9 +323,10 @@ class TpuWindowExec(UnaryTpuExec):
             # window partitions — frames span a whole partition — so memory
             # pressure here spills/blocks and re-runs instead of splitting
             with self.window_time.timed():
-                out, errs = self._kernel(b)
+                out, errs, parts = self._kernel(b)
             raise_kernel_errors(errs, self._err_msgs)
-            return out
+            self.decimal_aggs.add(self._err_msgs[-1])
+            return out, parts
 
         # full ownership transfer: popping from the holder hands the source
         # list to concat (freed as soon as the copy exists) and the merged
@@ -322,9 +334,10 @@ class TpuWindowExec(UnaryTpuExec):
         # this frame pins device memory while the retry seam spills
         holder = [batches]
         del batches
-        out = with_retry_no_split_spillable(
+        out, parts = with_retry_no_split_spillable(
             concat_batches(holder.pop()), run)
         self.num_output_rows.add(out.row_count())
+        self.partitions_closed.add(int(parts))
         yield self._count_output(out)
 
     def _arg_string(self):
@@ -356,6 +369,18 @@ class _WinEnv:
         self.has_order = has_order
         self.sorder_keyvecs = list(sorder_keyvecs)  # sorted order-key Vecs
         self.order_spec = list(order_spec)          # [(ascending, nulls_first)]
+        self.decimal_aggs = 0   # exact decimal aggregates lowered so far
+        self._segments = None
+
+    def segments(self) -> SortedSegments:
+        """The partitions as the grouped aggregate's sorted segments (the
+        rows ARE sorted by the partition keys, dead rows last), built once
+        for every aggregate that sums over whole partitions."""
+        if self._segments is None:
+            self._segments = SortedSegments(
+                jnp, self.gid, jnp.sum(self.part_start).astype(np.int32),
+                self.mask, compaction_order(jnp, self.part_start))
+        return self._segments
 
 
 def _eval_device(fn: WindowFunction, env: _WinEnv) -> Vec:
@@ -466,6 +491,9 @@ def _eval_device_agg(fn: WindowAggregate, env: _WinEnv) -> Vec:
     out_t = func.data_type
 
     unbounded = (frame.lower is None and frame.upper is None)
+    if name in ("Sum", "Average") and isinstance(v.dtype, T.DecimalType) \
+            and (unbounded or name == "Average" or is_dec128(out_t)):
+        return _decimal_partition_agg(func, v, unbounded, env)
     running_rows = isinstance(frame, RowFrame) and frame.lower is None and \
         frame.upper == 0
     running_range = isinstance(frame, RangeFrame) and frame.lower is None and \
@@ -593,6 +621,33 @@ def _eval_device_agg(fn: WindowAggregate, env: _WinEnv) -> Vec:
         out = wsum / jnp.maximum(wcnt, 1).astype(jnp.float64)
         return Vec(T.DOUBLE, out, wcnt > 0)
     return Vec(out_t, wsum, wcnt > 0)
+
+
+def _decimal_partition_agg(func, v: Vec, whole: bool, env: _WinEnv) -> Vec:
+    """`sum` / `avg` of a decimal over the whole partition, exact: each
+    partition's total by the grouped aggregate's own kernels over the
+    partitions as sorted segments (128-bit sums in carry-free chunks, the
+    average's one HALF_UP division in limbs; prefix sums and a difference
+    at the partitions' ends, no scatter), gathered back to the rows by
+    partition id. Results: decimal(p + 10, s) and decimal(p + 4, s + 4),
+    null for a partition without a value or whose sum left its type. Any
+    other frame of a 128-bit sum or of an average is the planner's to keep
+    off the device (`plan/overrides._decimal_window_agg_reason`); a sum
+    within 18 digits over a running or bounded frame is int64 arithmetic
+    and takes the generic path."""
+    from .aggregate import avg_decimal, sum_dec128
+    if not whole:
+        raise NotImplementedError(
+            f"{func!r} over a running or bounded frame has no exact device "
+            "kernel; the planner keeps it on the CPU engine")
+    segs = env.segments()
+    if isinstance(func, Average):
+        total = avg_decimal(jnp, func, [v], 0, segs, env.mask, False,
+                            False)[0]
+    else:
+        total = sum_dec128(jnp, func.data_type, v, segs, env.mask)[0]
+    env.decimal_aggs += 1
+    return gather_vecs(jnp, [total], env.gid)[0]
 
 
 def _frame_bounds(frame, env: _WinEnv):

@@ -3,7 +3,8 @@
 Reference: `org/apache/spark/sql/rapids/arithmetic.scala` (GpuAdd/GpuSubtract/GpuMultiply/
 GpuDivide/GpuIntegralDivide/GpuRemainder/GpuPmod/GpuUnaryMinus/GpuAbs). Semantics notes:
   * integral +,-,* wrap (Java two's complement) in non-ANSI mode;
-  * Divide always yields DOUBLE (inputs implicitly cast); x/0 -> null (non-ANSI);
+  * Divide yields DOUBLE (inputs implicitly cast) unless it is decimal arithmetic,
+    which is exact in Spark's decimal result type; x/0 -> null (non-ANSI);
   * IntegralDivide / Remainder / Pmod truncate toward zero (Java), unlike numpy's
     floor semantics — implemented explicitly;
   * ANSI overflow/zero-division raising is implemented on the CPU engine and marked
@@ -82,6 +83,17 @@ def _as_decimal_type(e: Expression):
     return T.DecimalType(_INTEGRAL_AS_DECIMAL[type(dt)], 0)
 
 
+def _decimal_operands(left: Expression, right: Expression):
+    """(left, right) as decimal types where `left op right` is decimal
+    arithmetic (a decimal on either side and a decimal or an integral on
+    the other), else None."""
+    if not (isinstance(left.data_type, T.DecimalType) or
+            isinstance(right.data_type, T.DecimalType)):
+        return None
+    pair = _as_decimal_type(left), _as_decimal_type(right)
+    return None if None in pair else pair
+
+
 class BinaryArithmetic(BinaryExpression):
     def _decimal_types(self):
         """(left, right) as decimal types when this is decimal arithmetic
@@ -89,12 +101,7 @@ class BinaryArithmetic(BinaryExpression):
         decimal or an integral on the other), else None."""
         if type(self) not in (Add, Subtract, Multiply):
             return None
-        lt, rt = self.left.data_type, self.right.data_type
-        if not (isinstance(lt, T.DecimalType) or
-                isinstance(rt, T.DecimalType)):
-            return None
-        pair = _as_decimal_type(self.left), _as_decimal_type(self.right)
-        return None if None in pair else pair
+        return _decimal_operands(self.left, self.right)
 
     @property
     def data_type(self) -> T.DataType:
@@ -256,20 +263,36 @@ class Multiply(BinaryArithmetic):
 
 
 class Divide(BinaryExpression):
-    """Spark Divide: result DOUBLE, x/0 -> null (non-ANSI)."""
+    """Spark Divide. Decimal / decimal (an integral beside a decimal is its
+    decimal(p, 0)) is exact, in Spark's result type (`DecimalPrecision`:
+    decimal(21,2) / decimal(27,2) is decimal(38,17)) and with Spark's two
+    roundings; x/0 -> null (non-ANSI) or DIVIDE_BY_ZERO (ANSI), a quotient
+    out of the result type -> null or ARITHMETIC_OVERFLOW. Every other pair
+    of types yields DOUBLE (inputs implicitly cast), as before."""
 
     @property
     def data_type(self):
-        return T.DOUBLE
+        pair = _decimal_operands(self.left, self.right)
+        if pair is None:
+            return T.DOUBLE
+        from .decimal128 import divide_result_type
+        return divide_result_type(*pair)
 
     @property
     def nullable(self):
         return True
 
     def _compute(self, ctx: EvalContext, l: Vec, r: Vec) -> Vec:
+        pair = _decimal_operands(self.left, self.right)
+        if pair is not None:
+            # an integral operand IS its decimal(p, 0): same unscaled value
+            l = Vec(pair[0], l.data, l.validity)
+            r = Vec(pair[1], r.data, r.validity)
+            if ctx.xp is np:
+                return self._decimal_div_host(ctx, l, r)
+            return self._decimal_div(ctx, l, r)
         xp = ctx.xp
-        a = l.data.astype(np.float64)
-        b = r.data.astype(np.float64)
+        a, b = (self._as_double(xp, v) for v in (l, r))
         zero = b == 0.0
         both = and_validity(xp, l.validity, r.validity)
         if ctx.ansi:
@@ -281,6 +304,79 @@ class Divide(BinaryExpression):
         else:
             data = xp.where(zero, 0.0, a / xp.where(zero, 1.0, b))
         return Vec(T.DOUBLE, data, validity)
+
+    @staticmethod
+    def _as_double(xp, v: Vec):
+        """The operand as float64: a decimal beside a float is cast by
+        value, as Spark casts it (its unscaled integer is not its value)."""
+        if isinstance(v.dtype, T.DecimalType):
+            from .cast import _decimal_cast
+            return _decimal_cast(xp, v, T.DOUBLE).data
+        return v.data.astype(np.float64)
+
+    def _finish_decimal(self, ctx: EvalContext, out_t, hi, lo, ok, zero,
+                        both) -> Vec:
+        from .decimal128 import is_dec128, pack_limbs
+        if ctx.ansi:
+            ansi_raise(ctx, zero & both, _DIV_ZERO)
+            ansi_raise(ctx, ~ok & ~zero & both, _overflow_msg(out_t))
+        validity = both & ~zero & ok
+        if is_dec128(out_t):
+            return Vec(out_t, pack_limbs(ctx.xp, hi, lo), validity)
+        return Vec(out_t, lo.astype(np.int64), validity)
+
+    def _decimal_div(self, ctx: EvalContext, l: Vec, r: Vec) -> Vec:
+        """The device kernel: `decimal128.div_half_up` on the limbs, the
+        dividend scaled by 10^(s - s1 + s2) so that the quotient's unit is
+        the result scale's (the exponent is never negative: Spark's bounded
+        scale keeps at least 38 - p1 >= 0 of it)."""
+        from .decimal128 import div_half_up, in_bounds, widen_operand
+        xp = ctx.xp
+        out_t = self.data_type
+        k = out_t.scale - l.dtype.scale + r.dtype.scale
+        rhi, rlo = widen_operand(xp, r)
+        zero = (rhi == 0) & (rlo == 0)
+        hi, lo, fits = div_half_up(
+            xp, *widen_operand(xp, l), l.dtype.precision, k,
+            rhi, xp.where(zero, np.int64(1), rlo))
+        ok = fits & in_bounds(xp, hi, lo, out_t.precision)
+        ctx.decimal_divides += 1
+        return self._finish_decimal(
+            ctx, out_t, hi, lo, ok, zero,
+            and_validity(xp, l.validity, r.validity))
+
+    def _decimal_div_host(self, ctx: EvalContext, l: Vec, r: Vec) -> Vec:
+        """The CPU engine's division, on Python's `decimal` and integers,
+        row by row: it shares nothing with the limb kernel it is the oracle
+        of."""
+        import decimal as _d
+        from .decimal128 import split_int, unscaled_ints
+        out_t = self.data_type
+        at38 = _d.Context(prec=38, rounding=_d.ROUND_HALF_UP)
+        exact = _d.Context(prec=160)
+        unit = _d.Decimal(1).scaleb(-out_t.scale)
+        bound = 10 ** out_t.precision
+        n = l.validity.shape[0]
+        hi, lo = np.zeros(n, np.int64), np.zeros(n, np.int64)
+        ok, zero = np.ones(n, bool), np.zeros(n, bool)
+        both = and_validity(np, l.validity, r.validity)
+        for i, (a, b) in enumerate(zip(unscaled_ints(l.data),
+                                       unscaled_ints(r.data))):
+            if not both[i]:
+                continue
+            if b == 0:
+                zero[i] = True
+                continue
+            q = at38.divide(exact.scaleb(_d.Decimal(a), -l.dtype.scale),
+                            exact.scaleb(_d.Decimal(b), -r.dtype.scale))
+            u = int(exact.scaleb(q.quantize(
+                unit, rounding=_d.ROUND_HALF_UP, context=exact),
+                out_t.scale))
+            if abs(u) >= bound:
+                ok[i] = False
+            else:
+                hi[i], lo[i] = split_int(u)
+        return self._finish_decimal(ctx, out_t, hi, lo, ok, zero, both)
 
 
 def _trunc_div(xp, a, b):

@@ -202,6 +202,9 @@ class EvalContext:
     # offset, possibly a traced scalar, across its batch stream)
     partition_id: Any = 0
     partition_row_offset: Any = 0
+    # exact decimal divisions lowered while this context's kernel was traced
+    # (the projection's `numDecimalDivides`)
+    decimal_divides: int = 0
 
     @property
     def is_device(self) -> bool:

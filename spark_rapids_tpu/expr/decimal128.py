@@ -77,6 +77,14 @@ def to_decimal(unscaled: int, scale: int) -> "_decimal.Decimal":
     return _EXACT_CTX.scaleb(_decimal.Decimal(unscaled), -scale)
 
 
+def unscaled_ints(data) -> list:
+    """A decimal column's host array ((n, 2) limb pairs or (n,) int64) as
+    Python ints: what the CPU engine's exact paths compute on."""
+    if data.ndim == 2:
+        return [join_int(int(h), int(lw)) for h, lw in data]
+    return [int(x) for x in data]
+
+
 def _u(xp, x):
     return x.astype(np.uint64)
 
@@ -423,6 +431,23 @@ def _repeat(xp, n: int, step, state):
     return jax.lax.fori_loop(0, n, lambda _, st: step(st), state)
 
 
+def _left_aligned_words(xp, limbs, nbits: int):
+    """u32 limbs (LSB first) of a value under 2^nbits as 64-bit words, least
+    significant first, shifted so that bit nbits - 1 is the top bit of the
+    last word: a restoring division's dividend register."""
+    nwords = -(-nbits // 64)
+    limbs = list(limbs)
+    limbs += [xp.zeros_like(limbs[0])] * (2 * nwords - len(limbs))
+    words = [limbs[2 * i] | (limbs[2 * i + 1] << np.uint64(32))
+             for i in range(nwords)]
+    shift = 64 * nwords - nbits
+    if shift:
+        words = [(w << np.uint64(shift)) |
+                 (words[i - 1] >> np.uint64(64 - shift) if i else
+                  np.uint64(0)) for i, w in enumerate(words)]
+    return words
+
+
 def div_count_half_up(xp, hi, lo, precision: int, k: int, count):
     """(hi, lo) * 10^k / count rounded HALF_UP on the magnitude, for a
     value of at most `precision` digits and an int64 `count` >= 1 (rows
@@ -439,14 +464,8 @@ def div_count_half_up(xp, hi, lo, precision: int, k: int, count):
                  [xp.full(mlo.shape, np.uint64(10 ** k & 0xFFFFFFFF)),
                   xp.full(mlo.shape, np.uint64(10 ** k >> 32))])
     nbits = (10 ** (precision + k) - 1).bit_length()
-    nwords = -(-nbits // 64)
-    words = [n[2 * i] | (n[2 * i + 1] << np.uint64(32))
-             for i in range(nwords)]  # least significant first
-    shift = 64 * nwords - nbits
-    if shift:
-        words = [(w << np.uint64(shift)) |
-                 (words[i - 1] >> np.uint64(64 - shift) if i else
-                  np.uint64(0)) for i, w in enumerate(words)]
+    words = _left_aligned_words(xp, n, nbits)
+    nwords = len(words)
     d = _u(xp, xp.where(count < 1, np.int64(1), count))
 
     def step(state):
@@ -470,6 +489,114 @@ def div_count_half_up(xp, hi, lo, precision: int, k: int, count):
     if nwords > 2:
         fits = fits & (q[2] == 0)
     nhi, nlo = neg128(xp, qhi, qlo)
+    return xp.where(neg, nhi, qhi), xp.where(neg, nlo, qlo), fits
+
+
+def divide_result_type(a, b) -> "T.DecimalType":
+    """Spark's decimal / decimal result (`DecimalPrecision`): ideal scale
+    max(6, s1 + p2 + 1), ideal precision p1 - s1 + s2 + scale, then
+    adjustPrecisionScale."""
+    s = max(6, a.scale + b.precision + 1)
+    return adjust_precision_scale(a.precision - a.scale + b.scale + s, s)
+
+
+def _const_limbs(xp, like, value: int):
+    """A non-negative Python int as u32 limbs (uint64 lanes, LSB first)
+    shaped like `like`: as many as it has bits, at least one."""
+    n = max(1, -(-value.bit_length() // 32))
+    return [xp.full(like.shape, np.uint64((value >> (32 * i)) & 0xFFFFFFFF))
+            for i in range(n)]
+
+
+# 10^0 .. 10^38 as four u32 limbs each, for a per-row power (div_half_up)
+_POW10_LIMBS = np.array([[(10 ** m >> (32 * i)) & 0xFFFFFFFF
+                          for i in range(4)] for m in range(39)],
+                        dtype=np.uint64)
+
+
+def div_half_up(xp, ahi, alo, precision: int, k: int, bhi, blo):
+    """(ahi, alo) * 10^k / (bhi, blo) as Spark's `Decimal./` followed by
+    `toPrecision` computes it, for a dividend of at most `precision` digits
+    and a non-zero divisor (rows with a zero divisor divide by 1): the
+    quotient at 38 SIGNIFICANT digits HALF_UP (`BigDecimal.divide` under
+    `MathContext(38, HALF_UP)`), then HALF_UP at the result scale, which is
+    the unit here because the caller chose k = result scale - s1 + s2.
+    Returns (hi, lo, fits), `fits` False where the quotient leaves 128 bits.
+
+    Exact, in integers: N = |a| * 10^k in as many 64-bit words as the TYPES'
+    digits need, Q = N div |b| and R = N mod |b| by restoring long division,
+    one bit a step in ONE loop (a 64-bit `//` costs the v5e compiler 22 s
+    apiece, 87 unrolled steps 269 s; PERF.md, PR 29), with a 128-bit
+    remainder (R < |b| < 2^127, so 2R + 1 fits). The two roundings fold into
+    one decision. With D the digits of Q, the first rounding keeps m = 38 - D
+    digits of the fraction F = R / |b|. For m <= 0 it is a rounding at the
+    unit or above: up iff 2R >= |b| (a Q of more than 38 digits is out of
+    every result type anyway). For m >= 1 the fraction rounded to m digits
+    reaches one half iff F >= 1/2 - 1/2 * 10^-m, i.e.
+    (|b| - 2R) * 10^m <= |b|: the single rounding's condition and, beside
+    it, the fractions 0.4999..95.. that the first rounding lifts to 0.5.
+    The second can only happen when 10^m <= |b|, so it is left out where the
+    types rule it out (precision + k < 38: then m > digits of |b|)."""
+    mahi, malo, aneg = abs128(xp, ahi, alo)
+    bhi_m, blo_m, bneg = abs128(xp, bhi, blo)
+    bh, bl = _u(xp, bhi_m), _u(xp, blo_m)
+    one, top_bit = np.uint64(1), np.uint64(63)
+    n = list(_split32(xp, mahi, malo))[:limbs_for(precision)]
+    if k:
+        n = wide_mul(xp, n, _const_limbs(xp, malo, 10 ** k))
+    nbits = (10 ** (precision + k) - 1).bit_length()
+    words = _left_aligned_words(xp, n, nbits)
+    nwords = len(words)
+
+    def step(state):
+        *ws, rh, rl = state
+        rh = (rh << one) | (rl >> top_bit)
+        rl = (rl << one) | (ws[-1] >> top_bit)
+        ge = (rh > bh) | ((rh == bh) & (rl >= bl))
+        dl = rl - bl
+        dh = rh - bh - (rl < bl).astype(np.uint64)
+        rh, rl = xp.where(ge, dh, rh), xp.where(ge, dl, rl)
+        carry, out = ge.astype(np.uint64), []
+        for w in ws:
+            out.append((w << one) | carry)
+            carry = w >> top_bit
+        return (*out, rh, rl)
+
+    zero = xp.zeros_like(bl)
+    *q, rh, rl = _repeat(xp, nbits, step, (*words, zero, zero))
+    qlo = _s(xp, q[0])
+    qhi = _s(xp, q[1]) if nwords > 1 else xp.zeros_like(qlo)
+    fits = qhi >= 0
+    for w in q[2:]:
+        fits = fits & (w == 0)
+    # t = |b| - 2R as a signed 129-bit quantity: up at once where t <= 0
+    r2h = (rh << one) | (rl >> top_bit)
+    r2l = rl << one
+    r2_over = (rh >> top_bit) != 0          # 2R >= 2^128 > |b|
+    tl = bl - r2l
+    th = bh - r2h - (bl < r2l).astype(np.uint64)
+    t_pos = ~r2_over & ((bh > r2h) | ((bh == r2h) & (bl > r2l)))
+    up = ~t_pos
+    if precision + k >= 38:
+        digits = xp.zeros(qlo.shape, dtype=np.int32)
+        for j in range(38):
+            phi, plo = split_int(10 ** j)
+            digits = digits + (~lt128(xp, qhi, qlo, np.int64(phi),
+                                      np.int64(plo))).astype(np.int32)
+        m = xp.clip(38 - digits, 0, 38)
+        pow_m = xp.asarray(_POW10_LIMBS)[m]           # (n, 4)
+        prod = wide_mul(xp, list(_split32(xp, _s(xp, th), _s(xp, tl))),
+                        [pow_m[:, i] for i in range(4)])
+        # both under 2^255, so the signed 256-bit compare is theirs
+        lt, eq = wide_cmp(xp, prod, list(_split32(xp, bhi_m, blo_m))
+                          + [zero] * 4)
+        up = up | (t_pos & (m >= 1) & (lt | eq))
+    ihi, ilo = add128(xp, qhi, qlo, xp.zeros_like(qhi), xp.ones_like(qlo))
+    # Q = 2^127 - 1 rounded up wraps negative: it did not fit
+    fits = fits & ~(up & (ihi < 0))
+    qhi, qlo = xp.where(up, ihi, qhi), xp.where(up, ilo, qlo)
+    nhi, nlo = neg128(xp, qhi, qlo)
+    neg = aneg != bneg
     return xp.where(neg, nhi, qhi), xp.where(neg, nlo, qlo), fits
 
 

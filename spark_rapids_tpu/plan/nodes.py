@@ -466,7 +466,7 @@ def _cpu_agg(func: AggregateFunction, ctx, b: HostBatch, gid, ng) -> Vec:
         return Vec(v.dtype, limbs, has)
     if name == "Average" and isinstance(out_t, T.DecimalType):
         # exact, in python ints: sum / count HALF_UP at the result scale
-        # (what exec/aggregate._avg_decimal computes in limbs)
+        # (what exec/aggregate.avg_decimal computes in limbs)
         from ..expr.decimal128 import join_int, split_int
         sums, cnts = [0] * ng, [0] * ng
         for i in np.nonzero(v.validity)[0]:
@@ -1095,9 +1095,16 @@ class CpuWindowExec(PhysicalPlan):
             child = func.child
             v = child.eval(ctx, svecs) if child is not None else None
             out_t = func.data_type
-            out_np = out_t.np_dtype
-            data = np.zeros(n, out_np)
+            # a decimal result is a Python int (its unscaled value) until it
+            # is stored: a 128-bit one as its limb pair
+            from ..expr.decimal128 import (is_dec128, split_int,
+                                           unscaled_ints)
+            wide = is_dec128(out_t)
+            data = np.zeros((n, 2) if wide else n,
+                            np.int64 if wide else out_t.np_dtype)
             valid = np.zeros(n, bool)
+            ints = unscaled_ints(v.data) if v is not None and \
+                isinstance(v.dtype, T.DecimalType) else None
             # string scratch only when the RESULT is a string (min/max/first/
             # last over strings) — Count over a string column yields LONG
             slens = sdata = None
@@ -1115,13 +1122,15 @@ class CpuWindowExec(PhysicalPlan):
                             valid[i] = True
                         continue
                     sl = slice(flo, fhi + 1)
-                    r = _cpu_window_agg(func, v, sl)
+                    r = _cpu_window_agg(func, v, sl, ints)
                     if r is None:
                         continue
                     valid[i] = True
                     if sdata is not None and isinstance(r, bytes):
                         sdata[i, :len(r)] = np.frombuffer(r, np.uint8)
                         slens[i] = len(r)
+                    elif wide:
+                        data[i] = split_int(r)
                     else:
                         data[i] = r
             if sdata is not None:
@@ -1189,9 +1198,12 @@ def _cpu_frame_bounds(frame, i, lo, hi, peer_start, sorder_vecs, order_spec):
     return flo, fhi
 
 
-def _cpu_window_agg(func, v, sl):
+def _cpu_window_agg(func, v, sl, ints=None):
     """Aggregate v[sl] (null-skipping; First/Last respect nulls, Spark default);
-    returns python scalar / bytes / None."""
+    returns python scalar / bytes / None. `ints` are a decimal column's
+    unscaled values (`decimal128.unscaled_ints`): every decimal aggregate is computed
+    on them, exactly, and returns the result's unscaled Python int (None
+    where it leaves the result type, as Spark's non-ANSI overflow does)."""
     name = type(func).__name__
     if v is None:  # count(*)
         return sl.stop - sl.start
@@ -1210,7 +1222,7 @@ def _cpu_window_agg(func, v, sl):
             return None
         if v.is_string:
             return bytes(v.data[j, :v.lengths[j]])
-        return v.data[j]
+        return v.data[j] if ints is None else ints[j]
     if not valid.any():
         return None
     if v.is_string:
@@ -1221,6 +1233,25 @@ def _cpu_window_agg(func, v, sl):
         if name == "Max":
             return max(vals)
         raise NotImplementedError(f"{name} over strings")
+    if ints is not None:
+        vals = [ints[j] for j in range(sl.start, sl.stop) if v.validity[j]]
+        if name == "Min":
+            return min(vals)
+        if name == "Max":
+            return max(vals)
+        total, out_t = sum(vals), func.data_type
+        if name == "Sum":
+            return total if abs(total) < 10 ** out_t.precision else None
+        if name == "Average":
+            # the sum as decimal(p + 10, s), then / count HALF_UP at s + 4
+            if abs(total) >= 10 ** func.sum_type.precision:
+                return None
+            shift = 10 ** (out_t.scale - func.sum_type.scale)
+            q = (2 * abs(total) * shift + len(vals)) // (2 * len(vals))
+            if q >= 10 ** out_t.precision:
+                return None
+            return -q if total < 0 else q
+        raise NotImplementedError(name)
     vals = v.data[sl][valid]
     if name == "Sum":
         return vals.sum()

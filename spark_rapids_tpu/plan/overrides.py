@@ -105,7 +105,8 @@ for cls in (EB.Literal, EB.AttributeReference, EB.BoundReference, EB.Alias):
 for cls in (EA.Add, EA.Subtract):
     expr_rule(cls, _num38)  # decimal +/- via 128-bit limb kernels
 expr_rule(EA.Multiply, _num38)  # decimal x decimal exact in 32-bit limbs
-for cls in (EA.Divide, EA.IntegralDivide, EA.Remainder, EA.Pmod):
+expr_rule(EA.Divide, _num38)  # decimal / decimal exact, one division loop
+for cls in (EA.IntegralDivide, EA.Remainder, EA.Pmod):
     expr_rule(cls, _num)
 for cls in (EA.UnaryMinus, EA.Abs):
     expr_rule(cls, _num38)
@@ -597,7 +598,9 @@ def _register_window_exprs():
     for cls in (WX.RowNumber, WX.Rank, WX.DenseRank, WX.PercentRank,
                 WX.CumeDist, WX.NTile, WX.Lead, WX.Lag):
         expr_rule(cls, _basic)
-    expr_rule(WX.WindowAggregate, _basic, tag_fn=_tag_window_agg)
+    # decimal sums and averages over a whole partition are exact in limbs
+    # (`_tag_window` holds every other decimal frame to what is exact)
+    expr_rule(WX.WindowAggregate, _basic38, tag_fn=_tag_window_agg)
     expr_rule(WX.NthValue, _basic)
 
 
@@ -892,6 +895,11 @@ def _tag_window(m: PlanMeta):
     for f, name in m.plan._bound_fns:
         if f.requires_order and not has_order:
             m.will_not_work(f"window function {name} requires an ORDER BY")
+        if isinstance(f, WX.WindowAggregate):
+            reason = _decimal_window_agg_reason(
+                f, f.frame or WX.default_frame(has_order))
+            if reason:
+                m.will_not_work(f"window column {name}: {reason}")
         if isinstance(f, (WX.WindowAggregate, WX.NthValue)) and \
                 WX.is_value_range_frame(f.frame):
             # value-offset RANGE frames: Spark restricts these to a single
@@ -911,6 +919,38 @@ def _tag_window(m: PlanMeta):
                     isinstance(key_t, (T.DateType, T.TimestampType))):
                 m.will_not_work("value-offset RANGE frames need a numeric "
                                 "order column")
+
+
+def _decimal_window_agg_reason(f, frame):
+    """Why an aggregate of a decimal over `frame` cannot run on the device,
+    or None: `sum` and `avg` over a whole partition are exact in limbs
+    (exec/window.py), a `sum` that stays within 18 digits is exact int64
+    arithmetic under every frame, count / first / last move no value; what
+    is left (a running or bounded 128-bit sum, a running or bounded average,
+    a 128-bit min / max) has no exact kernel and is answered, exactly, by
+    the CPU engine."""
+    from ..expr.decimal128 import is_dec128
+    child = f.func.child
+    try:
+        ct = None if child is None else child.data_type
+    except ValueError:
+        return None
+    name = type(f.func).__name__
+    if not isinstance(ct, T.DecimalType):
+        return None
+    whole = frame.lower is None and frame.upper is None
+    if name == "Sum" and (whole or not is_dec128(f.func.data_type)):
+        return None
+    if name == "Average" and whole:
+        return None
+    if name in ("Sum", "Average"):
+        return (f"{name.lower()} of {ct.simple_string()} over the frame "
+                f"{frame!r} has no exact device kernel (only a whole "
+                "partition, or a sum within 18 digits)")
+    if name in ("Min", "Max") and is_dec128(ct):
+        return (f"{name.lower()} of {ct.simple_string()} over a window has "
+                "no device kernel for 128-bit decimals")
+    return None
 
 
 def _c_window(plan, children, conf):
@@ -1035,7 +1075,7 @@ exec_rule(N.CpuExpandExec, TypeSig.all_basic(), _c_expand,
           expr_fn=_exprs_expand)
 exec_rule(N.CpuShuffleExchangeExec, TypeSig.all_basic(), _c_exchange,
           tag_fn=_tag_exchange)
-exec_rule(N.CpuWindowExec, TypeSig.all_basic(), _c_window,
+exec_rule(N.CpuWindowExec, TypeSig.all_basic(decimal_max=38), _c_window,
           tag_fn=_tag_window, expr_fn=_exprs_window)
 
 

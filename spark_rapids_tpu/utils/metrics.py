@@ -42,6 +42,9 @@ READ_TIME = "readTime"
 WRITE_TIME = "writeTime"
 PARTITION_TIME = "partitionTime"
 WINDOW_TIME = "windowTime"
+NUM_WINDOW_PARTITIONS = "numWindowPartitions"
+NUM_DECIMAL_WINDOW_AGGS = "numDecimalWindowAggs"
+NUM_DECIMAL_DIVIDES = "numDecimalDivides"
 BROADCAST_TIME = "broadcastTime"
 DATA_SIZE = "dataSize"
 SEMAPHORE_WAIT_TIME = "semaphoreWaitTime"

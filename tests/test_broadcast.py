@@ -136,3 +136,78 @@ class TestBroadcastExchange:
         q = fact.join(dim, on="id", how="left")
         assert "TpuBroadcastExchangeExec" in device_plan(session, q)
         assert_same(q, sort_by=["id", "val", "tag"])
+
+
+class TestBroadcastStaysOnDevice:
+    """A flat build side whose consumers sit in this process keeps no host
+    blob: it is cut to the bucket a reader of the blob would rebuild and
+    parked spillable (PERF.md, fault 17)."""
+
+    def _exchange(self, session, table, cap_rows=None):
+        from spark_rapids_tpu.columnar.batch import Schema, batch_from_arrow
+        batch = batch_from_arrow(table) if cap_rows is None \
+            else batch_from_arrow(table, capacity=cap_rows)
+        child = _CountingChild(batch, Schema.from_arrow(table.schema))
+        return TpuBroadcastExchangeExec(child, session.conf), child, batch
+
+    def test_no_blob_and_the_blob_readers_bucket(self, session, rng):
+        from spark_rapids_tpu.columnar.batch import batch_to_arrow
+        from spark_rapids_tpu.columnar.padding import row_bucket
+        t = pa.table({
+            "id": pa.array(np.arange(40), type=pa.int64()),
+            "tag": pa.array([None if k % 5 == 0 else f"t{k}" * 3
+                             for k in range(40)])})
+        ex, child, batch = self._exchange(session, t, cap_rows=4096)
+        assert batch.capacity >= 4096
+        out = list(ex.do_execute())
+        again = list(ex.do_execute())
+        assert child.calls == 1
+        assert ex._blob is None and ex._parked is not None
+        assert out[0].capacity == row_bucket(40) < batch.capacity
+        assert batch_to_arrow(out[0]).equals(t)
+        assert batch_to_arrow(again[0]).equals(t)
+        # the padding is what a reader of the blob writes: zeros, not valid
+        n = int(out[0].row_count())
+        for c in out[0].columns:
+            assert not np.asarray(c.validity)[n:].any()
+            assert not np.asarray(c.data)[n:].any()
+        assert ex._arg_string() == "[device]"
+
+    def test_parked_entry_goes_with_the_node(self, session, rng):
+        import gc
+
+        from spark_rapids_tpu.memory.catalog import BufferCatalog
+        gc.collect()
+        before = len(BufferCatalog.get()._entries)
+        ex, _, _ = self._exchange(session, make_dim(rng))
+        out = list(ex.do_execute())
+        assert len(BufferCatalog.get()._entries) == before + 1
+        del ex, out
+        gc.collect()
+        assert len(BufferCatalog.get()._entries) == before
+
+    def test_rescache_seam_keeps_the_blob(self, rng):
+        from spark_rapids_tpu import rescache
+        s = TpuSession({"spark.rapids.sql.enabled": True,
+                        "spark.rapids.sql.explain": "NONE",
+                        "spark.rapids.tpu.rescache.enabled": True})
+        try:
+            s.initialize_device()
+            assert rescache.is_enabled()
+            ex, _, _ = self._exchange(s, make_dim(rng))
+            out = list(ex.do_execute())
+            assert ex._blob is not None and ex._parked is None
+            assert len(out) == 1
+        finally:
+            rescache.shutdown()
+
+    def test_long_strings_take_the_blob(self, session):
+        t = pa.table({"id": pa.array([1, 2], type=pa.int64()),
+                      "s": pa.array(["x" * 5000, "y"])})
+        ex, _, batch = self._exchange(session, t)
+        if all(c.overflow is None for c in batch.columns):
+            pytest.skip("no long-string layout at this width")
+        out = list(ex.do_execute())
+        assert ex._blob is not None and ex._parked is None
+        from spark_rapids_tpu.columnar.batch import batch_to_arrow
+        assert batch_to_arrow(out[0]).equals(t)

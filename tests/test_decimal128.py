@@ -267,3 +267,46 @@ class TestWideExactness:
         assert got[0]["c"] is None          # 1.8e9 needs 10 int digits > 8
         assert got[1]["c"] == D(12345678)
         assert got[2]["c"] is None          # int64-min: abs() wraps
+
+
+class TestSinkWithoutPythonObjects:
+    """`collect()` hands arrow the unscaled integers as 16-byte words, no
+    `Decimal` per row (PERF.md, fault 4): equal to arrow's own conversion of
+    Python decimals, at the types' limits, negative, null."""
+
+    @pytest.mark.parametrize("precision, scale", [
+        (7, 2), (17, 2), (18, 0), (19, 4), (27, 2), (38, 17), (38, 0)])
+    def test_equal_to_arrows_conversion(self, precision, scale):
+        from spark_rapids_tpu.cpu.hostbatch import host_vec_to_arrow
+        from spark_rapids_tpu.expr.base import Vec
+        from spark_rapids_tpu.expr.decimal128 import split_int, to_decimal
+        rng = random.Random(precision * 100 + scale)
+        lim = 10 ** precision - 1
+        ints = [rng.randint(-lim, lim) for _ in range(500)] + \
+            [lim, -lim, 0, -1, 1, 2 ** 63, -2 ** 63, 2 ** 64 - 1]
+        ints = [x for x in ints if abs(x) <= lim]
+        valid = np.array([rng.random() < 0.85 for _ in ints])
+        dt = T.DecimalType(precision, scale)
+        if precision > T.DecimalType.MAX_LONG_DIGITS:
+            data = np.array([split_int(x) for x in ints], dtype=np.int64)
+        else:
+            data = np.array(ints, dtype=np.int64)
+        got = host_vec_to_arrow(Vec(dt, data, valid), len(ints))
+        got.validate(full=True)
+        want = pa.array([to_decimal(x, scale) if ok else None
+                         for x, ok in zip(ints, valid)],
+                        type=pa.decimal128(precision, scale))
+        assert got.equals(want) and got.null_count == want.null_count
+
+    def test_no_row_and_no_null(self):
+        from spark_rapids_tpu.cpu.hostbatch import host_vec_to_arrow
+        from spark_rapids_tpu.expr.base import Vec
+        dt = T.DecimalType(17, 2)
+        empty = host_vec_to_arrow(
+            Vec(dt, np.zeros(0, np.int64), np.zeros(0, bool)), 0)
+        assert len(empty) == 0 and empty.type == pa.decimal128(17, 2)
+        full = host_vec_to_arrow(
+            Vec(dt, np.array([-105, 250], np.int64), np.ones(2, bool)), 2)
+        assert full.to_pylist() == [decimal.Decimal("-1.05"),
+                                    decimal.Decimal("2.50")]
+        assert full.buffers()[0] is None

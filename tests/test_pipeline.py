@@ -396,3 +396,104 @@ class TestCpuFallbackRerunCounter:
                       "v": pa.array(np.ones(100))})
         sess.from_arrow(t).group_by("g").agg(n_=Count(col("v"))).collect()
         assert TaskMetrics.get().cpu_fallback_reruns == 0
+
+
+class TestJoinProbeStartsAhead:
+    """A hash join starts its probe side on a prefetch thread before it
+    builds, unless the probe side's scan waits for the build side's keys or
+    pipelined execution is off."""
+
+    class _Side:
+        def __init__(self, schema, batches, log, tag, gate=None):
+            self.output, self.children = schema, ()
+            self._batches, self._log, self._tag = batches, log, tag
+            self._gate = gate
+
+        def execute(self):
+            self._log.append((self._tag, "start",
+                              threading.current_thread().name))
+            if self._gate is not None:
+                # the build side does not end before the probe side began
+                assert self._gate.wait(timeout=20)
+            for b in self._batches:
+                yield b
+            self._log.append((self._tag, "end", ""))
+
+    def _join(self, conf, dpp=False):
+        from spark_rapids_tpu.columnar.batch import Schema
+        from spark_rapids_tpu.exec.joins import TpuShuffledHashJoinExec
+        left = pa.table({"k": pa.array([1, 2, 3, 4], type=pa.int64()),
+                         "a": pa.array([10, 20, 30, 40], type=pa.int64())})
+        right = pa.table({"k": pa.array([2, 4, 5], type=pa.int64()),
+                          "b": pa.array([7, 8, 9], type=pa.int64())})
+        log, probe_began = [], threading.Event()
+
+        class Probe(self._Side):
+            def execute(inner):
+                probe_began.set()
+                return super().execute()
+        probe = Probe(Schema.from_arrow(left.schema),
+                      [batch_from_arrow(left)], log, "probe")
+        build = self._Side(Schema.from_arrow(right.schema),
+                           [batch_from_arrow(right)], log, "build",
+                           gate=None if dpp else probe_began)
+        join = TpuShuffledHashJoinExec(probe, build, [col("k")], [col("k")],
+                                       "inner", conf=conf)
+        if dpp:
+            class _Filt:
+                def set_values(self, v):
+                    log.append(("dpp", "set", ""))
+            join.dpp_filters = [(0, _Filt())]
+        return join, log
+
+    def test_probe_side_runs_beside_the_build(self):
+        conf = TpuConf({})
+        before = EB.PREFETCH_THREADS_STARTED
+        join, log = self._join(conf)
+        out = [batch_to_arrow(b) for b in join.do_execute()]
+        assert sum(t.num_rows for t in out) == 2
+        assert EB.PREFETCH_THREADS_STARTED == before + 1
+        starts = {tag: th for tag, what, th in log if what == "start"}
+        assert starts["probe"].startswith("srtpu-join-probe")
+        assert not starts["build"].startswith("srtpu-")
+
+    def test_dpp_filters_keep_the_build_first(self):
+        before = EB.PREFETCH_THREADS_STARTED
+        join, log = self._join(TpuConf({}), dpp=True)
+        out = [batch_to_arrow(b) for b in join.do_execute()]
+        assert sum(t.num_rows for t in out) == 2
+        assert EB.PREFETCH_THREADS_STARTED == before
+        order = [(tag, what) for tag, what, _ in log]
+        assert order.index(("dpp", "set")) < order.index(("probe", "start"))
+
+    def test_pipeline_off_starts_nothing(self):
+        conf = TpuConf({"spark.rapids.tpu.pipeline.enabled": False})
+        before = EB.PREFETCH_THREADS_STARTED
+        join, log = self._join(conf, dpp=True)
+        join.dpp_filters = []
+        list(join.do_execute())
+        assert EB.PREFETCH_THREADS_STARTED == before
+        order = [(tag, what) for tag, what, _ in log]
+        assert order.index(("build", "end")) < order.index(("probe", "start"))
+
+    def test_an_empty_build_side_stops_the_probe_thread(self):
+        from spark_rapids_tpu.columnar.batch import Schema
+        from spark_rapids_tpu.exec.joins import TpuShuffledHashJoinExec
+        left = pa.table({"k": pa.array(np.arange(64), type=pa.int64())})
+        right = pa.table({"k": pa.array([], type=pa.int64())})
+        log = []
+        probe = self._Side(Schema.from_arrow(left.schema),
+                           [batch_from_arrow(left)] * 8, log, "probe")
+        build = self._Side(Schema.from_arrow(right.schema), [], log, "build")
+        join = TpuShuffledHashJoinExec(probe, build, [col("k")], [col("k")],
+                                       "inner", conf=TpuConf({}))
+        threads = {t.name for t in threading.enumerate()}
+        assert list(join.do_execute()) == []
+        deadline = time.monotonic() + 10
+        while time.monotonic() < deadline and any(
+                t.name.startswith("srtpu-join-probe") and t.is_alive()
+                and t.name not in threads for t in threading.enumerate()):
+            time.sleep(0.05)
+        assert not [t for t in threading.enumerate()
+                    if t.name.startswith("srtpu-join-probe")
+                    and t.is_alive()]
