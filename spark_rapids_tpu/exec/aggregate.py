@@ -23,13 +23,14 @@ from ..expr.base import Expression, Vec, bind_references, output_name
 from ..expr.aggregates import (AggregateFunction, ApproximatePercentile,
                                Average, CollectList, CollectSet, Count, First,
                                Last, Max, Min, Sum, _VarianceFamily)
-from ..ops.rowops import (SortedSegments, compaction_order, gather_vecs,
+from ..ops.rowops import (GatherTally, SortedSegments, compaction_order,
+                          gather_vecs,
                           group_ids_from_sorted, lexsort_indices,
                           segment_reduce, segment_sum_count, sort_keys_for)
 from ..plan.nodes import AggExpr
 from ..utils import metrics as M
-from .base import (TpuExec, UnaryTpuExec, batch_vecs, device_ctx,
-                   kernel_errors, vecs_to_batch)
+from .base import (GatherCounts, TpuExec, UnaryTpuExec, batch_vecs,
+                   device_ctx, kernel_notes, vecs_to_batch)
 from .coalesce import concat_batches
 
 
@@ -43,15 +44,23 @@ def _vals_equal(xp, v: Vec, shift: int):
     return v.data[shift:] == v.data[:-shift]
 
 
-def _sorted_by_keys(xp, key_vecs: List[Vec], all_vecs: List[Vec], row_mask):
+def _sorted_by_keys(xp, key_vecs: List[Vec], all_vecs: List[Vec], row_mask,
+                    tally: GatherTally = None):
+    """(`all_vecs` sorted by the keys with the dead rows last, their row
+    mask, the permutation). Dead rows sort last, so the sorted mask is the
+    first `live` rows and needs no gather of its own."""
+    cap = row_mask.shape[0]
     groups = [[(~row_mask).astype(np.int8)]]
     for kv in key_vecs:
         groups.append(sort_keys_for(xp, kv, True, True))
-    order = lexsort_indices(xp, groups, row_mask.shape[0])
-    return gather_vecs(xp, all_vecs, order), row_mask[order], order
+    order = lexsort_indices(xp, groups, cap)
+    live = xp.sum(row_mask).astype(np.int32)
+    sorted_mask = xp.arange(cap, dtype=np.int32) < live
+    return gather_vecs(xp, all_vecs, order, tally), sorted_mask, order
 
 
-def _group_segments(xp, skeys: List[Vec], sorted_mask):
+def _group_segments(xp, skeys: List[Vec], sorted_mask,
+                    tally: GatherTally = None):
     """(segments, representative key rows) of rows sorted by `skeys` with the
     live rows first; no keys is one group over the live rows. One sort of a
     one-byte flag compacts the group-start rows: its permutation gathers
@@ -63,17 +72,16 @@ def _group_segments(xp, skeys: List[Vec], sorted_mask):
     gid, ng, starts = group_ids_from_sorted(xp, skeys, sorted_mask)
     order = compaction_order(xp, starts)
     return (SortedSegments(xp, gid, ng, sorted_mask, order),
-            gather_vecs(xp, skeys, order))
+            gather_vecs(xp, skeys, order, tally))
 
 
-def _kernel_notes(ctx, box: list, segs: SortedSegments):
-    """What a kernel's trace leaves its host side, in the kernel's box (which
-    the compile service restores when the program comes from a cache): the
+def _kernel_notes(ctx, box: list, segs: SortedSegments, tally: GatherTally):
+    """What a kernel's trace leaves its host side, in the kernel's box: the
     ANSI messages, one per returned error flag, then the two counts of
-    segmented reductions by route (`_run` adds them to the metrics)."""
-    flags = kernel_errors(ctx, box)
-    box.extend((segs.prefix_routed, segs.scattered))
-    return flags
+    segmented reductions by route and the two of row-gathered arrays by
+    route (`_run` adds them to the metrics)."""
+    return kernel_notes(ctx, box, segs.prefix_routed, segs.scattered,
+                        tally.packed, tally.alone)
 
 
 def _seg_sum(xp, data, segs: SortedSegments):
@@ -224,6 +232,7 @@ class TpuHashAggregateExec(UnaryTpuExec):
             M.NUM_PREFIX_REDUCTIONS, M.MODERATE)
         self.scatter_reductions = self.metrics.create(
             M.NUM_SCATTER_REDUCTIONS, M.MODERATE)
+        self.gathers = GatherCounts(self.metrics)
         self._sp_maxes_jit = None
         self._sp_kernel_jit: dict = {}
 
@@ -317,17 +326,18 @@ class TpuHashAggregateExec(UnaryTpuExec):
                     else:
                         buf_vecs.append([a.func.child.eval(ctx, vecs)])
 
+            tally = GatherTally()
             if keys:
                 all_vecs = list(keys) + [v for grp in buf_vecs for v in grp]
                 sorted_vecs, sorted_mask, _ = _sorted_by_keys(
-                    xp, keys, all_vecs, mask)
+                    xp, keys, all_vecs, mask, tally)
                 skeys = sorted_vecs[:len(keys)]
                 sbufs = sorted_vecs[len(keys):]
             else:
                 sorted_vecs, sorted_mask = (
                     [v for grp in buf_vecs for v in grp], mask)
                 skeys, sbufs = [], sorted_vecs
-            segs, reps = _group_segments(xp, skeys, sorted_mask)
+            segs, reps = _group_segments(xp, skeys, sorted_mask, tally)
 
             out_vecs: List[Vec] = list(reps)
             bi = 0
@@ -337,7 +347,7 @@ class TpuHashAggregateExec(UnaryTpuExec):
                                               output_partial, ctx=ctx))
                 bi += len(a.func.partial_types()) if input_partial else 1
             return vecs_to_batch(out_schema, out_vecs, segs.num_groups), \
-                _kernel_notes(ctx, msgs_box, segs)
+                _kernel_notes(ctx, msgs_box, segs, tally)
 
         # merge/final kernels (input_partial) only read partial buffers —
         # never the black-box expressions — so they stay jitted even in
@@ -365,8 +375,9 @@ class TpuHashAggregateExec(UnaryTpuExec):
         out, errs = kernel(batch)
         box = self._kernel_boxes.get(kernel, self._err_msgs)
         raise_kernel_errors(errs, box)
-        self.prefix_reductions.add(box[-2])     # _kernel_notes' tail
-        self.scatter_reductions.add(box[-1])
+        self.prefix_reductions.add(box[-4])     # _kernel_notes' tail
+        self.scatter_reductions.add(box[-3])
+        self.gathers.add(box)
         return out
 
     def _agg_one(self, xp, func: AggregateFunction, sbufs: List[Vec], bi: int,
@@ -688,7 +699,8 @@ class TpuHashAggregateExec(UnaryTpuExec):
         """Phase 2: full output kernel with static fanout buckets per
         single-pass aggregate; normal aggregates ride along."""
         xp = jnp
-        _, svals, segs, reps, smask, ctx = self._sp_prepare(xp, batch)
+        tally = GatherTally()
+        _, svals, segs, reps, smask, ctx = self._sp_prepare(xp, batch, tally)
         cap = batch.capacity
         out_vecs: List[Vec] = list(reps)
         ki = 0
@@ -703,9 +715,9 @@ class TpuHashAggregateExec(UnaryTpuExec):
                 out_vecs.extend(self._agg_one(xp, a.func, buf, 0, segs,
                                               smask, False, False, ctx=ctx))
         return vecs_to_batch(self._schema, out_vecs, segs.num_groups), \
-            _kernel_notes(ctx, self._err_msgs, segs)
+            _kernel_notes(ctx, self._err_msgs, segs, tally)
 
-    def _sp_prepare(self, xp, batch: ColumnarBatch):
+    def _sp_prepare(self, xp, batch: ColumnarBatch, tally=None):
         """Evaluate keys + agg children and sort everything by the keys; the
         shared front half of both single-pass kernels."""
         ctx = device_ctx(batch, self.conf)
@@ -719,13 +731,13 @@ class TpuHashAggregateExec(UnaryTpuExec):
         if keys:
             all_vecs = keys + present
             sorted_vecs, sorted_mask, _ = _sorted_by_keys(xp, keys, all_vecs,
-                                                          mask)
+                                                          mask, tally)
             skeys = sorted_vecs[:len(keys)]
             rest = iter(sorted_vecs[len(keys):])
             svals = [None if v is None else next(rest) for v in vals]
         else:
             skeys, svals, sorted_mask = [], vals, mask
-        segs, reps = _group_segments(xp, skeys, sorted_mask)
+        segs, reps = _group_segments(xp, skeys, sorted_mask, tally)
         return skeys, svals, segs, reps, sorted_mask, ctx
 
     def _sp_agg_one(self, xp, func, v: Vec, segs: SortedSegments, row_mask,

@@ -394,6 +394,30 @@ def kernel_errors(ctx: EvalContext, msgs_box: list):
     return tuple(f for f, _ in entries)
 
 
+def kernel_notes(ctx: EvalContext, msgs_box: list, *counts):
+    """`kernel_errors`, then counts taken while the kernel was traced, left at
+    the box's tail: the compile service restores the box when the program
+    comes from a cache, so the exec can add them to its metrics for every
+    executed batch."""
+    flags = kernel_errors(ctx, msgs_box)
+    msgs_box.extend(counts)
+    return flags
+
+
+class GatherCounts:
+    """An exec's two metrics of its kernels' row gathers, fed from the
+    `(packed, alone)` of a `GatherTally` that `kernel_notes` left last in
+    the kernel's box."""
+
+    def __init__(self, metrics: M.MetricsSet):
+        self.packed = metrics.create(M.NUM_PACKED_GATHER_ARRAYS, M.MODERATE)
+        self.alone = metrics.create(M.NUM_SINGLE_GATHER_ARRAYS, M.MODERATE)
+
+    def add(self, msgs_box: list) -> None:
+        self.packed.add(msgs_box[-2])
+        self.alone.add(msgs_box[-1])
+
+
 def raise_kernel_errors(flags, msgs_box: list) -> None:
     """Host-side: raise the first ANSI violation a kernel reported."""
     for f, m in zip(flags, msgs_box):

@@ -15,9 +15,10 @@ from ..columnar.padding import row_bucket
 from ..compile import instance_jit, kernel_key
 from ..expr.base import (EvalContext, Expression, Vec, bind_references,
                          output_name)
-from ..ops.rowops import compact_vecs
+from ..ops.rowops import GatherTally, compact_vecs
 from ..utils import metrics as M
-from .base import TpuExec, UnaryTpuExec, batch_vecs, device_ctx, vecs_to_batch
+from .base import (GatherCounts, TpuExec, UnaryTpuExec, batch_vecs,
+                   device_ctx, vecs_to_batch)
 
 
 class TpuScanExec(TpuExec):
@@ -73,17 +74,15 @@ class TpuProjectExec(UnaryTpuExec):
         msgs_box = self._err_msgs
 
         def kernel(batch: ColumnarBatch, row_offset):
-            from .base import kernel_errors
+            from .base import kernel_notes
             ctx = device_ctx(batch, self.conf)
             ctx.partition_row_offset = row_offset
             vecs = batch_vecs(batch)
             outs = [e.eval(ctx, vecs) for e in bound]
-            flags = kernel_errors(ctx, msgs_box)
-            # the box's tail, for `do_execute` (the compile service restores
-            # the box when the program comes from a cache): the exact
-            # decimal divisions this trace lowered
-            msgs_box.append(ctx.decimal_divides)
-            return vecs_to_batch(self._schema, outs, batch.num_rows), flags
+            # the box's tail, for `do_execute`: the exact decimal divisions
+            # this trace lowered
+            return vecs_to_batch(self._schema, outs, batch.num_rows), \
+                kernel_notes(ctx, msgs_box, ctx.decimal_divides)
 
         self.decimal_divides = self.metrics.create(M.NUM_DECIMAL_DIVIDES,
                                                    M.MODERATE)
@@ -135,14 +134,17 @@ class TpuFilterExec(UnaryTpuExec):
         msgs_box = self._err_msgs
 
         def kernel(batch: ColumnarBatch):
-            from .base import kernel_errors
+            from .base import kernel_notes
             ctx = device_ctx(batch, self.conf)
             vecs = batch_vecs(batch)
             pred = bound.eval(ctx, vecs)
             keep = pred.data & pred.validity & batch.row_mask()
-            out_vecs, new_n = compact_vecs(jnp, vecs, keep)
+            tally = GatherTally()
+            out_vecs, new_n = compact_vecs(jnp, vecs, keep, tally)
             return vecs_to_batch(batch.schema, out_vecs, new_n), \
-                kernel_errors(ctx, msgs_box)
+                kernel_notes(ctx, msgs_box, tally.packed, tally.alone)
+
+        self.gathers = GatherCounts(self.metrics)
 
         # a condition containing a host black box (pandas UDF / eager
         # fanout expr) runs the kernel eagerly, like TpuProjectExec
@@ -158,6 +160,7 @@ class TpuFilterExec(UnaryTpuExec):
             with self.op_time.timed(), self.filter_time.timed():
                 out, errs = self._kernel(b)
             raise_kernel_errors(errs, self._err_msgs)
+            self.gathers.add(self._err_msgs)
             self.num_output_rows.add(out.row_count())
             yield self._count_output(out)
 

@@ -21,6 +21,7 @@ reference's GpuShuffledHashJoinExec with BuildRight."""
 
 from __future__ import annotations
 
+import dataclasses
 from typing import Iterator, List, Sequence, Tuple
 
 import jax.numpy as jnp
@@ -161,13 +162,19 @@ def _expand_join(probe: ColumnarBatch, build: ColumnarBatch,
     bidx_sorted = xp.clip(lo[pi] + k, 0, bcap - 1)
     bi = order[bidx_sorted]
 
+    # each side moves once, keys and payload in the same word matrices; a
+    # join that returns probe rows only and has no condition moves its keys
+    # alone (a row of a stacked matrix that nothing reads is still gathered)
+    if condition is None and join_type in ("semi", "anti", "existence"):
+        gp = gather_vecs(xp, pkeys, pi)
+        gb = gather_vecs(xp, bkeys, bi)
+    else:
+        left_out = gather_vecs(xp, pvecs, pi)
+        right_out = gather_vecs(xp, bvecs, bi)
+        gp = [left_out[i] for i in probe_key_ix]
+        gb = [right_out[i] for i in build_key_ix]
     # true equality check (hash collision + sentinel guard)
-    gp = gather_vecs(xp, pkeys, pi)
-    gb = gather_vecs(xp, bkeys, bi)
     eq = _keys_equal(xp, gp, gb) & pvalid[pi] & bvalid[bi] & (k < counts[pi])
-
-    left_out = gather_vecs(xp, pvecs, pi)
-    right_out = gather_vecs(xp, bvecs, bi)
 
     cond_errs = ()
     if condition is not None:
@@ -199,11 +206,6 @@ def _expand_join(probe: ColumnarBatch, build: ColumnarBatch,
     if join_type in ("right", "full"):
         bmatched = bmatched.at[xp.where(matched, bi, bcap - 1)].max(matched)
 
-    # null out the right side where no match (outer fill)
-    right_out = [Vec(v.dtype, v.data, v.validity & matched, v.lengths,
-                     v.children)
-                 for v in right_out] if join_type in ("left", "full") else right_out
-
     if join_type in ("semi", "anti", "existence"):
         if join_type == "existence":
             # all live probe rows, plus the exists flag column
@@ -215,6 +217,10 @@ def _expand_join(probe: ColumnarBatch, build: ColumnarBatch,
         out_vecs, n = compact_vecs(xp, pvecs, want & pmask)
         return out_vecs, n, bmatched, cond_errs
 
+    if join_type in ("left", "full"):
+        # null out the right side where no match (outer fill)
+        right_out = [dataclasses.replace(v, validity=v.validity & matched)
+                     for v in right_out]
     out_vecs = left_out + right_out
     compacted, n = compact_vecs(xp, out_vecs, keep)
     return compacted, n, bmatched, cond_errs

@@ -20,9 +20,11 @@ from .. import types as T
 from ..columnar.batch import ColumnarBatch, Schema
 from ..compile import instance_jit, kernel_key, sjit
 from ..expr.base import Expression, Vec, bind_references
-from ..ops.rowops import gather_vecs, lexsort_indices, sort_keys_for
+from ..ops.rowops import (GatherTally, gather_vecs, lexsort_indices,
+                          sort_keys_for)
 from ..utils import metrics as M
-from .base import TpuExec, UnaryTpuExec, batch_vecs, device_ctx, vecs_to_batch
+from .base import (GatherCounts, TpuExec, UnaryTpuExec, batch_vecs,
+                   device_ctx, vecs_to_batch)
 from .coalesce import concat_batches
 
 
@@ -37,12 +39,13 @@ class TpuSortExec(UnaryTpuExec):
         self._bound = [(bind_references(e, child.output), a, nf)
                        for e, a, nf in self.orders]
         self.sort_time = self.metrics.create(M.SORT_TIME, M.MODERATE)
+        self.gathers = GatherCounts(self.metrics)
         bound = self._bound
         self._err_msgs: list = []
         msgs_box = self._err_msgs
 
         def kernel(batch: ColumnarBatch):
-            from .base import kernel_errors
+            from .base import kernel_notes
             ctx = device_ctx(batch, self.conf)
             vecs = batch_vecs(batch)
             mask = batch.row_mask()
@@ -50,9 +53,10 @@ class TpuSortExec(UnaryTpuExec):
             for e, asc, nf in bound:
                 groups.append(sort_keys_for(jnp, e.eval(ctx, vecs), asc, nf))
             order = lexsort_indices(jnp, groups, batch.capacity)
-            out = gather_vecs(jnp, vecs, order)
+            tally = GatherTally()
+            out = gather_vecs(jnp, vecs, order, tally)
             return vecs_to_batch(batch.schema, out, batch.num_rows), \
-                kernel_errors(ctx, msgs_box)
+                kernel_notes(ctx, msgs_box, tally.packed, tally.alone)
 
         self._kernel = instance_jit(
             kernel, op="exec.sort",
@@ -65,6 +69,7 @@ class TpuSortExec(UnaryTpuExec):
         with self.sort_time.timed():
             out, errs = self._kernel(batch)
         raise_kernel_errors(errs, self._err_msgs)
+        self.gathers.add(self._err_msgs)
         return out
 
     def do_execute(self) -> Iterator[ColumnarBatch]:

@@ -247,7 +247,7 @@ class TpuWindowExec(UnaryTpuExec):
         msgs_box = self._err_msgs
 
         def kernel(batch: ColumnarBatch):
-            from .base import kernel_errors
+            from .base import kernel_notes
             ctx = device_ctx(batch, self.conf)
             vecs = batch_vecs(batch)
             mask = batch.row_mask()
@@ -261,9 +261,13 @@ class TpuWindowExec(UnaryTpuExec):
             groups += [sort_keys_for(jnp, v, True, True) for v in part_vecs]
             groups += [sort_keys_for(jnp, v, a, nf) for v, a, nf in order_vecs]
             perm = lexsort_indices(jnp, groups, cap)
-            svecs = gather_vecs(jnp, vecs, perm)
-            spart = gather_vecs(jnp, part_vecs, perm)
-            sorder = gather_vecs(jnp, [v for v, _, _ in order_vecs], perm)
+            # one call: the columns, the partition keys and the order keys
+            # share word matrices (and move once where they are one column)
+            moved = gather_vecs(
+                jnp, vecs + part_vecs + [v for v, _, _ in order_vecs], perm)
+            svecs = moved[:len(vecs)]
+            spart = moved[len(vecs):len(vecs) + len(part_vecs)]
+            sorder = moved[len(vecs) + len(part_vecs):]
             # padding sorted last => mask keeps its canonical first-n form
 
             part_start = key_change_flags(jnp, spart, cap) & mask
@@ -291,11 +295,9 @@ class TpuWindowExec(UnaryTpuExec):
             out = list(svecs)
             for fn, _ in bound_fns:
                 out.append(_eval_device(fn, env))
-            flags = kernel_errors(ctx, msgs_box)
-            # the box's tail, for `do_execute` (the compile service restores
-            # the box when the program comes from a cache): the exact
-            # decimal aggregates this trace lowered
-            msgs_box.append(env.decimal_aggs)
+            # the box's tail, for `do_execute`: the exact decimal
+            # aggregates this trace lowered
+            flags = kernel_notes(ctx, msgs_box, env.decimal_aggs)
             return vecs_to_batch(self._schema, out, batch.num_rows), \
                 flags, jnp.sum(part_start)
 

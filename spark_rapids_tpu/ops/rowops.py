@@ -7,8 +7,16 @@ practical so the CPU engine shares semantics; the sort/segment ops use jax-speci
 primitives (lexsort/segment_sum) with numpy equivalents behind the same signature.
 
 Design notes (ARCHITECTURE.md #4):
-  * compaction keeps the padded capacity and returns a new logical count — a stable
-    argsort on the keep-mask, which XLA lowers to a single sort+gather;
+  * rows move together: `gather_vecs` takes a batch's vecs and ONE index vector and,
+    on the device, moves every row-aligned array of the flat vecs as rows of a few
+    stacked (8, n) uint32 matrices, one gather a matrix (`_RowMover`): flags and
+    sub-word integers as bit fields, 32-bit integers as they are, 64-bit ones as
+    halves, narrow byte matrices as words; floats and wide matrices move alone. The
+    chip gathers eight 32-bit rows for the price of one index pass, so a gather an
+    array (what `Vec.gather` and the numpy path do) costs a batch many times more. Callers hand over everything that moves by the same
+    indices in one call; a `GatherTally` counts what went which way;
+  * compaction keeps the padded capacity and returns a new logical count — one stable
+    sort of the one-byte keep flag, then `gather_vecs` by its permutation;
   * multi-key sort builds a key list per SortOrder (null indicator + transformed
     data) and lexsorts; descending integer keys use bitwise-not (no INT_MIN
     overflow), descending floats negate, strings contribute big-endian words;
@@ -27,20 +35,193 @@ from typing import List, Optional, Sequence, Tuple
 import numpy as np
 
 from .. import types as T
-from ..expr.base import Vec
+from ..expr.base import Vec, vec_map_arrays
 
 BIG_I32 = np.int32(2 ** 31 - 1)
 LANE = 128
 
 
-def _take(xp, arr, idx):
-    return arr[idx]
+class GatherTally:
+    """What the row gathers of one kernel did, counted while it is traced:
+    distinct arrays that rode in a stacked word matrix, the matrices
+    gathered for them, and arrays gathered alone."""
+
+    def __init__(self):
+        self.packed = 0
+        self.matrices = 0
+        self.alone = 0
 
 
-def gather_vecs(xp, vecs: Sequence[Vec], idx) -> List[Vec]:
-    """Gather rows by index across columns (JoinGatherer analog); recurses
-    through nested children."""
-    return [v.gather(xp, idx) for v in vecs]
+# 32-bit rows of a (K, n) matrix that the chip gathers along n for the price
+# of one index pass (PERF.md price list: 11 ms for 8 rows of 2,097,152, 19 ms
+# for one row alone)
+PACK_ROWS = 8
+# widest row of an (n, W) matrix that is cut into word planes, the words of
+# one matrix; a wider one (a 256-byte string) is gathered as it is, a whole
+# row per index, which costs per index whatever the width (PERF.md price
+# list, PR 36: at 64 bytes the two ways tie or words lose)
+PACK_MAX_ROW_BYTES = 4 * PACK_ROWS
+
+
+def _as_u32(a):
+    """A (n,) array of a 1-, 2- or 4-byte dtype or bool, each value's bits
+    in the low end of a uint32."""
+    import jax
+    if a.dtype == np.bool_:
+        return a.astype(np.uint32)
+    size = a.dtype.itemsize
+    bits = jax.lax.bitcast_convert_type(a, np.dtype(f"uint{8 * size}"))
+    return bits.astype(np.uint32)
+
+
+def _from_u32(w, dtype):
+    """Inverse of `_as_u32`; `w` holds nothing above the dtype's bits."""
+    import jax
+    dtype = np.dtype(dtype)
+    if dtype == np.bool_:
+        return w != 0
+    bits = w.astype(np.dtype(f"uint{8 * dtype.itemsize}"))
+    return jax.lax.bitcast_convert_type(bits, dtype)
+
+
+class _RowMover:
+    """Moves row-aligned arrays (leading dim n) by one index vector as rows
+    of stacked (K, n) uint32 matrices, K <= PACK_ROWS, one gather a matrix.
+
+    `add` files an array once (by identity): bools and 1- and 2-byte
+    integers as bit fields of shared words, 32-bit integers as they are,
+    64-bit integers as two halves, an (n, W) matrix of those column by
+    column, a narrow byte matrix as W / 4 words. Floats (the chip emulates
+    float64, and float32 bit patterns did not come back equal from it:
+    PERF.md, PR 36) and everything wider or deeper are gathered alone, as
+    `arr[idx]`. `move` gathers; `got` cuts an array's planes back into its
+    dtype and shape. Indices mean what they mean to `arr[idx]`."""
+
+    def __init__(self, idx, tally: Optional[GatherTally] = None):
+        self.idx = idx
+        self.tally = tally or GatherTally()
+        self.words: List = []     # uint32 (n,) planes
+        self.fields: List = []    # (uint32 (n,) values, bits) to share words
+        self.where: dict = {}     # field -> (its word, its shift)
+        # id(array) -> (how to rebuild it, the array: its id stays its own)
+        self.plans: dict = {}
+        self.out_words: List = []
+
+    def _file_1d(self, a):
+        """Plan for one (n,) array, or None if it does not pack."""
+        kind, size = a.dtype.kind, a.dtype.itemsize
+        if kind == "b" or (kind in "iu" and size < 4):
+            self.fields.append((_as_u32(a), 1 if kind == "b" else 8 * size))
+            return ("field", len(self.fields) - 1)
+        if kind in "iu" and size == 4:
+            self.words.append(_as_u32(a))
+            return ("word", len(self.words) - 1)
+        if kind in "iu" and size == 8:
+            import jax
+            u = jax.lax.bitcast_convert_type(a, np.uint64)
+            self.words.append(u.astype(np.uint32))
+            self.words.append((u >> np.uint64(32)).astype(np.uint32))
+            return ("halves", len(self.words) - 2)
+        return None
+
+    def add(self, a) -> None:
+        if id(a) in self.plans:
+            return
+        plan = None
+        narrow = a.ndim == 2 and \
+            a.shape[1] * a.dtype.itemsize <= PACK_MAX_ROW_BYTES
+        if a.ndim == 1:
+            plan = self._file_1d(a)
+        elif narrow and a.dtype == np.uint8 and a.shape[1] % 4 == 0:
+            import jax.numpy as jnp
+            first = len(self.words)
+            self.words.extend(_string_words(jnp, a))
+            plan = ("bytes", first, a.shape[1] // 4)
+        elif narrow and a.dtype.kind in "iu" and a.dtype.itemsize >= 4:
+            plan = ("cols", [self._file_1d(a[:, j])     # decimal128 limbs
+                             for j in range(a.shape[1])])
+        if plan is None:
+            self.plans[id(a)] = (None, a, self.lone(a))
+        else:
+            self.plans[id(a)] = (plan, a)
+            self.tally.packed += 1
+
+    def lone(self, a):
+        """`a` gathered alone, as `arr[idx]` along its first axis."""
+        self.tally.alone += 1
+        return a[self.idx]
+
+    def move(self) -> None:
+        import jax.numpy as jnp
+        # widest fields first: 16, 8 and 1 divide 32, so words fill exactly
+        order = sorted(range(len(self.fields)),
+                       key=lambda i: -self.fields[i][1])
+        used = 32
+        for i in order:
+            vals, bits = self.fields[i]
+            if used + bits > 32:
+                self.words.append(jnp.zeros_like(vals))
+                used = 0
+            self.words[-1] = self.words[-1] | (vals << np.uint32(used))
+            self.where[i] = (len(self.words) - 1, used)
+            used += bits
+        for lo in range(0, len(self.words), PACK_ROWS):
+            m = jnp.stack(self.words[lo:lo + PACK_ROWS])
+            self.out_words.extend(m[:, self.idx])
+            self.tally.matrices += 1
+
+    def _rebuild(self, plan, dtype):
+        import jax
+        import jax.numpy as jnp
+        kind = plan[0]
+        if kind == "field":
+            w, shift = self.where[plan[1]]
+            bits = self.fields[plan[1]][1]
+            v = (self.out_words[w] >> np.uint32(shift)) & \
+                np.uint32((1 << bits) - 1)
+            return _from_u32(v, dtype)
+        if kind == "word":
+            return _from_u32(self.out_words[plan[1]], dtype)
+        lo, hi = self.out_words[plan[1]], self.out_words[plan[1] + 1]
+        u = (hi.astype(np.uint64) << np.uint64(32)) | lo.astype(np.uint64)
+        return jax.lax.bitcast_convert_type(u, dtype)
+
+    def got(self, a):
+        import jax.numpy as jnp
+        plan = self.plans[id(a)][0]
+        if plan is None:
+            return self.plans[id(a)][2]
+        if plan[0] == "bytes":
+            ws = jnp.stack(self.out_words[plan[1]:plan[1] + plan[2]], axis=1)
+            shifts = np.array([24, 16, 8, 0], dtype=np.uint32)
+            return ((ws[:, :, None] >> shifts) & np.uint32(0xFF)).astype(
+                np.uint8).reshape(ws.shape[0], 4 * plan[2])
+        if plan[0] == "cols":
+            return jnp.stack([self._rebuild(p, a.dtype) for p in plan[1]],
+                             axis=1)
+        return self._rebuild(plan, a.dtype)
+
+
+def gather_vecs(xp, vecs: Sequence[Vec], idx,
+                tally: Optional[GatherTally] = None) -> List[Vec]:
+    """Gather rows by index across columns (JoinGatherer analog).
+
+    On the device the columns move together: every row-aligned array of
+    the flat vecs rides in a few stacked word matrices (`_RowMover`), each
+    gathered once by `idx`, and is cut back bit for bit; an array that
+    several vecs share moves once (a long string's blob is not row-aligned
+    and passes through). Nested vecs move down their children one array at
+    a time, as the CPU engine's numpy path moves everything. `tally` counts
+    what went which way."""
+    if xp is np or np.ndim(idx) != 1:
+        return [v.gather(xp, idx) for v in vecs]
+    mover = _RowMover(idx, tally)
+    for v in vecs:
+        if v.children is None:
+            vec_map_arrays(v, mover.add)
+    mover.move()
+    return [vec_map_arrays(v, mover.got if v.children is None else mover.lone)
+            for v in vecs]
 
 
 def stable_lexsort(xp, keys: Sequence):
@@ -70,12 +251,13 @@ def compaction_order(xp, keep_mask):
     return stable_lexsort(xp, [(~keep_mask).astype(np.int8)])
 
 
-def compact_vecs(xp, vecs: Sequence[Vec], keep_mask) -> Tuple[List[Vec], any]:
+def compact_vecs(xp, vecs: Sequence[Vec], keep_mask,
+                 tally: Optional[GatherTally] = None) -> Tuple[List[Vec], any]:
     """Stable-move rows where keep_mask (bool[cap]) to the front; returns
     (columns, new_count). Padding tail contents are unspecified."""
     order = compaction_order(xp, keep_mask)
     new_count = xp.sum(keep_mask).astype(np.int32)
-    return gather_vecs(xp, vecs, order), new_count
+    return gather_vecs(xp, vecs, order, tally), new_count
 
 
 def _string_words(xp, data) -> List:

@@ -33,6 +33,10 @@ AGG_TIME = "computeAggTime"
 # a prefix sum and a difference at the group ends, or a scatter
 NUM_PREFIX_REDUCTIONS = "numPrefixSumReductions"
 NUM_SCATTER_REDUCTIONS = "numScatterReductions"
+# row-aligned arrays the executed kernels' row gathers moved, by route: as
+# rows of a stacked word matrix, or one array a gather (ops.rowops.gather_vecs)
+NUM_PACKED_GATHER_ARRAYS = "numPackedGatherArrays"
+NUM_SINGLE_GATHER_ARRAYS = "numSingleGatherArrays"
 JOIN_TIME = "joinTime"
 FILTER_TIME = "filterTime"
 BUILD_TIME = "buildTime"

@@ -121,7 +121,8 @@ def smoke_programs():
     return seen
 
 
-@pytest.mark.parametrize("op", ["exec.join.probe_counts", "exec.aggregate"])
+@pytest.mark.parametrize("op", ["exec.join.probe_counts", "exec.join.expand",
+                                "exec.aggregate"])
 def test_smoke_program_compiles(one_chip, smoke_programs, op):
     fn, dyn = smoke_programs[op]
     avals = jax.tree_util.tree_map(

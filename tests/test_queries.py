@@ -185,19 +185,26 @@ class TestSortQueries:
 
 
 class TestJoinQueries:
-    def _tables(self, session, rng):
+    def _tables(self, session, rng, payload="narrow"):
         left = session.from_arrow(make_table(rng, n=400))
         dim = pa.table({
             "id": pa.array(list(range(0, 40)) + [None], type=pa.int64()),
             "name": pa.array([f"name_{i}" for i in range(40)] + [None]),
         })
+        if payload == "narrow_and_wide":
+            # a description of up to 200 bytes beside the 8-byte name: the
+            # row gathers cut the one into words and move the other whole
+            dim = dim.append_column("descr", pa.array(
+                [None if i % 7 == 3 else ("word%d " % i) * (i % 29)
+                 for i in range(41)]))
         right = session.from_arrow(dim)
         return left, right
 
+    @pytest.mark.parametrize("payload", ["narrow", "narrow_and_wide"])
     @pytest.mark.parametrize("how", ["inner", "left", "right", "full", "semi",
                                      "anti"])
-    def test_join_types(self, session, rng, how):
-        left, right = self._tables(session, rng)
+    def test_join_types(self, session, rng, how, payload):
+        left, right = self._tables(session, rng, payload)
         q = left.join(right, on="id", how=how)
         sort_cols = ["id", "val"] if how in ("semi", "anti") else None
         tpu = q.collect()

@@ -43,10 +43,11 @@ def _plan_names(node):
     return [node.name] + [n for c in node.children for n in _plan_names(c)]
 
 
-@pytest.mark.parametrize("seed", [7, 2147483777])
+@pytest.mark.parametrize("rows,seed", [(20_000, 7), (20_000, 2147483777),
+                                       (4_999, 36)])
 def test_q1_equals_the_plain_reference_in_all_ten_columns(
-        q1, tmp_path_factory, seed):
-    paths = _data(tmp_path_factory, 20_000, seed)
+        q1, tmp_path_factory, rows, seed):
+    paths = _data(tmp_path_factory, rows, seed)
     session = TpuSession({})
     got = q1.build(session, paths).collect()
     names = _plan_names(session.last_plan)
