@@ -43,5 +43,38 @@ def _tune_host_malloc() -> bool:
 
 _tune_host_malloc()
 
+# seconds between two `settle_host_heap()`s that do anything
+_SETTLE_EVERY_S = 60.0
+_settled_at = None
+
+
+def settle_host_heap() -> bool:
+    """Python's cyclic collector as a long-lived executor wants it: what a
+    query that compiled leaves behind (programs, their jaxprs, the plan and
+    kernel caches, jax's own modules: some 150,000 container objects) lives
+    as long as the process, and every full collection walks all of it:
+    55-60 ms in the sandbox and 0.115 s on the chip machine, once in some
+    thirty warm queries, in the middle of whichever query crosses the
+    collector's threshold (PERF.md, fault 23). Called at the end of a query
+    that compiled: one full collection, then everything alive moves to the
+    permanent generation (`gc.freeze`), so that later full collections walk
+    only what was made since. What was frozen earlier and has died since is
+    thawed and collected first, so nothing stays frozen for longer than to
+    the next call that works, and a call works at most once a minute: a
+    process that compiles in every query pays one collection a minute for
+    it. False where the call came too early, or the collector is off."""
+    import gc
+    import time
+    global _settled_at
+    now = time.monotonic()
+    if not gc.isenabled() or (
+            _settled_at is not None and now - _settled_at < _SETTLE_EVERY_S):
+        return False
+    _settled_at = now
+    gc.unfreeze()
+    gc.collect()
+    gc.freeze()
+    return True
+
 from . import types  # noqa: F401
 from .config import TpuConf, get_default_conf  # noqa: F401

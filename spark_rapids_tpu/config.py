@@ -342,11 +342,15 @@ register("spark.rapids.sql.format.hiveText.deviceDecode.enabled", "bool",
          "the serde (GpuHiveTableScanExec analog).")
 register("spark.rapids.sql.format.orc.enabled", "bool", True, "Enable TPU ORC scan.")
 register("spark.rapids.sql.format.orc.deviceDecode.enabled", "bool", True,
-         "Decode flat ORC stripes on device: RLEv2 runs expand via "
-         "searchsorted run tables with big-endian bit-window unpacking, "
-         "present streams bit-unpack msb-first, strings gather from the "
-         "stripe blob (GpuOrcScan analog). Unsupported stripes fall back "
-         "to the pyarrow host path per stripe.")
+         "Decode flat ORC stripes on device, one compile-service program "
+         "a column (io.orc.*): RLEv2 runs expand by one mark per run and a "
+         "prefix sum with big-endian bit windows read from 32-bit words, "
+         "decimals of at most 18 digits fold their zigzag varints from the "
+         "value ends, present streams bit-unpack msb-first, dictionary "
+         "strings gather from the dictionary's matrix (GpuOrcScan analog). "
+         "Unsupported columns and stripes fall back to the pyarrow host "
+         "path per column and per stripe, each counted in "
+         "TaskMetrics.scan_host_decoded.")
 register("spark.rapids.sql.format.csv.enabled", "bool", True, "Enable TPU CSV scan.")
 register("spark.rapids.sql.format.json.enabled", "bool", True, "Enable TPU JSON scan.")
 register("spark.rapids.sql.format.iceberg.enabled", "bool", True,
@@ -480,9 +484,11 @@ register("spark.rapids.tpu.pipeline.enabled", "bool", True,
          "Pipelined execution: bounded-depth background prefetch of "
          "upstream batches at the scan, coalesce-input and result-sink "
          "seams (host-side work overlaps device execution) plus the "
-         "fused multi-chunk scan decode. Off restores the strictly "
+         "fused multi-chunk parquet scan decode and the side-by-side walk "
+         "of an ORC stripe's columns. Off restores the strictly "
          "serial pre-pipeline paths — zero prefetch threads, one decode "
-         "dispatch group per row-group chunk.")
+         "dispatch group per row-group chunk, one ORC column walked at a "
+         "time on the scan's thread.")
 register("spark.rapids.tpu.pipeline.prefetch.depth", "int", 2,
          "Max batches a pipeline prefetch thread may run ahead of its "
          "consumer. Prefetched batches are parked as spillable (budget-"

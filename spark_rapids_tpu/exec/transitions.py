@@ -73,20 +73,45 @@ def device_batch_to_host(b: ColumnarBatch) -> HostBatch:
     it reads `num_rows` directly and adds nothing to `host_sync_ns`."""
     with spans.timed("sink.d2h", "d2h_ns", kind=spans.KIND_IO):
         n = int(b.num_rows)
+        # every flat column's copies are started before the first is waited
+        # for, so they wait beside each other, and a string column's matrix,
+        # lengths and overflow starts are cut to the live rows on the device
+        # where that is most of the bytes: an answer of four rows at a
+        # capacity of two million ships its rows, not 40 MB. Where most of
+        # the capacity is alive they cross whole, as they always did, and
+        # no program is compiled for the cut (0.2 s apiece on the chip, in
+        # the first query of every process)
+        cut = {}
+        for i, c in enumerate(b.columns):
+            if c.children is None:
+                parts = [c.validity[:n], c.data[:n]]
+                if c.is_string:
+                    few = 4 * n <= c.lengths.shape[0]
+                    parts[1] = c.data[:n] if few else c.data
+                    parts.append(c.lengths[:n] if few else c.lengths)
+                    if c.overflow is not None:
+                        parts += [c.overflow[0], c.overflow[1][:n] if few
+                                  else c.overflow[1]]
+                for a in parts:
+                    if hasattr(a, "copy_to_host_async"):
+                        a.copy_to_host_async()
+                cut[i] = parts
         vecs = []
-        for c in b.columns:
+        for i, c in enumerate(b.columns):
             if c.children is not None:
                 from ..cpu.hostbatch import vec_map_arrays
                 vecs.append(vec_map_arrays(Vec.from_column(c),
                                            lambda a: np.asarray(a)[:n]))
                 continue
-            valid = np.asarray(c.validity[:n])
+            valid, data, *more = cut[i]
+            valid = np.asarray(valid)
             if c.is_string:
                 from ..columnar.strings import assemble_matrix
-                mat, lens = assemble_matrix(c.data, c.lengths, c.overflow, n)
+                mat, lens = assemble_matrix(
+                    data, more[0], tuple(more[1:]) or None, n)
                 vecs.append(Vec(c.dtype, mat, valid, lens))
             else:
-                vecs.append(Vec(c.dtype, np.asarray(c.data[:n]), valid))
+                vecs.append(Vec(c.dtype, np.asarray(data), valid))
         return HostBatch(b.schema, vecs, n)
 
 

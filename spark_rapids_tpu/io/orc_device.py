@@ -5,40 +5,62 @@ Parquet scan).
 
 TPU shape of the same split as `parquet_device.py` — the serial,
 byte-walking control plane stays on the host; every O(rows) expansion runs
-on the device:
+on the device, one compile-service program a column (`io.orc.<kind>`:
+`int`, `decimal`, `string_dict`, `string_direct`, `timestamp`, `float`,
+`bool`, `byte`), so each has a name in a trace, counts as a dispatch and
+reloads from the persistent cache:
 
-  host (cheap, control-plane):
+  host (cheap, control-plane), a column at a time under `scan.walk`, the
+  stripe's columns side by side on the process's few walkers
+  (`walker_pool`) where execution is pipelined, so the chip is handed the
+  first column as soon as it is walked and waits for no other:
     * postscript/footer/stripe-footer via a minimal protobuf wire parser;
     * compressed-stream deframing (3-byte block headers; zlib "deflate"
       blocks via zlib, snappy via pyarrow using the block's own varint
       length prefix; lz4/zstd raw blocks don't self-describe -> host);
-    * RLEv2 run STRUCTURE scan: SHORT_REPEAT -> repeat run, fixed-delta
-      DELTA -> arithmetic run, DIRECT -> bit-packed run (bytes shipped
-      packed), PATCHED_BASE / variable-delta -> host-decoded literal runs
-      (their varint/patch walks are inherently serial) appended to a small
-      aux array — values are never expanded row-wise on the host;
+    * RLEv2 run STRUCTURE scan: SHORT_REPEAT and fixed-delta DELTA ->
+      arithmetic runs, DIRECT -> bit-packed runs (bytes shipped packed),
+      PATCHED_BASE / variable-delta -> host-decoded (their varint/patch
+      walks are inherently serial) and packed again as bit-packed runs, so
+      the device knows two kinds of run — values are never expanded
+      row-wise on the host;
     * present/boolean byte-RLE run scan (runs, not bits);
-    * string LENGTH streams expanded host-side (tiny) -> offsets by cumsum.
+    * string LENGTH streams expanded host-side (tiny) -> offsets by cumsum;
+    * every table, byte stream and blob padded to a power-of-two bucket
+      (`scan.pack`) and shipped in one transfer a column (`scan.h2d`): the
+      programs are keyed by buckets and a few static flags, never by a
+      file's exact counts.
   device (the actual data work):
-    * RLEv2 expansion: output slot -> run via searchsorted over the run
-      table; repeat/arith runs computed, packed runs unpacked with
-      big-endian 64-bit gather windows + vector shifts, zigzag undone with
-      vector ops;
+    * RLEv2 expansion: output slot -> run by one mark per run and a prefix
+      sum (`_slot_runs`, `parquet_device._run_of_slot`'s way), the run's
+      words by one stacked gather, packed runs unpacked from two or three
+      big-endian 32-bit words a slot by one more, zigzag undone with vector
+      ops;
+    * DECIMAL (precision <= 18): the zigzag base-128 varint mantissas fold
+      from their value ends (`_varint_zigzag`): terminator bits per 32-byte
+      block, a prefix sum, two stacked gathers, shifts;
     * present bits: byte runs expanded and bit-unpacked msb-first;
     * FLOAT/DOUBLE: raw little-endian stream shipped once, viewed as lanes;
-    * strings: value spans gathered from the shipped data/dictionary blob
-      into the byte-matrix layout (shared `_gather_strings`);
-    * null scatter by rank = cumsum(present) (shared `_scatter_values`).
+    * strings: dictionary entries' bytes and lengths gathered by index out
+      of the dictionary's own matrix (`_dictionary_rows`); direct values'
+      spans gathered from the shipped blob (shared `_string_matrix_tail`);
+    * null scatter by rank = prefix sum of the present bits, skipped where
+      a column has no PRESENT stream.
 
-Anything else (RLEv1 DIRECT encoding, timestamps/decimals/nested, exotic
-codecs, over-wide strings) raises DeviceDecodeUnsupported and the scan
-falls back to the pyarrow host path PER STRIPE — the per-row-group
-fallback discipline of the parquet path applied to ORC's stripe unit."""
+Anything else (RLEv1 DIRECT encoding, decimals past 18 digits, nested
+types, exotic codecs, over-wide strings) raises DeviceDecodeUnsupported and
+the scan falls back to the pyarrow host path PER COLUMN or PER STRIPE — the
+per-row-group fallback discipline of the parquet path applied to ORC's
+stripe unit — and counts every such unit (`TaskMetrics.scan_host_decoded`)."""
 
 from __future__ import annotations
 
+import concurrent.futures as cf
 import functools
+import os
 import struct
+import sys
+import threading
 import zlib
 from dataclasses import dataclass, field
 from typing import Dict, Iterator, List, Optional, Tuple
@@ -47,8 +69,11 @@ import numpy as np
 
 from .. import types as T
 from ..columnar.padding import row_bucket
-from .parquet_device import (DeviceDecodeUnsupported, _gather_strings,
-                             _host_cols_to_device, _scatter_values)
+from ..ops.rowops import PACK_ROWS
+from ..utils import spans
+from .parquet_device import (DeviceDecodeUnsupported, _host_cols_to_device,
+                             _note_dispatches, _pow2, _prefix_sum_i32, _ship,
+                             _string_matrix_tail)
 
 __all__ = ["OrcFileInfo", "columns_supported", "decode_stripe",
            "device_decode_file", "file_supported"]
@@ -490,9 +515,19 @@ def _unpack_be_host(buf: bytes, count: int, width: int) -> np.ndarray:
     return (w * weights).sum(axis=1, dtype=np.uint64).view(np.int64)
 
 
+def _pack_be(u: np.ndarray, width: int) -> bytes:
+    """Inverse of `_unpack_be_host`: uint64 values as a big-endian bit
+    stream of `width` bits each (the last byte zero-padded)."""
+    bits = np.unpackbits(u.astype(">u8").view(np.uint8).reshape(-1, 8),
+                         axis=1)[:, 64 - width:]
+    return np.packbits(bits.reshape(-1)).tobytes()
+
+
 class _RunTable:
     """Accumulates RLEv2 runs: kind 0=repeat(base) 1=arith(base,step)
-    2=packed(offs:bit,width) 3=literal(offs into aux)."""
+    2=packed(offs:bit,width) 3=literal(offs into aux). `arrays()` is the
+    host mirror's view, `device_arrays()` the device's, in which a literal
+    run is a packed one."""
 
     def __init__(self):
         self.kinds: List[int] = []
@@ -524,7 +559,7 @@ class _RunTable:
         aux = (np.concatenate(self.aux) if self.aux
                else np.zeros(1, np.int64))
         packed = (np.frombuffer(bytes(self.packed), np.uint8)
-                  if self.packed else np.zeros(1, np.uint8))
+                  if len(self.packed) else np.zeros(1, np.uint8))
         return (np.array(self.kinds, np.uint8),
                 np.array(self.counts, np.int64),
                 np.array(self.base, np.int64),
@@ -533,12 +568,165 @@ class _RunTable:
                 np.array(self.width, np.uint8),
                 packed, aux)
 
+    def device_arrays(self, signed: bool, cap: int = 0):
+        """(ends int32[R], table uint32[7, R], words uint32[P], wide): what
+        `_expand_rlev2` reads. Per run its exclusive end slot and seven
+        words: first slot, base and step as 32-bit halves, bit offset into
+        the packed stream, width | packed << 8. Literal runs (PATCHED_BASE,
+        variable DELTA: the host decoded them) are packed again, big-endian
+        at the width their largest value needs, zigzagged first in a signed
+        stream, behind the DIRECT runs' bytes. R and P are power-of-two
+        buckets (padding runs hold nothing and end where the last real run
+        does; P is at least a bit for each of the `cap` slots, so two
+        columns of few packed bits share a program); `wide` says a width
+        passes 32 bits, the one fact of the widths a program is
+        specialised on."""
+        from ..native import runtime as native
+        offs, width = self.offs, self.width
+        literal = np.flatnonzero(np.asarray(self.kinds) == 3)
+        packed = np.frombuffer(self.packed, np.uint8)
+        total, tails = packed.size, []
+        if literal.size:
+            offs = np.array(offs, np.int64)
+            width = np.array(width, np.uint8)
+            for vals, i in zip(self.aux, literal):
+                u = vals.view(np.uint64)
+                if signed:
+                    u = (u << np.uint64(1)) ^ (vals >> 63).view(np.uint64)
+                w = max(int(u.max()).bit_length(), 1) if len(u) else 1
+                offs[i], width[i] = total * 8, w
+                tails.append(np.frombuffer(_pack_be(u, w), np.uint8))
+                total += tails[-1].size
+        if total >= 1 << 27:
+            raise DeviceDecodeUnsupported("packed RLEv2 stream past 128 MiB")
+        words = _be_words([packed] + tails, total, cap // 32)
+        r, rb = len(self.kinds), _bucket(len(self.kinds))
+        done = native.orc_run_table(self.kinds, self.counts, self.base,
+                                    self.step, offs, width, rb)
+        if done is not None:
+            return done[0], done[1], words, done[2]
+        kinds = np.array(self.kinds, np.int64)
+        counts = np.array(self.counts, np.int64)
+        ends = np.cumsum(counts)
+        base = np.array(self.base, np.int64).view(np.uint64)
+        step = np.array(self.step, np.int64).view(np.uint64)
+        width = np.array(width, np.int64)
+        # written row by row into the two arrays that ship: every
+        # temporary of half a million runs is pages the host faults in
+        table = np.zeros((7, rb), np.uint32)
+        for row, vals in enumerate((
+                ends - counts, base, base >> np.uint64(32), step,
+                step >> np.uint64(32), np.array(offs, np.int64),
+                width | ((kinds >= 2) << 8))):
+            table[row, :r] = vals    # the cast keeps the low 32 bits
+        ends_b = np.full(rb, ends[-1] if r else 0, np.int32)
+        ends_b[:r] = ends
+        return ends_b, table, words, bool(r and int(width.max()) > 32)
+
+
+def _delta_literal_run(buf, pos: int, signed: bool):
+    """A DELTA run with bit-packed deltas at `pos` -> (its values, the
+    next run's position). A serial walk: each value is the one before it
+    plus a delta."""
+    n = len(buf)
+    b0 = buf[pos]
+    cnt = ((b0 & 1) << 8 | buf[pos + 1]) + 1
+    p = pos + 2
+    base, p = _svarint(buf, p) if signed else _pb_varint(buf, p)
+    db, p = _svarint(buf, p)
+    if cnt < 2:
+        raise DeviceDecodeUnsupported(
+            "DELTA run shorter than 2 with literal deltas")
+    width = _decode_width((b0 >> 1) & 0x1F)
+    nbytes = ((cnt - 2) * width + 7) // 8
+    if p + nbytes > n:
+        raise DeviceDecodeUnsupported("truncated DELTA run")
+    deltas = _unpack_be_host(buf[p:p + nbytes], cnt - 2,
+                             width).astype(np.int64)
+    sign = 1 if db >= 0 else -1
+    vals = np.empty(cnt, np.int64)
+    vals[0] = base
+    vals[1] = base + db
+    np.cumsum(sign * deltas, out=deltas)
+    vals[2:] = base + db + deltas
+    return vals, p + nbytes
+
+
+def _patched_base_run(buf, pos: int):
+    """A PATCHED_BASE run at `pos` -> (its values, the next run's
+    position): the base, the bit-packed low bits, and the patch list that
+    restores the high bits of the few values that had any."""
+    n = len(buf)
+    if pos + 4 > n:
+        raise DeviceDecodeUnsupported("truncated PATCHED header")
+    b0 = buf[pos]
+    width = _decode_width((b0 >> 1) & 0x1F)
+    cnt = ((b0 & 1) << 8 | buf[pos + 1]) + 1
+    b2, b3 = buf[pos + 2], buf[pos + 3]
+    bw = ((b2 >> 5) & 7) + 1
+    pw = _decode_width(b2 & 0x1F)
+    pgw = ((b3 >> 5) & 7) + 1
+    pl = b3 & 0x1F
+    p = pos + 4
+    if p + bw > n:
+        raise DeviceDecodeUnsupported("truncated PATCHED base")
+    base = int.from_bytes(buf[p:p + bw], "big")
+    sign_mask = 1 << (bw * 8 - 1)
+    if base & sign_mask:
+        base = -(base & (sign_mask - 1))
+    p += bw
+    nbytes = (cnt * width + 7) // 8
+    vals = _unpack_be_host(buf[p:p + nbytes], cnt, width).astype(np.int64)
+    p += nbytes
+    # patch entries: (gap:pgw bits | patch:pw bits) bit-packed at
+    # the closest fixed width >= pgw+pw (the readers' contract)
+    ew = _closest_fixed_bits(pgw + pw)
+    nbytes = (pl * ew + 7) // 8
+    if p + nbytes > n:
+        raise DeviceDecodeUnsupported("truncated patch list")
+    entries = _unpack_be_host(buf[p:p + nbytes], pl, ew).view(np.uint64)
+    p += nbytes
+    idx = 0
+    pmask = (1 << pw) - 1
+    for e in entries:
+        gap = int(e) >> pw
+        patch = int(e) & pmask
+        idx += gap  # gaps accumulate; a (gap=255, patch=0)
+        if patch == 0:  # entry is a pure continuation marker
+            continue
+        if idx < cnt:
+            vals[idx] |= patch << width
+    return base + vals, p
+
 
 def _rlev2_runs(buf: bytes, num_values: int, signed: bool) -> _RunTable:
     """Scan an RLEv2 stream into a run table without expanding values.
     Big-endian bit-packed DIRECT payloads are carried packed (device
     unpacks); PATCHED_BASE and variable-delta runs host-decode into the
-    aux literal array (their byte walks are serial by construction)."""
+    aux literal array (their byte walks are serial by construction). The
+    walk over the run headers is native where `native/` is built
+    (`srtpu_orc_rlev2_scan`: a column of 387,064 runs took the Python loop
+    below 0.45 s a query), and leaves those few runs to the two functions
+    above either way."""
+    from ..native import runtime as native
+    try:
+        walked = native.orc_rlev2_scan(buf, num_values, signed)
+    except ValueError as e:
+        raise DeviceDecodeUnsupported(str(e)) from e
+    if walked is not None:
+        rt = _RunTable()
+        (rt.kinds, rt.counts, rt.base, rt.step, rt.offs, rt.width,
+         packed) = walked
+        rt.packed = packed
+        rt.total = int(rt.counts.sum())
+        for i in np.flatnonzero(rt.kinds >= 4):
+            at = int(rt.offs[i])
+            vals, _ = _delta_literal_run(buf, at, signed) \
+                if rt.kinds[i] == 4 else _patched_base_run(buf, at)
+            rt.kinds[i], rt.offs[i] = 3, rt.aux_len
+            rt.aux.append(vals.astype(np.int64))
+            rt.aux_len += len(vals)
+        return rt
     rt = _RunTable()
     pos, n = 0, len(buf)
     while rt.total < num_values and pos < n:
@@ -568,78 +756,18 @@ def _rlev2_runs(buf: bytes, num_values: int, signed: bool) -> _RunTable:
         elif enc == 3:  # DELTA
             if pos + 2 > n:
                 raise DeviceDecodeUnsupported("truncated DELTA header")
-            wcode = (b0 >> 1) & 0x1F
+            if (b0 >> 1) & 0x1F:  # bit-packed deltas
+                vals, pos = _delta_literal_run(buf, pos, signed)
+                rt.add_literal(vals)
+                continue
             cnt = ((b0 & 1) << 8 | buf[pos + 1]) + 1
             p = pos + 2
-            if signed:
-                base, p = _svarint(buf, p)
-            else:
-                base, p = _pb_varint(buf, p)
-            db, p = _svarint(buf, p)
-            if wcode == 0:  # fixed delta: v_i = base + i*db
-                rt.add(1, cnt, base=base, step=db)
-            elif cnt < 2:
-                raise DeviceDecodeUnsupported(
-                    "DELTA run shorter than 2 with literal deltas")
-            else:
-                width = _decode_width(wcode)
-                nbytes = ((cnt - 2) * width + 7) // 8
-                if p + nbytes > n:
-                    raise DeviceDecodeUnsupported("truncated DELTA run")
-                deltas = _unpack_be_host(buf[p:p + nbytes], cnt - 2,
-                                         width).astype(np.int64)
-                sign = 1 if db >= 0 else -1
-                vals = np.empty(cnt, np.int64)
-                vals[0] = base
-                vals[1] = base + db
-                np.cumsum(sign * deltas, out=deltas)
-                vals[2:] = base + db + deltas
-                rt.add_literal(vals)
-                p += nbytes
-            pos = p
+            base, p = _svarint(buf, p) if signed else _pb_varint(buf, p)
+            db, pos = _svarint(buf, p)
+            rt.add(1, cnt, base=base, step=db)  # v_i = base + i*db
         else:  # PATCHED_BASE
-            if pos + 4 > n:
-                raise DeviceDecodeUnsupported("truncated PATCHED header")
-            width = _decode_width((b0 >> 1) & 0x1F)
-            cnt = ((b0 & 1) << 8 | buf[pos + 1]) + 1
-            b2, b3 = buf[pos + 2], buf[pos + 3]
-            bw = ((b2 >> 5) & 7) + 1
-            pw = _decode_width(b2 & 0x1F)
-            pgw = ((b3 >> 5) & 7) + 1
-            pl = b3 & 0x1F
-            p = pos + 4
-            if p + bw > n:
-                raise DeviceDecodeUnsupported("truncated PATCHED base")
-            base = int.from_bytes(buf[p:p + bw], "big")
-            sign_mask = 1 << (bw * 8 - 1)
-            if base & sign_mask:
-                base = -(base & (sign_mask - 1))
-            p += bw
-            nbytes = (cnt * width + 7) // 8
-            vals = _unpack_be_host(buf[p:p + nbytes], cnt,
-                                   width).astype(np.int64)
-            p += nbytes
-            # patch entries: (gap:pgw bits | patch:pw bits) bit-packed at
-            # the closest fixed width >= pgw+pw (the readers' contract)
-            ew = _closest_fixed_bits(pgw + pw)
-            nbytes = (pl * ew + 7) // 8
-            if p + nbytes > n:
-                raise DeviceDecodeUnsupported("truncated patch list")
-            entries = _unpack_be_host(buf[p:p + nbytes], pl,
-                                      ew).view(np.uint64)
-            p += nbytes
-            idx = 0
-            pmask = (1 << pw) - 1
-            for e in entries:
-                gap = int(e) >> pw
-                patch = int(e) & pmask
-                idx += gap  # gaps accumulate; a (gap=255, patch=0)
-                if patch == 0:  # entry is a pure continuation marker
-                    continue
-                if idx < cnt:
-                    vals[idx] |= patch << width
-            rt.add_literal(base + vals)
-            pos = p
+            vals, pos = _patched_base_run(buf, pos)
+            rt.add_literal(vals)
     if rt.total < num_values:
         raise DeviceDecodeUnsupported("short RLEv2 stream")
     return rt
@@ -677,112 +805,189 @@ def _expand_runs_host(rt: _RunTable, num_values: int,
 
 
 # ----------------------------------------------------------------------------
-# Device kernels
+# Device kernels (traced: they run inside a column's `io.orc.*` program)
 # ----------------------------------------------------------------------------
 
-@functools.partial(__import__("jax").jit, static_argnums=(8, 9))
-def _expand_rlev2_device(kinds, counts, base, step, offs, width, packed,
-                         aux, cap: int, signed: bool):
-    """Run table -> i64[cap] values, entirely on device: searchsorted run
-    lookup; repeat/arith computed; DIRECT runs unpacked from the big-endian
-    bit stream with 8-byte gather windows; zigzag undone with vector ops."""
+def _slot_runs(ends, cap: int):
+    """run int32[cap]: for every output slot the run of the table that holds
+    it, from the runs' exclusive end slots. `parquet_device._run_of_slot`'s
+    way (one mark per run, one prefix sum, its barrier and for its reason),
+    from ends the host's walk has already summed: a run table of half a
+    million runs would otherwise pay a flat `cumsum` (PERF.md, fault 15).
+    Padding runs end where the last real one does and are stepped over."""
+    import jax.numpy as jnp
+    from jax import lax
+    assert 0 < cap < 2 ** 31, cap
+    marks = jnp.zeros(cap, jnp.int32).at[ends].add(
+        1, mode="drop", indices_are_sorted=True)
+    run = jnp.clip(_prefix_sum_i32(marks), 0, ends.shape[0] - 1)
+    return lax.optimization_barrier(run)
+
+
+# The v5e compiler gathers out of a table of under ~2 MB another way, with
+# 1 GB of temporaries for 2,097,152 indices, and that way costs by the row:
+# 37.9 ms for the date column's expansion, whose 7-row table has 8,192 runs,
+# against 18.0 with the table zero-padded to 524,288, and 37.8 against 22.0
+# for the 8 MB varint stream's two rows of 262,144; but two rows of 65,536 or
+# 131,072 are 2.5 ms cheaper left short (sandbox v5e compiler and my chip
+# runs, PERF.md, PR 37). So a short table is padded, on the device, to this
+# many columns before a long gather unless it is two rows of under a megabyte.
+_GATHER_MIN_COLS = 1 << 19
+_GATHER_SHORT_OK = (2, 1 << 17)      # at most (rows, columns)
+
+
+def _gather_rows(rows, idx):
+    """uint32[K, len(idx)]: K <= 8 uint32 rows of one length (a list of
+    them, or a (K, n) matrix) gathered along it by one index vector,
+    stacked so that the chip pays per index and not per row
+    (`ops/rowops.PACK_ROWS`; PERF.md price list)."""
+    import jax.numpy as jnp
+    m = jnp.stack(rows) if isinstance(rows, (list, tuple)) else rows
+    assert m.shape[0] <= PACK_ROWS, m.shape
+    k, n = m.shape
+    short = min(_GATHER_MIN_COLS, idx.shape[0]) - n
+    if short > 0 and not (k <= _GATHER_SHORT_OK[0]
+                          and n <= _GATHER_SHORT_OK[1]):
+        m = jnp.pad(m, ((0, 0), (0, short)))
+    return m[:, jnp.clip(idx, 0, n - 1)]
+
+
+def _ahead(words, k: int):
+    """`words` read `k` places ahead (zeros past the end)."""
+    import jax.numpy as jnp
+    return jnp.concatenate([words[k:], jnp.zeros(k, words.dtype)])
+
+
+def _u64(lo, hi):
+    import jax.numpy as jnp
+    return lo.astype(jnp.uint64) | (hi.astype(jnp.uint64) << jnp.uint64(32))
+
+
+def _unzigzag(u):
     import jax
     import jax.numpy as jnp
-    ends = jnp.cumsum(counts)
-    j = jnp.arange(cap, dtype=jnp.int64)
-    run = jnp.clip(jnp.searchsorted(ends, j, side="right"),
-                   0, counts.shape[0] - 1)
-    within = j - (ends[run] - counts[run])
-    # repeat (step==0) and arithmetic runs
-    va = base[run] + within * step[run]
-    # literal runs
-    vl = aux[jnp.clip(offs[run] + within, 0, aux.shape[0] - 1)]
-    # packed runs: big-endian window gather. ORC widths are 1..30 bits or
-    # byte multiples (32/40/48/56/64); sh<=7 and W<=56 fit an 8-byte
-    # window, W=64 runs are byte-aligned (sh=0) so the window is exact.
-    W = width[run].astype(jnp.uint64)
-    bitpos = offs[run] + within * width[run].astype(jnp.int64)
-    b0 = bitpos // 8
-    window = jnp.zeros(cap, jnp.uint64)
-    for k in range(8):
-        byte = packed[jnp.clip(b0 + k, 0, packed.shape[0] - 1)]
-        window = window | (byte.astype(jnp.uint64)
-                           << jnp.uint64(8 * (7 - k)))
-    sh = (bitpos % 8).astype(jnp.uint64)
-    shift = jnp.uint64(64) - sh - W
-    shift = jnp.where(W >= 64, jnp.uint64(0), shift)
-    pv = window >> shift
-    mask = jnp.where(W >= 64, ~jnp.uint64(0),
-                     (jnp.uint64(1) << jnp.minimum(W, jnp.uint64(63)))
-                     - jnp.uint64(1))
-    pv = pv & mask
+    return jax.lax.bitcast_convert_type(
+        (u >> jnp.uint64(1)) ^ (jnp.uint64(0) - (u & jnp.uint64(1))),
+        jnp.int64)
+
+
+def _expand_rlev2(ends, table, words, cap: int, signed: bool, wide: bool):
+    """RLEv2 run table (`_RunTable.device_arrays`) -> int64[cap] values.
+    Per slot: its run by `_slot_runs`, the run's seven words by one stacked
+    gather, then either `base + within * step` (SHORT_REPEAT, fixed DELTA) or
+    the run's `width` bits at `offs + within * width` of the big-endian
+    packed stream (DIRECT; PATCHED_BASE and literal DELTA runs, which the
+    host decoded, packed again the same way), read out of two 32-bit words
+    a slot, three where a width passes 32 (`wide`), by one more stacked
+    gather. Slots past the table's total read a padding run: the caller
+    masks them."""
+    import jax
+    import jax.numpy as jnp
+    u32, u64 = jnp.uint32, jnp.uint64
+    run = _slot_runs(ends, cap)
+    start, blo, bhi, slo, shi, offs, wk = _gather_rows(table, run)
+    within = jnp.arange(cap, dtype=jnp.int32) - start.astype(jnp.int32)
+    arith = _u64(blo, bhi) + within.astype(u64) * _u64(slo, shi)
+    width = wk & u32(0xFF)
+    bitpos = offs + within.astype(u32) * width
+    q, r = bitpos >> u32(5), (bitpos & u32(31)).astype(u64)
+    w = width.astype(u64)
+    rows = [words, _ahead(words, 1)] + ([_ahead(words, 2)] if wide else [])
+    g = _gather_rows(rows, q)
+    hi = _u64(g[1], g[0])
+    if wide:
+        top = (hi << r) | (g[2].astype(u64) >> (u64(32) - r))
+        pv = top >> jnp.minimum(u64(64) - w, u64(63))
+    else:   # r <= 31 and w <= 32: the bits lie inside the two words
+        w = jnp.minimum(w, u64(32))
+        pv = (hi >> (u64(64) - r - w)) & ((u64(1) << w) - u64(1))
     if signed:
-        pv = (pv >> jnp.uint64(1)) ^ (jnp.uint64(0) -
-                                      (pv & jnp.uint64(1)))
-    pvs = jax.lax.bitcast_convert_type(pv, jnp.int64)
-    v = jnp.where(kinds[run] == 2, pvs,
-                  jnp.where(kinds[run] == 3, vl, va))
-    return jnp.where(j < ends[-1], v, 0)
+        pv = (pv >> u64(1)) ^ (u64(0) - (pv & u64(1)))
+    return jax.lax.bitcast_convert_type(
+        jnp.where((wk >> u32(8)) != 0, pv, arith), jnp.int64)
 
 
-@functools.partial(__import__("jax").jit, static_argnums=(5,))
-def _expand_present_device(kinds, counts, values, offs, blob, cap: int):
-    """Byte-RLE run table -> bool[cap] present mask on device. Row j reads
-    bit 7-(j%8) of stream byte j//8, msb-first per the ORC spec."""
-    import jax.numpy as jnp
-    ends = jnp.cumsum(counts)  # ends in BYTES
-    j = jnp.arange(cap, dtype=jnp.int64)
-    bi = j // 8
-    run = jnp.clip(jnp.searchsorted(ends, bi, side="right"),
-                   0, counts.shape[0] - 1)
-    within = bi - (ends[run] - counts[run])
-    byte = jnp.where(kinds[run] == 0, values[run],
-                     blob[jnp.clip(offs[run] + within, 0,
-                                   blob.shape[0] - 1)])
-    bit = (byte >> (7 - (j % 8)).astype(jnp.uint8)) & 1
-    return (bit == 1) & (bi < ends[-1])
-
-
-@functools.partial(__import__("jax").jit, static_argnums=(5,))
-def _expand_bytes_device(kinds, counts, values, offs, blob, cap: int):
-    """Byte-RLE run table -> u8[cap] values on device (BYTE columns)."""
-    import jax.numpy as jnp
-    ends = jnp.cumsum(counts)
-    j = jnp.arange(cap, dtype=jnp.int64)
-    run = jnp.clip(jnp.searchsorted(ends, j, side="right"),
-                   0, counts.shape[0] - 1)
-    within = j - (ends[run] - counts[run])
-    byte = jnp.where(kinds[run] == 0, values[run],
-                     blob[jnp.clip(offs[run] + within, 0,
-                                   blob.shape[0] - 1)])
-    return jnp.where(j < ends[-1], byte, 0)
-
-
-@functools.partial(__import__("jax").jit, static_argnums=(1,))
-def _varint_zigzag_device(stream, cap: int):
-    """Signed-varint (zigzag base-128) value stream -> i64[cap] values on
-    device — the ORC DECIMAL mantissa encoding. Each byte's 7 payload bits
-    shift into place by its within-value position and a segment-sum folds
-    them per value; value boundaries come from the continuation bits.
-    Values wider than 64 bits never reach here (columns_supported keeps
-    precision > 18 on the host path)."""
+def _select_bit(mask, k):
+    """Position of the k-th (0-based) set bit of each uint32 `mask`: five
+    halvings by popcount."""
     import jax
     import jax.numpy as jnp
-    b = stream.astype(jnp.uint64)
-    term = stream < 128  # last byte of its value
-    n = stream.shape[0]
-    i = jnp.arange(n, dtype=jnp.int64)
-    # value id of each byte: exclusive cumsum of terminators
-    vid = jnp.cumsum(term.astype(jnp.int64)) - term.astype(jnp.int64)
-    # within-value position: distance from the value's first byte
-    is_start = jnp.concatenate([jnp.ones(1, bool), term[:-1]])
-    seg_start = jax.lax.cummax(jnp.where(is_start, i, -1))
-    within = (i - seg_start).astype(jnp.uint64)
-    contrib = (b & jnp.uint64(0x7F)) << (jnp.uint64(7) *
-                                         jnp.minimum(within, jnp.uint64(9)))
-    u = jax.ops.segment_sum(contrib, vid, num_segments=cap)
-    return ((u >> jnp.uint64(1)) ^
-            (jnp.uint64(0) - (u & jnp.uint64(1)))).astype(jnp.int64)
+    u32 = jnp.uint32
+    pos = jnp.zeros(mask.shape, u32)
+    k = k.astype(u32)
+    for half in (16, 8, 4, 2, 1):
+        low = (mask >> pos) & u32((1 << half) - 1)
+        c = jax.lax.population_count(low)
+        up = k >= c
+        k = jnp.where(up, k - c, k)
+        pos = jnp.where(up, pos + u32(half), pos)
+    return pos
+
+
+def _varint_zigzag(words, cap: int):
+    """Signed-varint (zigzag base-128) value stream -> int64[cap], the ORC
+    DECIMAL mantissa encoding, from the value ENDS: a byte under 128 ends a
+    value. `words` is the stream as little-endian uint32 words, zero-padded
+    to a bucket of whole 32-byte blocks (a padding byte is a value 0 past
+    the live ones). Per block the 32 terminator bits and their count, the
+    counts' prefix sum, `_slot_runs` for the block that ends value v, one
+    stacked gather for that block's bits and first value, `_select_bit` for
+    the byte; a value starts after the one before it. Its up to nine bytes
+    (18 digits zigzag into 61 bits) come out of three words by one more
+    stacked gather, and the 7-bit groups fold with shifts: no scatter-add,
+    no flat `cumsum`, no scan over the bytes (PERF.md, faults 9 and 15).
+    Values past 64 bits never reach here (`columns_supported`, and the
+    host's check of the value lengths)."""
+    import jax
+    import jax.numpy as jnp
+    u32, u64 = jnp.uint32, jnp.uint64
+    w8 = words.reshape(-1, 8)
+    t = ~w8 & u32(0x80808080)
+    nib = ((t >> u32(7)) & u32(1)) | ((t >> u32(14)) & u32(2)) | \
+        ((t >> u32(21)) & u32(4)) | ((t >> u32(28)) & u32(8))
+    mask = jnp.sum(nib << (u32(4) * jnp.arange(8, dtype=u32))[None, :],
+                   axis=1, dtype=u32)
+    counts = jax.lax.population_count(mask).astype(jnp.int32)
+    ends = _prefix_sum_i32(counts)
+    blk = _slot_runs(ends, cap)
+    m, first = _gather_rows([mask, (ends - counts).astype(u32)], blk)
+    v = jnp.arange(cap, dtype=jnp.int32)
+    end = blk * 32 + _select_bit(m, v - first.astype(jnp.int32)).astype(
+        jnp.int32)
+    start = jnp.concatenate([jnp.zeros(1, jnp.int32), end[:-1] + 1])
+    n = (end - start + 1).astype(u64)
+    r8 = (start & 3).astype(u64) * u64(8)
+    w0, w1, w2 = _gather_rows([words, _ahead(words, 1), _ahead(words, 2)],
+                              start >> 2)
+    # shift amounts stay under 64 in every lane, the unused ones too
+    x = (_u64(w0, w1) >> r8) | jnp.where(
+        r8 > 0, w2.astype(u64) << ((u64(64) - r8) & u64(63)), u64(0))
+    ninth = (w2.astype(u64) >> r8) & u64(0x7F)
+    x = jnp.where(n >= 8, x, x & (
+        (u64(1) << (jnp.minimum(n, u64(7)) * u64(8))) - u64(1)))
+    u = jnp.where(n >= 9, ninth << u64(56), u64(0))
+    for k in range(8):
+        u = u | (((x >> u64(8 * k)) & u64(0x7F)) << u64(7 * k))
+    return _unzigzag(u)
+
+
+def _expand_byte_rle(ends, table, blob, cap: int):
+    """Byte-RLE run table (`_byte_rle_device`) -> uint8[cap] bytes."""
+    import jax.numpy as jnp
+    run = _slot_runs(ends, cap)
+    start, vk, offs = _gather_rows(table, run)
+    within = jnp.arange(cap, dtype=jnp.int32) - start.astype(jnp.int32)
+    lit = blob[jnp.clip(offs.astype(jnp.int32) + within, 0,
+                        blob.shape[0] - 1)]
+    return jnp.where((vk >> jnp.uint32(8)) != 0, lit,
+                     (vk & jnp.uint32(0xFF)).astype(jnp.uint8))
+
+
+def _bits_msb_first(byts):
+    """uint8[n] -> bool[8 n]: bit 7 of a byte first, per the ORC spec."""
+    import jax.numpy as jnp
+    shifts = jnp.arange(7, -1, -1, dtype=jnp.uint8)
+    return (((byts[:, None] >> shifts[None, :]) & 1) == 1).reshape(-1)
 
 
 # nanos trailing-zero expansion table: encoded low 3 bits z -> 10^(z+1)
@@ -791,7 +996,6 @@ _NANO_MULT = np.array([1, 100, 1000, 10_000, 100_000, 1_000_000,
                        10_000_000, 100_000_000], np.int64)
 
 
-@__import__("jax").jit
 def _orc_timestamp_micros(secs, nanos_enc):
     """ORC timestamp streams -> Spark micros since the unix epoch.
     secs counts from 2015-01-01; nanos carry their trailing-zero count in
@@ -805,8 +1009,128 @@ def _orc_timestamp_micros(secs, nanos_enc):
     return (secs + _ORC_TS_BASE) * 1_000_000 + nanos // 1000
 
 
+# A dictionary of a handful of entries (TPC-H's flags: 3 and 2) is gathered
+# byte by byte out of its blob in 1.2 ms for 2,097,152 rows of 8 bytes, the
+# compiler's own way with a table that small; by rows it takes 9.5 (my chip
+# run, PERF.md, PR 37). Between this and the thousands of entries at which
+# the byte gather runs at 0.1 GB/s (fault 16) nothing is measured.
+_TINY_DICTIONARY = 64
+
+
+def _dictionary_rows(blob, dstarts, dlens, idx, valid, width: int):
+    """Dictionary-encoded strings -> (uint8[cap, width], int32[cap]): the
+    dictionary's own byte matrix is built once (`_string_matrix_tail` over
+    its few rows), then every row takes its entry's bytes and length by ONE
+    stacked gather of big-endian words (a row of at most 28 bytes and its
+    length are the eight rows a gather moves at one price); a wider entry
+    is gathered whole and its length beside it. A tiny dictionary's spans
+    are gathered straight out of the blob (`_TINY_DICTIONARY`)."""
+    import jax.numpy as jnp
+    u32 = jnp.uint32
+    dcap = dstarts.shape[0]
+    if dcap <= _TINY_DICTIONARY:
+        safe = jnp.clip(idx, 0, dcap - 1)
+        return _string_matrix_tail(blob, dstarts[safe], dlens[safe], valid,
+                                   width)
+    dmat, dln = _string_matrix_tail(blob, dstarts, dlens,
+                                    jnp.ones(dcap, bool), width)
+    nw = width // 4
+    if nw + 1 <= PACK_ROWS:
+        b = dmat.reshape(dcap, nw, 4).astype(u32)
+        ws = (b[:, :, 0] << u32(24)) | (b[:, :, 1] << u32(16)) | \
+            (b[:, :, 2] << u32(8)) | b[:, :, 3]
+        g = _gather_rows([ws[:, i] for i in range(nw)] + [dln.astype(u32)],
+                         idx)
+        shifts = jnp.array([24, 16, 8, 0], dtype=u32)
+        mat = ((jnp.stack(list(g[:nw]), axis=1)[:, :, None] >> shifts)
+               & u32(0xFF)).astype(jnp.uint8).reshape(-1, width)
+        ln = g[nw].astype(jnp.int32)
+    else:
+        safe = jnp.clip(idx, 0, dcap - 1)
+        mat, ln = dmat[safe], dln[safe]
+    return jnp.where(valid[:, None], mat, 0).astype(jnp.uint8), \
+        jnp.where(valid, ln, 0)
+
+
+def _traced_column(sig, cap: int, nrows, it):
+    """Decode ONE column (traced) from its ship-order array iterator ->
+    (data, validity, lengths or None). `sig` is the column's static
+    signature `_ColPlan.sig`: (kind, has PRESENT, ...). Values decode dense
+    (the non-null ones in order) and reach their rows by the null rank, a
+    blocked prefix sum of the PRESENT bits; a column without a PRESENT
+    stream, as every column of a NOT NULL table, skips that gather."""
+    import jax.numpy as jnp
+    kind, has_present = sig[0], sig[1]
+    live = jnp.arange(cap, dtype=jnp.int32) < nrows
+    if has_present:
+        ends, table, blob = next(it), next(it), next(it)
+        defined = _bits_msb_first(
+            _expand_byte_rle(ends, table, blob, cap // 8)) & live
+        rank = jnp.clip(_prefix_sum_i32(defined.astype(jnp.int32)) - 1,
+                        0, cap - 1)
+    else:
+        defined, rank = live, None
+
+    def to_rows(dense):
+        return dense if rank is None else dense[rank]
+
+    def fixed(dense, dtype):
+        data = jnp.where(defined, to_rows(dense), jnp.zeros((), dense.dtype))
+        return data.astype(dtype), defined, None
+
+    def rlev2(signed: bool, wide: bool):
+        return _expand_rlev2(next(it), next(it), next(it), cap, signed, wide)
+
+    if kind == "int":
+        wide, dtype = sig[2:]
+        return fixed(rlev2(True, wide), np.dtype(dtype))
+    if kind == "decimal":
+        return fixed(_varint_zigzag(next(it), cap), np.int64)
+    if kind == "timestamp":
+        wide_secs, wide_nanos = sig[2:]
+        secs = rlev2(True, wide_secs)
+        return fixed(_orc_timestamp_micros(secs, rlev2(False, wide_nanos)),
+                     np.int64)
+    if kind == "float":
+        return fixed(next(it), np.dtype(sig[2]))
+    if kind == "bool":
+        return fixed(_bits_msb_first(_expand_byte_rle(
+            next(it), next(it), next(it), cap // 8)), np.bool_)
+    if kind == "byte":
+        return fixed(_expand_byte_rle(next(it), next(it), next(it), cap),
+                     np.int8)
+    if kind == "string_dict":
+        wide, width, dcount = sig[2:]
+        idx = jnp.clip(rlev2(False, wide), 0, max(dcount - 1, 0))
+        dstarts, dlens, blob = next(it), next(it), next(it)
+        mat, ln = _dictionary_rows(blob, dstarts, dlens,
+                                   to_rows(idx).astype(jnp.int32), defined,
+                                   width)
+        return mat, defined, ln
+    if kind == "string_direct":
+        starts, lens, blob = next(it), next(it), next(it)
+        mat, ln = _string_matrix_tail(blob, to_rows(starts), to_rows(lens),
+                                      defined, sig[2])
+        return mat, defined, ln
+    raise AssertionError(sig)
+
+
+@functools.lru_cache(maxsize=256)
+def _column_program(sig, cap: int):
+    """The service program of one column kind: `io.orc.<kind>`, keyed by
+    the column's static signature and the batch capacity; the tables'
+    bucketed shapes ride the service's own digest of the arguments. Takes
+    the (traced) row count and the column's arrays in ship order."""
+
+    def fn(nrows, *arrays):
+        return _traced_column(sig, cap, nrows, iter(arrays))
+
+    from ..compile import sjit
+    return sjit(fn, op=f"io.orc.{sig[0]}", key=repr((sig, cap)))
+
+
 # ----------------------------------------------------------------------------
-# Stripe decode
+# Stripe decode: the host's half (read, deframe, run walk, buckets)
 # ----------------------------------------------------------------------------
 
 @dataclass
@@ -814,27 +1138,35 @@ class _ColStreams:
     encoding: int = _E_DIRECT
     dict_size: int = 0
     streams: Dict[int, bytes] = field(default_factory=dict)
+    # a DECIMAL's DATA stream as the file frames it, (bytes, codec, block
+    # size): `_decimal_stream` deframes it straight into its bucket
+    framed: Optional[Tuple[bytes, int, int]] = None
 
 
-def _stripe_writer_tz(info: OrcFileInfo, f, st: _Stripe) -> str:
-    """Read ONLY a stripe's footer and return its writerTimezone."""
-    f.seek(st.offset + st.index_len + st.data_len)
-    sf_raw = _deframe(f.read(st.footer_len), info.compression,
-                      info.block_size)
-    for fno, _, v in _pb_fields(sf_raw):
-        if fno == 3:
-            return v.decode("utf-8", "replace")
-    return ""
+@dataclass
+class _ColPlan:
+    """One column's host-phase product: its program's static signature and
+    the numpy arrays to ship, in the order `_traced_column` reads them."""
+    sig: tuple
+    arrays: List[np.ndarray]
 
 
-def _read_stripe_streams(info: OrcFileInfo, f, st: _Stripe,
-                         want_cols):
-    """Read + deframe the stripe footer and the wanted columns' streams.
-    Returns ({col id: _ColStreams}, writer timezone string)."""
-    f.seek(st.offset + st.index_len + st.data_len)
-    sf_raw = _deframe(f.read(st.footer_len), info.compression,
-                      info.block_size)
-    streams: List[Tuple[int, int, int]] = []  # (kind, col, length)
+class _ScanStats:
+    """What one stripe's host phase walked: RLEv2 and byte-RLE runs, and the
+    bytes of varint streams (the scan's operator metrics)."""
+    __slots__ = ("runs", "varint_bytes")
+
+    def __init__(self):
+        self.runs = self.varint_bytes = 0
+
+
+def _stripe_footer(info: OrcFileInfo, f, st: _Stripe):
+    """(streams [(kind, column, length)], encodings [(kind, dictionary
+    size)], writer timezone) of one stripe, from its footer alone."""
+    sf_raw = _deframe(_read_at(f, st.offset + st.index_len + st.data_len,
+                               st.footer_len),
+                      info.compression, info.block_size)
+    streams: List[Tuple[int, int, int]] = []
     encodings: List[Tuple[int, int]] = []
     writer_tz = ""
     for fno, _, v in _pb_fields(sf_raw):
@@ -850,76 +1182,119 @@ def _read_stripe_streams(info: OrcFileInfo, f, st: _Stripe,
             encodings.append((e[1], e[2]))
         elif fno == 3:
             writer_tz = v.decode("utf-8", "replace")
-    cols: Dict[int, _ColStreams] = {}
-    for cid in want_cols:
-        cs = _ColStreams()
-        if cid < len(encodings):
-            cs.encoding, cs.dict_size = encodings[cid]
-        cols[cid] = cs
+    return streams, encodings, writer_tz
+
+
+def _stripe_writer_tz(info: OrcFileInfo, f, st: _Stripe) -> str:
+    """Read ONLY a stripe's footer and return its writerTimezone."""
+    return _stripe_footer(info, f, st)[2]
+
+
+_READ_LOCK = threading.Lock()
+
+
+def _read_at(f, pos: int, length: int) -> bytes:
+    """`length` bytes of `f` from `pos`, whichever thread asks: `pread`
+    moves no file position; a file object without a descriptor is read
+    under a lock."""
+    try:
+        return os.pread(f.fileno(), length, pos)
+    except (AttributeError, OSError):    # io.UnsupportedOperation is one
+        with _READ_LOCK:
+            f.seek(pos)
+            return f.read(length)
+
+
+def _column_streams(info: OrcFileInfo, f, st: _Stripe, directory,
+                    cid: int) -> _ColStreams:
+    """Read + deframe ONE column's data-area streams (a DECIMAL's DATA
+    stream is only read: `_ColStreams.framed`)."""
+    streams, encodings, _ = directory
+    cs = _ColStreams()
+    if cid < len(encodings):
+        cs.encoding, cs.dict_size = encodings[cid]
+    decimal = info.col_kinds.get(cid) == _K_DECIMAL
     pos = st.offset
     for kind, col, length in streams:
-        if col in cols and kind in (_S_PRESENT, _S_DATA, _S_LENGTH,
-                                    _S_DICT_DATA, _S_SECONDARY) \
+        if col == cid and kind in (_S_PRESENT, _S_DATA, _S_LENGTH,
+                                   _S_DICT_DATA, _S_SECONDARY) \
                 and pos >= st.offset + st.index_len:
-            f.seek(pos)
-            cols[col].streams[kind] = _deframe(
-                f.read(length), info.compression, info.block_size)
+            raw = _read_at(f, pos, length)
+            if decimal and kind == _S_DATA:
+                cs.framed = (raw, info.compression, info.block_size)
+            else:
+                cs.streams[kind] = _deframe(raw, info.compression,
+                                            info.block_size)
         pos += length
-    return cols, writer_tz
+    return cs
 
 
-def _defined_and_count(cs: _ColStreams, nrows: int, cap: int):
-    """(device bool[cap] mask, non-null count) from the PRESENT stream."""
-    import jax.numpy as jnp
+def _deframe_padded(buf: bytes, comp: int, block_size: int):
+    """(a stream deframed into ONE zero-tailed uint8 array of a power-of-two
+    bucket of at least 128 bytes, its live length): natively for the
+    self-describing codecs, so that an 8 MB stream is written once where it
+    ships from; `_deframe` and a padding copy otherwise."""
+    from ..native import runtime as native
+    kind = {_COMP_NONE: 0, _COMP_SNAPPY: 2}.get(comp, -1)
+    try:
+        done = native.orc_deframe(buf, kind, lambda n: _bucket(n, 128))
+    except ValueError as e:
+        raise DeviceDecodeUnsupported(str(e)) from e
+    if done is not None:
+        return done
+    raw = np.frombuffer(_deframe(buf, comp, block_size), np.uint8)
+    return _padded(raw, _bucket(raw.size, 128)), raw.size
+
+
+def _bucket(n: int, least: int = 1) -> int:
+    return _pow2(max(n, least))
+
+
+def _padded(a: np.ndarray, n: int, fill=0) -> np.ndarray:
+    if a.shape[-1] >= n:
+        return a
+    pad = [(0, 0)] * (a.ndim - 1) + [(0, n - a.shape[-1])]
+    return np.pad(a, pad, constant_values=fill)
+
+
+def _be_words(parts, total: int, least: int = 0) -> np.ndarray:
+    """A big-endian bit stream, given as uint8 arrays of `total` bytes
+    together, as uint32 words zero-padded to a bucket of at least `least`
+    words: written once into the buffer that ships and swapped there."""
+    buf = np.zeros(_bucket(total, max(4 * least, 16)), np.uint8)
+    at = 0
+    for part in parts:
+        buf[at:at + part.size] = part
+        at += part.size
+    words = buf.view(np.uint32)
+    if sys.byteorder == "little":
+        words.byteswap(inplace=True)
+    return words
+
+
+def _byte_rle_device(runs, total: int) -> List[np.ndarray]:
+    """`_byte_rle_runs`' table in the shapes `_expand_byte_rle` reads:
+    (ends int32[R], uint32[3, R] of start, value | literal << 8, offset;
+    the literal bytes), R and the bytes in power-of-two buckets."""
+    kinds, counts, values, offs, blob = runs
+    ends = np.cumsum(counts)
+    rb = _bucket(len(kinds))
+    table = np.stack([ends - counts,
+                      values.astype(np.int64) | (kinds.astype(np.int64) << 8),
+                      offs]).astype(np.uint32)
+    return [_padded(ends.astype(np.int32), rb, fill=total),
+            _padded(table, rb), _padded(blob, _bucket(blob.size))]
+
+
+def _present_arrays(cs: _ColStreams, nrows: int, stats: _ScanStats):
+    """(arrays of the PRESENT stream's table or [], non-null count)."""
     present = cs.streams.get(_S_PRESENT)
     if present is None:
-        return jnp.arange(cap) < nrows, nrows
-    runs = _byte_rle_runs(present, (nrows + 7) // 8)
-    ndef = _present_ndef(runs, nrows)
-    defined = _expand_present_device(
-        jnp.asarray(runs[0]), jnp.asarray(runs[1]), jnp.asarray(runs[2]),
-        jnp.asarray(runs[3]), jnp.asarray(runs[4]), cap)
-    defined = defined & (jnp.arange(cap) < nrows)
-    return defined, ndef
-
-
-def _rlev2_device_from_buf(buf: bytes, count: int, signed: bool):
-    """Scan an RLEv2 stream (host) and expand it on device -> i64."""
-    import jax.numpy as jnp
-    if count == 0:  # all-null column: no runs to expand
-        return jnp.zeros(1, jnp.int64)
-    rt = _rlev2_runs(buf, count, signed)
-    arrs = [jnp.asarray(a) for a in rt.arrays()]
-    return _expand_rlev2_device(*arrs, row_bucket(count), signed)[:count]
-
-
-def _int_values_device(cs: _ColStreams, ndef: int, signed: bool):
-    if cs.encoding != _E_DIRECT_V2:
-        raise DeviceDecodeUnsupported(f"integer encoding {cs.encoding}")
-    data = cs.streams.get(_S_DATA)
-    if data is None:
-        raise DeviceDecodeUnsupported("missing DATA stream")
-    return _rlev2_device_from_buf(data, ndef, signed)
-
-
-def _byte_runs_device(runs, cap: int, as_bits: bool):
-    import jax.numpy as jnp
-    arrs = [jnp.asarray(a) for a in runs]
-    fn = _expand_present_device if as_bits else _expand_bytes_device
-    return fn(*arrs, cap)
-
-
-def _fixed_column(vals, dt, defined, cap: int, out_dtype=None):
-    """Shared tail for every fixed-width branch: pad the dense non-null
-    value vector to cap, scatter to row slots by null rank, wrap."""
-    import jax.numpy as jnp
-    from ..columnar.column import Column
-    if vals.shape[0] < cap:
-        vals = jnp.pad(vals, (0, cap - vals.shape[0]))
-    data, validity = _scatter_values(vals[:cap], defined)
-    if out_dtype is not None and data.dtype != out_dtype:
-        data = data.astype(out_dtype)
-    return Column(dt, data, validity)
+        return [], nrows
+    nbytes = (nrows + 7) // 8
+    runs = _byte_rle_runs(present, nbytes)
+    stats.runs += len(runs[0])
+    return _byte_rle_device(runs, nbytes), _present_ndef(runs, nrows)
 
 
 def _require_data(cs: _ColStreams) -> bytes:
@@ -929,9 +1304,211 @@ def _require_data(cs: _ColStreams) -> bytes:
     return raw
 
 
+def _rlev2_arrays(buf: bytes, count: int, signed: bool, cap: int,
+                  stats: _ScanStats):
+    """(arrays of an RLEv2 stream's run table, whether a width passes 32)."""
+    rt = _rlev2_runs(buf, count, signed) if count else _RunTable()
+    stats.runs += len(rt.kinds)
+    ends, table, words, wide = rt.device_arrays(signed, cap)
+    return [ends, table, words], wide
+
+
+def _decimal_stream(cs: _ColStreams, dt, ndef: int,
+                    stats: _ScanStats) -> np.ndarray:
+    """A DECIMAL column's mantissa stream (precision <= 18) as the
+    little-endian words `_varint_zigzag` reads, checked on the way: the
+    SECONDARY per-value scale stream must equal the declared scale (writers
+    emit constant runs, read from the run table without expanding it) or
+    the stripe host-falls-back rather than rescale; the stream must hold
+    `ndef` whole values of at most nine bytes."""
+    if cs.framed is None and _S_DATA not in cs.streams:
+        raise DeviceDecodeUnsupported("missing DATA stream")
+    if cs.encoding != _E_DIRECT_V2:
+        # DIRECT (Hive 0.11-era) pairs the mantissas with an RLEv1 scale
+        # stream this parser would misread — like the integer path, only
+        # the v2 encoding decodes here
+        raise DeviceDecodeUnsupported(f"decimal encoding {cs.encoding}")
+    scale_raw = cs.streams.get(_S_SECONDARY)
+    if scale_raw is None:
+        raise DeviceDecodeUnsupported("missing decimal scale stream")
+    if ndef:
+        rt = _rlev2_runs(scale_raw, ndef, True)
+        stats.runs += len(rt.kinds)
+        kinds = np.asarray(rt.kinds)
+        if ((kinds <= 1) & (np.asarray(rt.step) == 0)).all():
+            same = (np.asarray(rt.base) == dt.scale).all()
+        else:
+            same = (_expand_runs_host(rt, ndef, True) == dt.scale).all()
+        if not same:
+            raise DeviceDecodeUnsupported("per-value decimal rescale")
+    if cs.framed is not None:
+        padded, n = _deframe_padded(*cs.framed)
+    else:
+        raw = np.frombuffer(cs.streams[_S_DATA], np.uint8)
+        padded, n = _padded(raw, _bucket(raw.size, 128)), raw.size
+    stats.varint_bytes += n
+    from ..native import runtime as native
+    scan = native.varint_scan(padded[:n], ndef)
+    if scan is None:
+        last = np.flatnonzero(padded[:n] < 128)
+        scan = (last.size,
+                int(np.diff(last[:ndef], prepend=-1).max()) if ndef else 0,
+                bool(n and padded[n - 1] >= 128))
+    ends, longest, cut = scan
+    if cut:
+        raise DeviceDecodeUnsupported("decimal stream ends inside a value")
+    if ends < ndef:
+        raise DeviceDecodeUnsupported("short decimal mantissa stream")
+    # a <=18-digit mantissa zigzags into <=63 bits -> <=9 varint bytes
+    if longest > 9:
+        raise DeviceDecodeUnsupported("mantissa varint wider than 64")
+    return padded.view("<u4")
+
+
+def _offsets(lens: np.ndarray) -> np.ndarray:
+    starts = np.zeros(len(lens), np.int64)
+    if len(lens):
+        np.cumsum(lens[:-1], out=starts[1:])
+    return starts
+
+
+def _blob(raw: bytes) -> np.ndarray:
+    buf = np.frombuffer(raw, np.uint8) if raw else np.zeros(1, np.uint8)
+    return _padded(buf, _bucket(buf.size, 16))
+
+
+def _string_plan(cs: _ColStreams, ndef: int, cap: int, stats: _ScanStats):
+    """STRING column. DIRECT_V2: the LENGTH stream expands on the host
+    (offsets are a serial sum), the device gathers spans from the DATA blob.
+    DICTIONARY_V2: the index runs expand on the device, the dictionary's
+    few offsets on the host, and every row takes its entry's bytes from the
+    dictionary's own matrix (`_dictionary_rows`). Returns (the signature
+    past its first two fields, the arrays)."""
+    from ..columnar.padding import width_bucket
+    from ..config import get_default_conf
+    lens_raw = cs.streams.get(_S_LENGTH)
+    if cs.encoding == _E_DIRECT_V2:
+        if lens_raw is None:
+            raise DeviceDecodeUnsupported("missing LENGTH stream")
+        rt = _rlev2_runs(lens_raw, ndef, False)
+        stats.runs += len(rt.kinds)
+        lens = _expand_runs_host(rt, ndef, False)
+        max_len = int(lens.max()) if ndef else 0
+        width = width_bucket(max(max_len, 1))
+        arrays = [_padded(_offsets(lens), cap),
+                  _padded(lens.astype(np.int32), cap),
+                  _blob(cs.streams.get(_S_DATA, b""))]
+        sig = ("string_direct", width)
+    elif cs.encoding == _E_DICT_V2:
+        data = cs.streams.get(_S_DATA)
+        if lens_raw is None or data is None:
+            raise DeviceDecodeUnsupported("missing dictionary streams")
+        dcount = cs.dict_size
+        dlens = _expand_runs_host(_rlev2_runs(lens_raw, dcount, False),
+                                  dcount, False)
+        max_len = int(dlens.max()) if dcount else 0
+        width = width_bucket(max(max_len, 1))
+        idx_arrays, wide = _rlev2_arrays(data, ndef, False, cap, stats)
+        db = _bucket(dcount, 8)
+        arrays = idx_arrays + [_padded(_offsets(dlens), db),
+                               _padded(dlens.astype(np.int32), db),
+                               _blob(cs.streams.get(_S_DICT_DATA, b""))]
+        # the live dictionary size rides the signature only as its bucket
+        sig = ("string_dict", wide, width, db)
+    else:
+        raise DeviceDecodeUnsupported(f"string encoding {cs.encoding}")
+    if width > get_default_conf().string_max_width:
+        raise DeviceDecodeUnsupported(
+            f"string width {max_len} exceeds device layout limit")
+    return sig, arrays
+
+
+def _column_plan(cs: _ColStreams, kind: int, dt, nrows: int, cap: int,
+                 writer_tz: str, stats: _ScanStats) -> _ColPlan:
+    """The host phase of one column: walk its streams into run tables,
+    pad every table, byte stream and literal array to a power-of-two bucket
+    (a file with 387,065 runs runs the program of one with 387,064), and
+    name the program by the column's kind and static flags."""
+    present, ndef = _present_arrays(cs, nrows, stats)
+    has_present = bool(present)
+
+    def plan(sig_tail: tuple, arrays) -> _ColPlan:
+        return _ColPlan((sig_tail[0], has_present) + sig_tail[1:],
+                        present + list(arrays))
+
+    if kind in (_K_TIMESTAMP, _K_TIMESTAMP_INSTANT):
+        if kind == _K_TIMESTAMP and writer_tz not in _UTC_TZ:
+            # local-time semantics in a non-UTC zone need tz-rule
+            # arithmetic; the host reader owns that
+            raise DeviceDecodeUnsupported(f"writer timezone {writer_tz}")
+        if cs.encoding != _E_DIRECT_V2:
+            raise DeviceDecodeUnsupported(
+                f"timestamp encoding {cs.encoding}")
+        secondary = cs.streams.get(_S_SECONDARY)
+        if secondary is None:
+            raise DeviceDecodeUnsupported("missing SECONDARY stream")
+        secs, wide_s = _rlev2_arrays(_require_data(cs), ndef, True, cap, stats)
+        nanos, wide_n = _rlev2_arrays(secondary, ndef, False, cap, stats)
+        return plan(("timestamp", wide_s, wide_n), secs + nanos)
+    if kind == _K_DECIMAL:
+        return plan(("decimal",), [_decimal_stream(cs, dt, ndef, stats)])
+    if kind in (_K_SHORT, _K_INT, _K_LONG, _K_DATE):
+        if cs.encoding != _E_DIRECT_V2:
+            raise DeviceDecodeUnsupported(
+                f"integer encoding {cs.encoding}")
+        arrays, wide = _rlev2_arrays(_require_data(cs), ndef, True, cap, stats)
+        return plan(("int", wide, str(np.dtype(dt.np_dtype))), arrays)
+    if kind in (_K_FLOAT, _K_DOUBLE):
+        npdt = np.float32 if kind == _K_FLOAT else np.float64
+        try:
+            host = np.frombuffer(_require_data(cs), npdt, count=ndef)
+        except ValueError as e:
+            raise DeviceDecodeUnsupported(f"short float stream: {e}") from e
+        return plan(("float", str(np.dtype(dt.np_dtype))),
+                    [_padded(host, cap)])
+    if kind in (_K_BOOLEAN, _K_BYTE):
+        nbytes = (ndef + 7) // 8 if kind == _K_BOOLEAN else ndef
+        runs = _byte_rle_runs(_require_data(cs), nbytes) if ndef else (
+            np.zeros(1, np.uint8), np.zeros(1, np.int64),
+            np.zeros(1, np.uint8), np.zeros(1, np.int64),
+            np.zeros(1, np.uint8))
+        stats.runs += len(runs[0])
+        return plan(("bool" if kind == _K_BOOLEAN else "byte",),
+                    _byte_rle_device(runs, nbytes))
+    if kind in (_K_STRING, _K_VARCHAR, _K_CHAR):
+        return plan(*_string_plan(cs, ndef, cap, stats))
+    raise DeviceDecodeUnsupported(f"ORC kind {kind}")
+
+
+_WALKERS: Optional[cf.ThreadPoolExecutor] = None
+_WALKERS_LOCK = threading.Lock()
+
+
+def walker_pool() -> cf.ThreadPoolExecutor:
+    """The process's few threads that walk a stripe's columns side by side
+    (`decode_stripe`). They live as long as the process: a thread keeps its
+    allocator arena, so a query's walk finds the buffers the last one's
+    left, where a fresh thread for every query faults its pages in anew
+    (PERF.md, fault 22)."""
+    global _WALKERS
+    with _WALKERS_LOCK:
+        if _WALKERS is None:
+            _WALKERS = cf.ThreadPoolExecutor(
+                max_workers=max(1, min(4, len(os.sched_getaffinity(0)) - 1)),
+                thread_name_prefix="srtpu-orc-walk")
+        return _WALKERS
+
+
 def decode_stripe(info: OrcFileInfo, f, si: int, schema, host_cols=None,
-                  pushed=None):
+                  pushed=None, stats: Optional[_ScanStats] = None,
+                  walkers: Optional[cf.ThreadPoolExecutor] = None):
     """Decode ONE stripe on the TPU -> (device ColumnarBatch, row count).
+    Column by column: the host reads, deframes and walks the column's
+    streams (`scan.walk`; on `walkers`, all columns at once, where the
+    caller hands a pool in), pads its tables to their buckets
+    (`scan.pack`), ships them in one transfer (`scan.h2d`) and queues the
+    column's `io.orc.*` program in the schema's order, so the chip decodes
+    one column while the host walks the others.
     `pushed` is the scan-pushdown seam (plan/scan_pushdown.py): applied
     to the decoded stripe batch with the engine's exact kernels (mask +
     compact in one program), returning (pushed batch, output rows) —
@@ -945,128 +1522,59 @@ def decode_stripe(info: OrcFileInfo, f, si: int, schema, host_cols=None,
     (RLEv1 integer runs, missing streams, non-UTC writer timezones) raise
     DeviceDecodeUnsupported so the caller falls just THIS stripe back to
     the host reader — per-stripe granularity, the parquet path's
-    per-row-group discipline."""
+    per-row-group discipline. `stats` gathers what the host walked."""
     import jax.numpy as jnp
     from ..columnar.batch import ColumnarBatch
-    from ..columnar.padding import width_bucket
-    from ..config import get_default_conf
+    from ..columnar.column import Column
 
     st = info.stripes[si]
     nrows = st.num_rows
     cap = row_bucket(nrows, op="scan.orc")
     host_cols = set(host_cols or ())
+    stats = stats if stats is not None else _ScanStats()
     host_decoded = _host_decode_stripe_cols(info, si, schema, host_cols,
                                             cap, nrows)
-    want = {info.col_ids[name] for name in schema.names
-            if name not in host_cols}
-    cols_streams, writer_tz = _read_stripe_streams(info, f, st, want)
-    out_cols = []
-    for name, dt in zip(schema.names, schema.types):
-        if name in host_decoded:
-            out_cols.append(host_decoded[name])
-            continue
-        cid = info.col_ids[name]
-        kind = info.col_kinds[cid]
-        cs = cols_streams[cid]
-        defined, ndef = _defined_and_count(cs, nrows, cap)
-        if kind in (_K_TIMESTAMP, _K_TIMESTAMP_INSTANT):
-            if kind == _K_TIMESTAMP and writer_tz not in _UTC_TZ:
-                # local-time semantics in a non-UTC zone need tz-rule
-                # arithmetic; the host reader owns that
-                raise DeviceDecodeUnsupported(
-                    f"writer timezone {writer_tz}")
-            if cs.encoding != _E_DIRECT_V2:
-                raise DeviceDecodeUnsupported(
-                    f"timestamp encoding {cs.encoding}")
-            secondary = cs.streams.get(_S_SECONDARY)
-            if secondary is None:
-                raise DeviceDecodeUnsupported("missing SECONDARY stream")
-            secs = _rlev2_device_from_buf(_require_data(cs), ndef,
-                                          signed=True)
-            nanos_enc = _rlev2_device_from_buf(secondary, ndef,
-                                               signed=False)
-            vals = _orc_timestamp_micros(secs, nanos_enc)
-            out_cols.append(_fixed_column(vals, dt, defined, cap,
-                                          dt.np_dtype))
-        elif kind == _K_DECIMAL:
-            out_cols.append(_decimal_column(cs, dt, defined, ndef, cap))
-        elif kind in (_K_SHORT, _K_INT, _K_LONG, _K_DATE):
-            vals = _int_values_device(cs, ndef, signed=True)
-            out_cols.append(_fixed_column(vals, dt, defined, cap,
-                                          dt.np_dtype))
-        elif kind in (_K_FLOAT, _K_DOUBLE):
-            raw = _require_data(cs)
-            npdt = np.float32 if kind == _K_FLOAT else np.float64
-            try:
-                host = np.frombuffer(raw, npdt, count=ndef)
-            except ValueError as e:
-                raise DeviceDecodeUnsupported(
-                    f"short float stream: {e}") from e
-            out_cols.append(_fixed_column(jnp.asarray(host), dt, defined,
-                                          cap, dt.np_dtype))
-        elif kind == _K_BOOLEAN:
-            raw = _require_data(cs)
-            if ndef == 0:
-                vals = jnp.zeros(1, bool)
-            else:
-                runs = _byte_rle_runs(raw, (ndef + 7) // 8)
-                vals = _byte_runs_device(runs, row_bucket(ndef),
-                                         as_bits=True)[:ndef]
-            out_cols.append(_fixed_column(vals, dt, defined, cap))
-        elif kind == _K_BYTE:
-            raw = _require_data(cs)
-            if ndef == 0:
-                vals = jnp.zeros(1, jnp.uint8)
-            else:
-                runs = _byte_rle_runs(raw, ndef)
-                vals = _byte_runs_device(runs, row_bucket(ndef),
-                                         as_bits=False)[:ndef]
-            out_cols.append(_fixed_column(vals, dt, defined, cap,
-                                          jnp.int8))
-        elif kind in (_K_STRING, _K_VARCHAR, _K_CHAR):
-            out_cols.append(_assemble_strings_orc(
-                cs, dt, defined, ndef, cap, width_bucket,
-                get_default_conf().string_max_width))
-        else:
-            raise DeviceDecodeUnsupported(f"ORC kind {kind}")
-    batch = ColumnarBatch(schema, tuple(out_cols),
+    with spans.span("scan.walk", kind=spans.KIND_IO):
+        directory = _stripe_footer(info, f, st)
+
+    def walk(name, dt):
+        cid, col_stats = info.col_ids[name], _ScanStats()
+        with spans.span("scan.walk", kind=spans.KIND_IO):
+            cs = _column_streams(info, f, st, directory, cid)
+            return _column_plan(cs, info.col_kinds[cid], dt, nrows, cap,
+                                directory[2], col_stats), col_stats
+
+    todo = [(name, dt) for name, dt in zip(schema.names, schema.types)
+            if name not in host_decoded]
+    # every column's host phase starts now, on the walkers: the chip is
+    # handed the first column as soon as it is walked and decodes one while
+    # the others are walked beside it, so past the first column the scan
+    # goes at the chip's pace and not the host's
+    walks = [walkers.submit(walk, *c) for c in todo] if walkers else []
+    out_cols = dict(host_decoded)
+    try:
+        for i, (name, dt) in enumerate(todo):
+            plan, col_stats = walks[i].result() if walks else walk(name, dt)
+            stats.runs += col_stats.runs
+            stats.varint_bytes += col_stats.varint_bytes
+            with spans.span("scan.pack", kind=spans.KIND_IO):
+                program = _column_program(plan.sig, cap)
+            data, validity, lengths = program(np.int32(nrows),
+                                              *_ship(plan.arrays))
+            # one transfer, the row count and one program a column
+            _note_dispatches(3)
+            out_cols[name] = Column(dt, data, validity, lengths)
+    finally:
+        # a column that cannot decode here ends the stripe: no walk may
+        # outlive the caller's open file
+        for w in walks:
+            w.cancel()
+        cf.wait(walks)
+    batch = ColumnarBatch(schema, tuple(out_cols[n] for n in schema.names),
                           jnp.asarray(nrows, jnp.int32))
     if pushed is not None:
         return pushed(batch, nrows)
     return batch, nrows
-
-
-def _decimal_column(cs: _ColStreams, dt, defined, ndef: int, cap: int):
-    """DECIMAL column (precision <= 18): the zigzag-varint mantissa
-    stream expands per value with the device segment-sum kernel; the
-    SECONDARY per-value scale stream must equal the declared scale
-    (writers emit a constant run) or the stripe host-falls-back rather
-    than rescale."""
-    import jax.numpy as jnp
-    raw = _require_data(cs)
-    if cs.encoding != _E_DIRECT_V2:
-        # DIRECT (Hive 0.11-era) pairs the mantissas with an RLEv1 scale
-        # stream this parser would misread — like the integer path, only
-        # the v2 encoding decodes here
-        raise DeviceDecodeUnsupported(f"decimal encoding {cs.encoding}")
-    scale_raw = cs.streams.get(_S_SECONDARY)
-    if scale_raw is None:
-        raise DeviceDecodeUnsupported("missing decimal scale stream")
-    scales = _expand_runs_host(_rlev2_runs(scale_raw, ndef, True),
-                               ndef, True)
-    if ndef and not (scales == dt.scale).all():
-        raise DeviceDecodeUnsupported("per-value decimal rescale")
-    stream = np.frombuffer(raw, np.uint8)
-    if int(np.count_nonzero(stream < 128)) < ndef:
-        raise DeviceDecodeUnsupported("short decimal mantissa stream")
-    # a <=18-digit mantissa zigzags into <=63 bits -> <=9 varint bytes
-    if ndef:
-        widths = np.diff(np.concatenate(
-            ([-1], np.nonzero(stream < 128)[0][:ndef])))
-        if int(widths.max()) > 9:
-            raise DeviceDecodeUnsupported("mantissa varint wider than 64")
-    vals = _varint_zigzag_device(jnp.asarray(stream), cap)[:max(ndef, 1)]
-    return _fixed_column(vals, dt, defined, cap, dt.np_dtype)
 
 
 def _host_decode_stripe_cols(info: OrcFileInfo, si: int, schema,
@@ -1093,63 +1601,6 @@ def _host_decode_stripe_cols(info: OrcFileInfo, si: int, schema,
     if t.num_rows != nrows:
         raise DeviceDecodeUnsupported("host column row-count mismatch")
     return _host_cols_to_device(t, schema, names, cap)
-
-
-def _assemble_strings_orc(cs: _ColStreams, dt, defined, ndef: int,
-                          cap: int, width_bucket, max_width: int):
-    """STRING column -> byte-matrix layout. DIRECT_V2: LENGTH lengths
-    (host, tiny) -> cumsum offsets, device gathers spans from the DATA
-    blob. DICTIONARY_V2: indices expand on device, dictionary offsets on
-    host, device gathers from the dictionary blob. Mirrors the parquet
-    `_assemble_strings` split exactly."""
-    import jax.numpy as jnp
-    from ..columnar.column import Column
-
-    if cs.encoding == _E_DIRECT_V2:
-        blob_raw = cs.streams.get(_S_DATA, b"")
-        lens_raw = cs.streams.get(_S_LENGTH)
-        if lens_raw is None:
-            raise DeviceDecodeUnsupported("missing LENGTH stream")
-        lens = _expand_runs_host(_rlev2_runs(lens_raw, ndef, False),
-                                 ndef, False)
-        starts = np.zeros(ndef, np.int64)
-        if ndef:
-            np.cumsum(lens[:-1], out=starts[1:])
-        max_len = int(lens.max()) if ndef else 0
-        st_dev = jnp.asarray(starts)
-        ln_dev = jnp.asarray(lens.astype(np.int32))
-    elif cs.encoding == _E_DICT_V2:
-        blob_raw = cs.streams.get(_S_DICT_DATA, b"")
-        lens_raw = cs.streams.get(_S_LENGTH)
-        data = cs.streams.get(_S_DATA)
-        if lens_raw is None or data is None:
-            raise DeviceDecodeUnsupported("missing dictionary streams")
-        dcount = cs.dict_size
-        dlens = _expand_runs_host(_rlev2_runs(lens_raw, dcount, False),
-                                  dcount, False)
-        dstarts = np.zeros(dcount, np.int64)
-        if dcount:
-            np.cumsum(dlens[:-1], out=dstarts[1:])
-        max_len = int(dlens.max()) if dcount else 0
-        idx = _rlev2_device_from_buf(data, ndef, signed=False)
-        idx = jnp.clip(idx, 0, max(dcount - 1, 0))
-        st_dev = jnp.asarray(dstarts)[idx]
-        ln_dev = jnp.asarray(dlens.astype(np.int32))[idx]
-    else:
-        raise DeviceDecodeUnsupported(f"string encoding {cs.encoding}")
-
-    width = width_bucket(max(max_len, 1))
-    if width > max_width:
-        raise DeviceDecodeUnsupported(
-            f"string width {max_len} exceeds device layout limit")
-    if st_dev.shape[0] < cap:
-        st_dev = jnp.pad(st_dev, (0, cap - st_dev.shape[0]))
-        ln_dev = jnp.pad(ln_dev, (0, cap - ln_dev.shape[0]))
-    blob = jnp.asarray(np.frombuffer(blob_raw, np.uint8)
-                       if blob_raw else np.zeros(1, np.uint8))
-    matrix, lengths = _gather_strings(blob, st_dev[:cap], ln_dev[:cap],
-                                      defined, width)
-    return Column(dt, matrix, defined, lengths)
 
 
 def device_decode_file(info: OrcFileInfo, path: str, schema) -> Iterator:

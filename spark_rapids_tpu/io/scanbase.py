@@ -187,6 +187,19 @@ class TpuFileScanExec(_TpuExec):
         # visible in explain/metrics
         self.cols_host_decoded = self.metrics.create("colsHostDecoded",
                                                      M.MODERATE)
+        if plan.format_name == "orc":
+            # the ORC path's fallbacks and host walk, per executed stripe:
+            # stripes pyarrow decoded whole, every unit it decoded (a file,
+            # a stripe, a stripe's column), RLE runs the host walked and
+            # bytes of decimal varint streams it checked and shipped
+            self.stripes_host_decoded = self.metrics.create(
+                "stripesHostDecoded", M.MODERATE)
+            self.host_decoded_units = self.metrics.create(
+                "hostDecodedUnits", M.MODERATE)
+            self.orc_runs_walked = self.metrics.create("orcRunsWalked",
+                                                       M.MODERATE)
+            self.orc_varint_bytes = self.metrics.create("orcVarintBytes",
+                                                        M.MODERATE)
         # decode/read wall time per produced batch (host or device path)
         self.read_time = self.metrics.create(M.READ_TIME, M.MODERATE)
 
@@ -456,18 +469,35 @@ class TpuFileScanExec(_TpuExec):
         and merges while its siblings ride the device path); a stripe-level
         surprise (RLEv1 runs, missing streams, over-wide strings, non-UTC
         writer timezones) falls just THAT stripe back to pyarrow's
-        read_stripe."""
+        read_stripe. No fallback is silent: every file, stripe and
+        stripe's column that pyarrow decoded is one unit of
+        `TaskMetrics.scan_host_decoded` and of this scan's
+        `hostDecodedUnits` (`stripesHostDecoded`, `colsHostDecoded` say
+        which)."""
         from ..columnar.batch import batch_from_arrow
-        from .orc_device import (DeviceDecodeUnsupported, columns_supported,
-                                 decode_stripe)
+        from ..utils.metrics import TaskMetrics
+        from .orc_device import (DeviceDecodeUnsupported, _ScanStats,
+                                 columns_supported, decode_stripe,
+                                 walker_pool)
         scan = self.cpu_scan
+        tm = TaskMetrics.get()
+        # pipelined: a stripe's columns are walked side by side, ahead of
+        # the chip; off, one after the other on this thread
+        walkers = walker_pool() if self.conf.get(
+            "spark.rapids.tpu.pipeline.enabled") else None
         pushed_cb = self._apply_pushdown if self.pushed is not None else None
+
+        def host_decoded(units: int) -> None:
+            tm.scan_host_decoded += units
+            self.host_decoded_units.add(units)
+
         for path in scan.paths:
             try:
                 info, bad = columns_supported(path, scan.output)
                 if len(bad) >= len(scan.output.names):
                     raise DeviceDecodeUnsupported("no device column")
             except (DeviceDecodeUnsupported, OSError, struct_error):
+                host_decoded(1)
                 for b, nrows in self._host_file_batches(path):
                     self.num_output_rows.add(nrows)
                     yield self._count_output(b)
@@ -478,12 +508,20 @@ class TpuFileScanExec(_TpuExec):
             ofile = None
             with open(path, "rb") as f:
                 for si in range(len(info.stripes)):
+                    stats = _ScanStats()
                     try:
                         b, nrows = decode_stripe(info, f, si, scan.output,
                                                  host_cols=bad,
-                                                 pushed=pushed_cb)
+                                                 pushed=pushed_cb,
+                                                 stats=stats,
+                                                 walkers=walkers)
+                        tm.scan_batches += 1
+                        if bad:
+                            host_decoded(len(bad))
                     except (DeviceDecodeUnsupported, OSError,
                             struct_error):
+                        self.stripes_host_decoded.add(1)
+                        host_decoded(1)
                         if ofile is None:
                             ofile = pa_orc.ORCFile(path)
                         t = scan._postprocess(pa.Table.from_batches(
@@ -492,6 +530,8 @@ class TpuFileScanExec(_TpuExec):
                         b, nrows = batch_from_arrow(t), t.num_rows
                         if pushed_cb is not None:
                             b, nrows = pushed_cb(b, nrows)
+                    self.orc_runs_walked.add(stats.runs)
+                    self.orc_varint_bytes.add(stats.varint_bytes)
                     self.num_output_rows.add(nrows)
                     yield self._count_output(b)
 

@@ -75,6 +75,21 @@ def _bind(lib: ctypes.CDLL) -> None:
     lib.srtpu_rle_scan.argtypes = [u8p, ctypes.c_int64, ctypes.c_int64,
                                    ctypes.c_int32, u8p, i64p, u32p, i64p,
                                    u8p, i64p]
+    lib.srtpu_orc_deframe.restype = ctypes.c_int64
+    lib.srtpu_orc_deframe.argtypes = [u8p, ctypes.c_int64, ctypes.c_int32,
+                                      u8p, ctypes.c_int64]
+    lib.srtpu_varint_scan.restype = ctypes.c_int32
+    lib.srtpu_varint_scan.argtypes = [u8p, ctypes.c_int64, ctypes.c_int64,
+                                      i64p, i64p]
+    lib.srtpu_orc_run_table.restype = ctypes.c_int32
+    lib.srtpu_orc_run_table.argtypes = [u8p, i64p, i64p, i64p, i64p, u8p,
+                                        ctypes.c_int64, ctypes.c_int64,
+                                        ctypes.POINTER(ctypes.c_int32),
+                                        ctypes.POINTER(ctypes.c_uint32)]
+    lib.srtpu_orc_rlev2_scan.restype = ctypes.c_int64
+    lib.srtpu_orc_rlev2_scan.argtypes = [u8p, ctypes.c_int64, ctypes.c_int64,
+                                         ctypes.c_int32, u8p, i64p, i64p,
+                                         i64p, i64p, u8p, u8p, i64p]
     lib.srtpu_chunk_walk.restype = ctypes.POINTER(_SrtpuChunk)
     lib.srtpu_chunk_walk.argtypes = [u8p, ctypes.c_int64, ctypes.c_int32,
                                      ctypes.c_int32, ctypes.c_int32,
@@ -269,6 +284,105 @@ def rle_scan(payload: np.ndarray, num_values: int, bit_width: int):
     return (s.kinds[:nruns].copy(), s.counts[:nruns].copy(),
             s.values[:nruns].copy(), s.bitoffs[:nruns].copy(),
             s.packed[:pl].copy())
+
+
+def orc_deframe(buf: bytes, kind: int, bucket):
+    """An ORC stream's compression blocks (kind 0 none, 2 snappy) decoded
+    into ONE zero-tailed uint8 array of `bucket(n)` bytes, n the
+    uncompressed length -> (array, n): no growing buffer, no copy to pad.
+    None when the native lib is absent or the codec is another; raises
+    ValueError on a truncated or malformed stream."""
+    lib = _load()
+    if lib is None or kind not in (0, 2):
+        return None
+    src = np.frombuffer(buf, np.uint8)
+    n = lib.srtpu_orc_deframe(_u8(src), src.shape[0], kind, None, 0)
+    if n < 0:
+        raise ValueError("malformed compressed stream")
+    out = np.zeros(bucket(n), np.uint8)
+    if lib.srtpu_orc_deframe(_u8(src), src.shape[0], kind, _u8(out),
+                             out.shape[0]) != n:
+        raise ValueError("malformed compressed stream")
+    return out, n
+
+
+def varint_scan(stream: np.ndarray, values: int):
+    """(values that end in the zigzag-varint `stream`, the longest of the
+    first `values` in bytes, whether the stream ends inside a value) in one
+    native pass with no temporary; None when the native lib is absent."""
+    lib = _load()
+    if lib is None:
+        return None
+    ends, longest = ctypes.c_int64(0), ctypes.c_int64(0)
+    cut = lib.srtpu_varint_scan(_u8(stream), stream.shape[0], values,
+                                ctypes.byref(ends), ctypes.byref(longest))
+    return ends.value, longest.value, bool(cut)
+
+
+def orc_run_table(kinds, counts, base, step, offs, width, rb: int):
+    """`orc_rlev2_scan`'s run arrays -> (ends int32[rb], table
+    uint32[7, rb], a width passes 32 bits) in one native pass with no
+    temporary (`srtpu_orc_run_table`); None when the native lib is absent
+    or an array is not of the scan's dtype."""
+    lib = _load()
+    arrays = (kinds, counts, base, step, offs, width)
+    dtypes = (np.uint8, np.int64, np.int64, np.int64, np.int64, np.uint8)
+    if lib is None or any(
+            not isinstance(a, np.ndarray) or a.dtype != d
+            or not a.flags.c_contiguous for a, d in zip(arrays, dtypes)):
+        return None
+    ends = np.empty(rb, np.int32)
+    table = np.zeros((7, rb), np.uint32)
+    i64 = ctypes.POINTER(ctypes.c_int64)
+    wide = lib.srtpu_orc_run_table(
+        _u8(kinds), counts.ctypes.data_as(i64), base.ctypes.data_as(i64),
+        step.ctypes.data_as(i64), offs.ctypes.data_as(i64), _u8(width),
+        kinds.shape[0], rb, ends.ctypes.data_as(
+            ctypes.POINTER(ctypes.c_int32)),
+        table.ctypes.data_as(ctypes.POINTER(ctypes.c_uint32)))
+    return ends, table, bool(wide)
+
+
+_ORC_SCRATCH = threading.local()
+
+
+def orc_rlev2_scan(buf: bytes, num_values: int, signed: bool):
+    """ORC RLEv2 stream -> run table (kinds u8[R], counts, base, step, offs
+    i64[R], width u8[R], packed u8[...]) by `srtpu_orc_rlev2_scan`: kinds 0
+    repeat, 1 arithmetic, 2 packed (offs: bit offset into `packed`), 4
+    literal-delta DELTA and 5 PATCHED_BASE (offs: the run header's byte
+    position in `buf`, for the caller to decode). None when the native lib
+    is absent (io/orc_device._rlev2_runs then walks in Python); raises
+    ValueError on a truncated or short stream, as that walk does."""
+    lib = _load()
+    if lib is None:
+        return None
+    payload = np.frombuffer(buf, np.uint8)
+    n = payload.shape[0]
+    cap = n // 2 + 2  # a run takes >= 2 stream bytes
+    # worst-case outputs are thread-local scratch, as `rle_scan`'s are and
+    # for its reason; only the run-count-sized results are copied out
+    s = _ORC_SCRATCH
+    if getattr(s, "cap", 0) < cap:
+        s.cap = max(cap, 1 << 16)
+        s.u8 = [np.empty(s.cap, np.uint8) for _ in range(2)]
+        s.i64 = [np.empty(s.cap, np.int64) for _ in range(4)]
+    if getattr(s, "packed", np.empty(0, np.uint8)).shape[0] < n:
+        s.packed = np.empty(max(n, 1), np.uint8)
+    (kinds, width), (counts, base, step, offs) = s.u8, s.i64
+    plen = ctypes.c_int64(0)
+    i64 = ctypes.POINTER(ctypes.c_int64)
+    nruns = lib.srtpu_orc_rlev2_scan(
+        _u8(payload), n, num_values, int(signed), _u8(kinds),
+        counts.ctypes.data_as(i64), base.ctypes.data_as(i64),
+        step.ctypes.data_as(i64), offs.ctypes.data_as(i64), _u8(width),
+        _u8(s.packed), ctypes.byref(plen))
+    if nruns < 0:
+        raise ValueError("short RLEv2 stream" if nruns == -2
+                         else "truncated RLEv2 stream")
+    return (kinds[:nruns].copy(), counts[:nruns].copy(), base[:nruns].copy(),
+            step[:nruns].copy(), offs[:nruns].copy(), width[:nruns].copy(),
+            s.packed[:plen.value].copy())
 
 
 class _ChunkHold:

@@ -229,10 +229,16 @@ class TpuSession:
         try:
             return self._run_plan(plan, enabled)
         finally:
+            tm = metrics.TaskMetrics.get()
             metrics.note_query(
                 time.perf_counter() - t0,
                 getattr(self._last_plan, "name", plan.name),
-                spans.task_metrics_dict(metrics.TaskMetrics.get()))
+                spans.task_metrics_dict(tm))
+            if tm.compile_count:
+                # its programs are here to stay: keep the cyclic collector
+                # from walking them in the middle of every thirtieth query
+                from . import settle_host_heap
+                settle_host_heap()
 
     @staticmethod
     def recent_queries():

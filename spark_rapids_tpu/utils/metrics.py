@@ -201,6 +201,11 @@ class TaskMetrics:
         self.scan_rows_pruned = 0
         self.scan_bytes_materialized = 0
         self.scan_rowgroups_pruned = 0
+        # units of an ORC scan that the host's reader (pyarrow) decoded in
+        # the chip's place (io/scanbase._orc_batches): a file read whole, a
+        # stripe that fell back, a column of a stripe the footer routed to
+        # the host. The fallback is the default and never silent.
+        self.scan_host_decoded = 0
         # CPU-fallback stage re-runs: a device-side CpuFallbackRequired
         # (e.g. require_flat_strings on a >headWidth key) silently re-ran
         # the whole stage on the host engine this many times

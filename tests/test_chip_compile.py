@@ -130,3 +130,39 @@ def test_smoke_program_compiles(one_chip, smoke_programs, op):
     assert max(np.shape(x)[0] for x in jax.tree_util.tree_leaves(dyn)
                if np.ndim(x)) >= BATCH
     _compile(fn, *avals)
+
+
+# the ORC column programs at `lineitem.q1_orc`'s shapes (PERF.md, PR 37):
+# (signature, the shapes and dtypes of the arrays the host phase ships)
+_ORC_CAP = 2 << 20
+_RLE = lambda runs, words: [((runs,), np.int32), ((7, runs), np.uint32),  # noqa: E731
+                            ((words,), np.uint32)]
+_ORC_PROGRAMS = {
+    "decimal_2m_bytes": (("decimal", False), [((1 << 19,), np.uint32)]),
+    "decimal_8m_bytes": (("decimal", False), [((1 << 21,), np.uint32)]),
+    "date_4209_runs": (("int", False, False, "int32"),
+                       _RLE(8192, 1 << 20)),
+    "flag_387064_runs": (("string_dict", False, False, 8, 8),
+                         _RLE(1 << 19, 1 << 16) + [
+                             ((8,), np.int64), ((8,), np.int32),
+                             ((16,), np.uint8)]),
+    "nullable_wide_long": (("int", True, True, "int64"),
+                           _RLE(1024, 128) + _RLE(8192, 1 << 21)),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_ORC_PROGRAMS))
+def test_orc_column_program_compiles_without_a_loop(one_chip, name):
+    """A decode program holds no `while` (a search per slot, a scan over
+    the bytes) and no sort: marks, prefix sums, stacked gathers, shifts."""
+    from spark_rapids_tpu.io import orc_device as O
+    sig, arrays = _ORC_PROGRAMS[name]
+    if sig[1]:    # a PRESENT stream's byte-RLE table comes first
+        arrays = [((1024,), np.int32), ((3, 1024), np.uint32),
+                  ((1 << 18,), np.uint8)] + arrays[3:]
+    fn = O._column_program(sig, _ORC_CAP).fn
+    exe = _compile(fn, _shape(one_chip, (), jnp.int32),
+                   *[_shape(one_chip, s, d) for s, d in arrays])
+    text = exe.as_text()
+    assert " while(" not in text and " sort(" not in text
+    assert text.count(" gather(") <= 6

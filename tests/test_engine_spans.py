@@ -376,3 +376,34 @@ class TestThePrimitive:
         names = {n for n, _, _ in _host_events(tmp_path)}
         assert SPAN_PREFIX + "dispatch.exec.test" in names
         assert "dispatch.exec.test" not in names
+
+
+@pytest.mark.parametrize("alive", [3, 600])
+@pytest.mark.parametrize("wide", [False, True], ids=["head", "overflow"])
+def test_the_sink_ships_a_string_columns_live_rows_not_its_capacity(
+        monkeypatch, wide, alive):
+    """An answer of a few rows in a batch of a large capacity: the string
+    matrix, lengths and overflow starts are cut to the live rows on the
+    device, as validity and flat data always were, before they cross. Where
+    more than a quarter of the capacity is alive they cross whole, and no
+    program is compiled for the cut."""
+    from spark_rapids_tpu.columnar import strings
+    from spark_rapids_tpu.exec.transitions import device_batch_to_host
+    words = ["N", "A" * 3, "R" * (300 if wide else 5)]
+    t = pa.table({"flag": words + [""] * 1021, "n": list(range(1024))})
+    b = batch_from_arrow(t)
+    some = ColumnarBatch(b.schema, b.columns, jnp.asarray(alive, jnp.int32))
+    crossed, real = [], strings.assemble_matrix
+
+    def seen(head, lengths, overflow, n):
+        crossed.append((head.shape[0], lengths.shape[0],
+                        None if overflow is None else overflow[1].shape[0]))
+        return real(head, lengths, overflow, n)
+    monkeypatch.setattr(strings, "assemble_matrix", seen)
+    hb = device_batch_to_host(some)
+    rows = alive if 4 * alive <= 1024 else 1024
+    assert crossed == [(rows, rows, rows if wide else None)]
+    mat, lens = hb.vecs[0].data, hb.vecs[0].lengths
+    assert mat.shape[0] == lens.shape[0] == alive
+    assert [bytes(mat[i, :lens[i]]).decode() for i in range(3)] == words
+    assert hb.vecs[1].data.tolist() == list(range(alive))
