@@ -31,6 +31,7 @@ import jax.numpy as jnp  # noqa: E402
 
 import spark_rapids_tpu  # noqa: E402,F401  (x64 on)
 from spark_rapids_tpu.io import orc_device as O  # noqa: E402
+from spark_rapids_tpu.ops import rowops as R  # noqa: E402
 
 OUT = []
 
@@ -211,11 +212,11 @@ def main(argv=None) -> int:
             assert np.array_equal(np.asarray(got), want), label
         price(f"{label}.value_ends", lambda w: O._varint_zigzag(w, cap),
               jax.device_put(words), check=same)
-        was, O._GATHER_MIN_COLS = O._GATHER_MIN_COLS, 0
+        was, R._GATHER_MIN_COLS = R._GATHER_MIN_COLS, 0
         price(f"{label}.value_ends_short_tables_unpadded",
               lambda w: O._varint_zigzag(w, cap), jax.device_put(words),
               check=same)
-        O._GATHER_MIN_COLS = was
+        R._GATHER_MIN_COLS = was
         if parent_too(label):
             price(f"{label}.parent_segment_sum",
                   lambda s: parent_varint(s, cap), jax.device_put(stream),
@@ -237,16 +238,16 @@ def main(argv=None) -> int:
               *jax.device_put([ends, table, words]), check=same)
         price(f"{label}.slot_runs_alone",
               lambda e: O._slot_runs(e, cap), jax.device_put(ends))
-        if ends.shape[0] < O._GATHER_MIN_COLS:
+        if ends.shape[0] < R._GATHER_MIN_COLS:
             # the compiler gathers out of a table of under ~2 MB another
             # way, with 1 GB of temporaries at 2,097,152 slots (sandbox
-            # v5e compiler, PR 37): `_gather_rows` pads it on the device
-            was, O._GATHER_MIN_COLS = O._GATHER_MIN_COLS, 0
+            # v5e compiler, PR 37): `gather_rows` pads it on the device
+            was, R._GATHER_MIN_COLS = R._GATHER_MIN_COLS, 0
             price(f"{label}.short_tables_unpadded",
                   lambda e, t, w: O._expand_rlev2(e, t, w, cap, signed,
                                                   wide),
                   *jax.device_put([ends, table, words]), check=same)
-            O._GATHER_MIN_COLS = was
+            R._GATHER_MIN_COLS = was
         if parent_too(label):
             arrs = [jnp.asarray(a) for a in rt.arrays()]
             price(f"{label}.parent_searchsorted",
@@ -260,7 +261,7 @@ def main(argv=None) -> int:
         bitpos = jnp.arange(cap, dtype=jnp.uint32) * jnp.uint32(16)
 
         def two_words(w, bp):
-            g = O._gather_rows([w, O._ahead(w, 1)], bp >> jnp.uint32(5))
+            g = R.gather_rows([w, R.ahead(w, 1)], bp >> jnp.uint32(5))
             hi = O._u64(g[1], g[0])
             r = (bp & jnp.uint32(31)).astype(jnp.uint64)
             return (hi >> (jnp.uint64(48) - r)) & jnp.uint64(0xFFFF)

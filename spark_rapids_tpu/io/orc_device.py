@@ -32,10 +32,9 @@ reloads from the persistent cache:
       file's exact counts.
   device (the actual data work):
     * RLEv2 expansion: output slot -> run by one mark per run and a prefix
-      sum (`_slot_runs`, `parquet_device._run_of_slot`'s way), the run's
-      words by one stacked gather, packed runs unpacked from two or three
-      big-endian 32-bit words a slot by one more, zigzag undone with vector
-      ops;
+      sum (`parquet_device._slot_runs`), the run's words by one stacked
+      gather, packed runs unpacked from two or three big-endian 32-bit
+      words a slot by one more, zigzag undone with vector ops;
     * DECIMAL (precision <= 18): the zigzag base-128 varint mantissas fold
       from their value ends (`_varint_zigzag`): terminator bits per 32-byte
       block, a prefix sum, two stacked gathers, shifts;
@@ -69,11 +68,11 @@ import numpy as np
 
 from .. import types as T
 from ..columnar.padding import row_bucket
-from ..ops.rowops import PACK_ROWS
+from ..ops.rowops import PACK_ROWS, ahead, gather_rows
 from ..utils import spans
 from .parquet_device import (DeviceDecodeUnsupported, _host_cols_to_device,
                              _note_dispatches, _pow2, _prefix_sum_i32, _ship,
-                             _string_matrix_tail)
+                             _slot_runs, _string_matrix_tail)
 
 __all__ = ["OrcFileInfo", "columns_supported", "decode_stripe",
            "device_decode_file", "file_supported"]
@@ -808,56 +807,6 @@ def _expand_runs_host(rt: _RunTable, num_values: int,
 # Device kernels (traced: they run inside a column's `io.orc.*` program)
 # ----------------------------------------------------------------------------
 
-def _slot_runs(ends, cap: int):
-    """run int32[cap]: for every output slot the run of the table that holds
-    it, from the runs' exclusive end slots. `parquet_device._run_of_slot`'s
-    way (one mark per run, one prefix sum, its barrier and for its reason),
-    from ends the host's walk has already summed: a run table of half a
-    million runs would otherwise pay a flat `cumsum` (PERF.md, fault 15).
-    Padding runs end where the last real one does and are stepped over."""
-    import jax.numpy as jnp
-    from jax import lax
-    assert 0 < cap < 2 ** 31, cap
-    marks = jnp.zeros(cap, jnp.int32).at[ends].add(
-        1, mode="drop", indices_are_sorted=True)
-    run = jnp.clip(_prefix_sum_i32(marks), 0, ends.shape[0] - 1)
-    return lax.optimization_barrier(run)
-
-
-# The v5e compiler gathers out of a table of under ~2 MB another way, with
-# 1 GB of temporaries for 2,097,152 indices, and that way costs by the row:
-# 37.9 ms for the date column's expansion, whose 7-row table has 8,192 runs,
-# against 18.0 with the table zero-padded to 524,288, and 37.8 against 22.0
-# for the 8 MB varint stream's two rows of 262,144; but two rows of 65,536 or
-# 131,072 are 2.5 ms cheaper left short (sandbox v5e compiler and my chip
-# runs, PERF.md, PR 37). So a short table is padded, on the device, to this
-# many columns before a long gather unless it is two rows of under a megabyte.
-_GATHER_MIN_COLS = 1 << 19
-_GATHER_SHORT_OK = (2, 1 << 17)      # at most (rows, columns)
-
-
-def _gather_rows(rows, idx):
-    """uint32[K, len(idx)]: K <= 8 uint32 rows of one length (a list of
-    them, or a (K, n) matrix) gathered along it by one index vector,
-    stacked so that the chip pays per index and not per row
-    (`ops/rowops.PACK_ROWS`; PERF.md price list)."""
-    import jax.numpy as jnp
-    m = jnp.stack(rows) if isinstance(rows, (list, tuple)) else rows
-    assert m.shape[0] <= PACK_ROWS, m.shape
-    k, n = m.shape
-    short = min(_GATHER_MIN_COLS, idx.shape[0]) - n
-    if short > 0 and not (k <= _GATHER_SHORT_OK[0]
-                          and n <= _GATHER_SHORT_OK[1]):
-        m = jnp.pad(m, ((0, 0), (0, short)))
-    return m[:, jnp.clip(idx, 0, n - 1)]
-
-
-def _ahead(words, k: int):
-    """`words` read `k` places ahead (zeros past the end)."""
-    import jax.numpy as jnp
-    return jnp.concatenate([words[k:], jnp.zeros(k, words.dtype)])
-
-
 def _u64(lo, hi):
     import jax.numpy as jnp
     return lo.astype(jnp.uint64) | (hi.astype(jnp.uint64) << jnp.uint64(32))
@@ -885,15 +834,15 @@ def _expand_rlev2(ends, table, words, cap: int, signed: bool, wide: bool):
     import jax.numpy as jnp
     u32, u64 = jnp.uint32, jnp.uint64
     run = _slot_runs(ends, cap)
-    start, blo, bhi, slo, shi, offs, wk = _gather_rows(table, run)
+    start, blo, bhi, slo, shi, offs, wk = gather_rows(table, run)
     within = jnp.arange(cap, dtype=jnp.int32) - start.astype(jnp.int32)
     arith = _u64(blo, bhi) + within.astype(u64) * _u64(slo, shi)
     width = wk & u32(0xFF)
     bitpos = offs + within.astype(u32) * width
     q, r = bitpos >> u32(5), (bitpos & u32(31)).astype(u64)
     w = width.astype(u64)
-    rows = [words, _ahead(words, 1)] + ([_ahead(words, 2)] if wide else [])
-    g = _gather_rows(rows, q)
+    rows = [words, ahead(words, 1)] + ([ahead(words, 2)] if wide else [])
+    g = gather_rows(rows, q)
     hi = _u64(g[1], g[0])
     if wide:
         top = (hi << r) | (g[2].astype(u64) >> (u64(32) - r))
@@ -950,15 +899,15 @@ def _varint_zigzag(words, cap: int):
     counts = jax.lax.population_count(mask).astype(jnp.int32)
     ends = _prefix_sum_i32(counts)
     blk = _slot_runs(ends, cap)
-    m, first = _gather_rows([mask, (ends - counts).astype(u32)], blk)
+    m, first = gather_rows([mask, (ends - counts).astype(u32)], blk)
     v = jnp.arange(cap, dtype=jnp.int32)
     end = blk * 32 + _select_bit(m, v - first.astype(jnp.int32)).astype(
         jnp.int32)
     start = jnp.concatenate([jnp.zeros(1, jnp.int32), end[:-1] + 1])
     n = (end - start + 1).astype(u64)
     r8 = (start & 3).astype(u64) * u64(8)
-    w0, w1, w2 = _gather_rows([words, _ahead(words, 1), _ahead(words, 2)],
-                              start >> 2)
+    w0, w1, w2 = gather_rows([words, ahead(words, 1), ahead(words, 2)],
+                             start >> 2)
     # shift amounts stay under 64 in every lane, the unused ones too
     x = (_u64(w0, w1) >> r8) | jnp.where(
         r8 > 0, w2.astype(u64) << ((u64(64) - r8) & u64(63)), u64(0))
@@ -975,7 +924,7 @@ def _expand_byte_rle(ends, table, blob, cap: int):
     """Byte-RLE run table (`_byte_rle_device`) -> uint8[cap] bytes."""
     import jax.numpy as jnp
     run = _slot_runs(ends, cap)
-    start, vk, offs = _gather_rows(table, run)
+    start, vk, offs = gather_rows(table, run)
     within = jnp.arange(cap, dtype=jnp.int32) - start.astype(jnp.int32)
     lit = blob[jnp.clip(offs.astype(jnp.int32) + within, 0,
                         blob.shape[0] - 1)]
@@ -1039,8 +988,8 @@ def _dictionary_rows(blob, dstarts, dlens, idx, valid, width: int):
         b = dmat.reshape(dcap, nw, 4).astype(u32)
         ws = (b[:, :, 0] << u32(24)) | (b[:, :, 1] << u32(16)) | \
             (b[:, :, 2] << u32(8)) | b[:, :, 3]
-        g = _gather_rows([ws[:, i] for i in range(nw)] + [dln.astype(u32)],
-                         idx)
+        g = gather_rows([ws[:, i] for i in range(nw)] + [dln.astype(u32)],
+                        idx)
         shifts = jnp.array([24, 16, 8, 0], dtype=u32)
         mat = ((jnp.stack(list(g[:nw]), axis=1)[:, :, None] >> shifts)
                & u32(0xFF)).astype(jnp.uint8).reshape(-1, width)

@@ -12,15 +12,19 @@ encodings default pyarrow/Spark output actually uses:
     * page decompression (snappy/gzip/zstd via pyarrow) — byte plumbing only;
     * RLE run STRUCTURE scan: def-level and dictionary-index streams split
       into small per-run tables (kind, count, value, bit offset) without
-      expanding any values;
+      expanding any values, then ONE table per column chunk and stream
+      (`_run_words`: per run its end slot, bit base, value and width, so
+      pages of every bit width share it), the packed bits as 32-bit words;
+      a chunk whose def levels define every row ships none;
     * BYTE_ARRAY offset scan: the serial (u32 len, bytes)* prefix walk
       (native C++, srtpu_byte_array_scan) — each length's position depends
       on all previous lengths, the one genuinely sequential step.
   device (the actual data work):
-    * def-level + index expansion: output slot -> run by one mark per run
-      scattered into the slots and a prefix sum over them (`_run_of_slot`:
-      the slots are all queried, in order, so no table is searched),
-      bit-packed runs unpacked with vector shifts (1-bit def levels,
+    * def-level + index expansion (`_unpack_runs`): output slot -> run by
+      one mark per run scattered into the slots and a prefix sum over them
+      (`_slot_runs`: the slots are all queried, in order, so no table is
+      searched), the run's words by one stacked gather and the two packed
+      words that hold the slot's bits by one more (1-bit def levels,
       up-to-32-bit dictionary indices) — values never exist row-wise on
       the host;
     * PLAIN values: the raw little-endian byte buffer is shipped once and
@@ -29,7 +33,9 @@ encodings default pyarrow/Spark output actually uses:
     * BYTE_ARRAY: every value span gathered out of the shipped page/dict
       blobs into the byte-matrix string layout (uint8[cap, width]);
     * null scatter: non-null values land at their row slots via the
-      rank = cumsum(defined) gather (same shape as the join expansion).
+      rank = cumsum(defined) gather (same shape as the join expansion);
+      where a chunk has no def-level table the rank is the slot, and the
+      values are masked, not gathered.
 
 Logical-type coverage beyond the primitives (reference decodes the full
 matrix in one `Table.readParquet`, `GpuParquetScan.scala:2461`):
@@ -63,6 +69,7 @@ import numpy as np
 
 from .. import types as T
 from ..columnar.padding import LANE, row_bucket
+from ..ops.rowops import ahead, gather_rows
 from ..utils import spans
 
 __all__ = ["DeviceDecodeUnsupported", "columns_supported",
@@ -312,77 +319,87 @@ def _prefix_sum_i32(x):
     return rows.reshape(-1)[:n]
 
 
-def _run_of_slot(counts, cap: int):
-    """(run int32[cap], ends int32[R]): for every output slot j the run of
-    the table that holds it, `searchsorted(cumsum(counts), j, side="right")`
-    clipped to R - 1, and the runs' exclusive end slots. The query is every
+def _slot_runs(ends, cap: int):
+    """run int32[cap]: for every output slot the run of the table that holds
+    it, `searchsorted(ends, j, side="right")` clipped to R - 1, from the
+    runs' exclusive end slots, which the host has summed. The query is every
     slot in order, so nothing is searched: a run ends before slot j exactly
     when its end marks a slot <= j, which is one mark per RUN scattered into
-    the slots and one prefix sum over them. Zero-count runs (`_pad_runs`'
-    tail, real empty runs) stack their marks on one slot and are stepped
-    over as side="right" steps over them; ends at or past `cap` mark no
-    slot. int32 throughout: the callers' totals are row counts <= cap.
+    the slots and one prefix sum over them. Zero-count runs (real empty runs,
+    and padding runs, which end where the last real one does) stack their
+    marks on one slot and are stepped over as side="right" steps over them;
+    ends at or past `cap` mark no slot. Shared with `orc_device`.
 
     The barrier makes the map one array, computed once. The searches it
-    replaces were loops, which XLA fuses nothing into; without them it
-    fuses the prefix sum into each of the five gathers that read the map,
-    and the decode programs' code, which lives in device memory, grows
-    from 182 to 279 MB (`star.q3`) and from 189 to 500 MB (`lineitem.q1`;
-    sandbox v5e compiler, PR 30)."""
+    replaced were loops, which XLA fuses nothing into; without them it
+    fuses the prefix sum into each gather that reads the map, and the
+    decode programs' code, which lives in device memory, grows from 182 to
+    279 MB (`star.q3`) and from 189 to 500 MB (`lineitem.q1`; sandbox v5e
+    compiler, PR 30)."""
     import jax.numpy as jnp
     from jax import lax
     assert 0 < cap < 2 ** 31, cap
-    ends = jnp.cumsum(counts.astype(jnp.int32))
     marks = jnp.zeros(cap, jnp.int32).at[ends].add(
         1, mode="drop", indices_are_sorted=True)
-    run = jnp.clip(_prefix_sum_i32(marks), 0, counts.shape[0] - 1)
-    return lax.optimization_barrier((run, ends))
+    run = jnp.clip(_prefix_sum_i32(marks), 0, ends.shape[0] - 1)
+    return lax.optimization_barrier(run)
 
 
-def _slot_in_packed(run, ends, bitoffs, packed, cap: int, bw: int):
-    """(j, bitpos): the slot numbers and, for slots of bit-packed runs,
-    each slot's bit position in `packed`. int32 where the static shapes
-    allow it (64-bit integers are emulated on the chip), int64 where a bit
-    position, a dead slot's included, could pass 2^31."""
+class _Tally:
+    """What a decode program's per-slot gathers are, counted while it is
+    traced: `stacked`, the stacked gathers lowered into it (two a run
+    table, and the chunk merge's word matrices); `elided`, the gathers by
+    an index the host knows to be the slot itself that it does not make
+    (a null scatter's rank gather in a chunk with no def-level table, a
+    string column's two, and each array of a chunk merge over full
+    leading chunks). The program keeps the two in its box (`[stacked,
+    elided]`), which the compile service restores on every cache hit."""
+    __slots__ = ("stacked", "elided")
+
+    def __init__(self):
+        self.stacked = self.elided = 0
+
+
+def _unpack_runs(ends, table, words, cap: int):
+    """Run table (`_run_words`) -> uint32[cap], traced. Per slot: its run
+    by `_slot_runs`, the run's three words (bit base, repeated value,
+    width) by one stacked gather, then the two 32-bit words of the
+    LSB-first packed stream that hold its bits by one more: a packed
+    run's slot reads `width` bits at `base + j * width`, a repeated run's
+    has width 0 and reads `value`. uint32 throughout; the host keeps every
+    bit position under 2^31. Slots past the table's total read a padding
+    run or the last run: the callers mask them."""
     import jax.numpy as jnp
-    narrow = packed.shape[0] * 8 + cap * bw < 2 ** 31
-    idt = jnp.int32 if narrow else jnp.int64
-    j = jnp.arange(cap, dtype=jnp.int32)
-    base = jnp.where(run > 0, ends[jnp.maximum(run - 1, 0)], 0)
-    bitpos = bitoffs.astype(idt)[run] + (j - base).astype(idt) * bw
-    return j, bitpos
+    u32 = jnp.uint32
+    run = _slot_runs(ends, cap)
+    base, value, width = gather_rows(table, run)
+    bitpos = base + jnp.arange(cap, dtype=u32) * width
+    r = bitpos & u32(31)
+    lo, hi = gather_rows([words, ahead(words, 1)],
+                         (bitpos >> u32(5)).astype(jnp.int32))
+    # r + width <= 63: the bits lie inside the two words
+    bits = (lo >> r) | jnp.where(r > 0, hi << ((u32(32) - r) & u32(31)),
+                                 u32(0))
+    mask = jnp.where(width >= 32, ~u32(0),
+                     (u32(1) << (width & u32(31))) - u32(1))
+    return (bits & mask) | value
 
 
-@functools.partial(__import__("jax").jit, static_argnums=(5,))
-def _expand_def_levels(kinds, counts, values, bitoffs, packed, cap: int):
-    """Run table -> bool[cap] defined mask, entirely on device."""
+@functools.partial(__import__("jax").jit, static_argnums=(3,))
+def _expand_def_levels(ends, table, words, cap: int):
+    """Def-level run table -> bool[cap] defined mask, entirely on device."""
     import jax.numpy as jnp
-    run, ends = _run_of_slot(counts, cap)
-    j, bitpos = _slot_in_packed(run, ends, bitoffs, packed, cap, 1)
-    byte = packed[jnp.clip(bitpos // 8, 0, packed.shape[0] - 1)]
-    bit = (byte >> (bitpos % 8).astype(jnp.uint8)) & 1
-    lvl = jnp.where(kinds[run] == 1, bit, values[run])
-    return (lvl == 1) & (j < ends[-1])
+    lvl = _unpack_runs(ends, table, words, cap)
+    return (lvl == 1) & (jnp.arange(cap, dtype=jnp.int32) < ends[-1])
 
 
-@functools.partial(__import__("jax").jit, static_argnums=(5, 6))
-def _expand_rle_u32(kinds, counts, values, bitoffs, packed, cap: int,
-                    bw: int):
-    """Run table -> u32[cap] values (dictionary indices), on device.
-    Multi-bit generalization of _expand_def_levels: each output slot
-    gathers a (bw+7)/8+1-byte window and shifts its value out."""
+@functools.partial(__import__("jax").jit, static_argnums=(3,))
+def _expand_rle_u32(ends, table, words, cap: int):
+    """Dictionary-index run table -> u32[cap] values, on device; one table
+    holds the runs of pages of any bit widths up to 32."""
     import jax.numpy as jnp
-    run, ends = _run_of_slot(counts, cap)
-    j, bitpos = _slot_in_packed(run, ends, bitoffs, packed, cap, bw)
-    b0 = bitpos // 8
-    window = jnp.zeros(cap, jnp.uint64)
-    for k in range((bw + 7) // 8 + 1):  # bw bits at offset<=7 span this many
-        byte = packed[jnp.clip(b0 + k, 0, packed.shape[0] - 1)]
-        window = window | (byte.astype(jnp.uint64) << jnp.uint64(8 * k))
-    sh = (bitpos % 8).astype(jnp.uint64)
-    pv = ((window >> sh) & jnp.uint64((1 << bw) - 1)).astype(jnp.uint32)
-    out = jnp.where(kinds[run] == 1, pv, values[run])
-    return jnp.where(j < ends[-1], out, 0)
+    out = _unpack_runs(ends, table, words, cap)
+    return jnp.where(jnp.arange(cap, dtype=jnp.int32) < ends[-1], out, 0)
 
 
 @__import__("jax").jit
@@ -395,19 +412,73 @@ def _scatter_values(vals, defined):
     return jnp.where(defined, data, jnp.zeros((), vals.dtype)), defined
 
 
-@functools.partial(__import__("jax").jit, static_argnums=(4,))
-def _gather_strings(blob, starts, lens, defined, width: int):
+def _to_slots(vals, defined, has_def: bool, tally: _Tally):
+    """`_scatter_values`, traced, where the chunk has a def-level table;
+    without one every slot below the row count is defined, so a value's
+    rank is its slot: masked, not gathered."""
+    import jax.numpy as jnp
+    if has_def:
+        return _scatter_values(vals, defined)
+    tally.elided += 1
+    return jnp.where(defined, vals, jnp.zeros((), vals.dtype)), defined
+
+
+def _dict_gather(dicts, idx):
+    """[d[idx] for d in dicts]: dictionaries of 4- and 8-byte integers as
+    the 32-bit rows of one stacked matrix (`gather_rows`; at most eight
+    rows), gathered once; any other dtype alone. A stacked matrix costs
+    per index, not per row (PERF.md price list), and the v5e compiler
+    took 320 s over one nullable int64 column of a 231,037-entry
+    dictionary gathered as 64-bit values, 116 s as stacked words (sandbox
+    v5e compiler, PR 38)."""
+    import jax
+    import jax.numpy as jnp
+    u32 = jnp.uint32
+    rows, plans = [], []
+    for d in dicts:
+        size = d.dtype.itemsize
+        if d.dtype.kind not in "iu" or size not in (4, 8) or \
+                len(rows) + size // 4 > 8:
+            plans.append(d[idx])
+            continue
+        u = jax.lax.bitcast_convert_type(d, np.dtype(f"uint{8 * size}"))
+        plans.append((len(rows), d.dtype))
+        rows.append(u.astype(u32))
+        if size == 8:
+            rows.append((u >> jnp.uint64(32)).astype(u32))
+    g = gather_rows(rows, idx.astype(jnp.int32)) if rows else None
+    out = []
+    for p in plans:
+        if not isinstance(p, tuple):
+            out.append(p)
+            continue
+        at, dt = p
+        if dt.itemsize == 4:
+            out.append(jax.lax.bitcast_convert_type(g[at], dt))
+        else:
+            u = g[at].astype(jnp.uint64) | \
+                (g[at + 1].astype(jnp.uint64) << jnp.uint64(32))
+            out.append(jax.lax.bitcast_convert_type(u, dt))
+    return out
+
+
+@functools.partial(__import__("jax").jit, static_argnums=(4, 5))
+def _gather_strings(blob, starts, lens, defined, width: int,
+                    dense: bool = False):
     """Device bytes->matrix: row r reads value rank[r]'s span out of the
     page/dict blob into the fixed-width byte-matrix string layout
     (data uint8[cap, width] + lengths int32[cap]). The variable-length
     stream never exists row-wise on the host — only the serial offset
-    scan (native byte_array_scan) ran there."""
+    scan (native byte_array_scan) ran there. `dense`: every defined row is
+    a slot below the row count, so rank[r] is r and nothing is gathered
+    by it."""
     import jax.numpy as jnp
-    cap = defined.shape[0]
-    rank = jnp.cumsum(defined.astype(jnp.int32)) - 1
-    safe = jnp.clip(rank, 0, cap - 1)
-    return _string_matrix_tail(blob, starts[safe], lens[safe], defined,
-                               width)
+    if not dense:
+        cap = defined.shape[0]
+        rank = jnp.cumsum(defined.astype(jnp.int32)) - 1
+        safe = jnp.clip(rank, 0, cap - 1)
+        starts, lens = starts[safe], lens[safe]
+    return _string_matrix_tail(blob, starts, lens, defined, width)
 
 
 def _string_matrix_tail(blob, starts, lens, valid, width: int):
@@ -459,6 +530,16 @@ def _int96_to_micros(mat):
     # // truncates like Java integer division here
     return (day - 2440588) * 86_400_000_000 + \
         (nanos.astype(jnp.int64) // 1000)
+
+
+def _flba_values(mat, post: str, flen: int):
+    """Fixed-width byte values [n, flen] -> the engine's value arrays:
+    [micros] (INT96), [unscaled int64] (decimal of at most 18 digits) or
+    [hi, lo] limbs (wider decimals)."""
+    if post == "int96":
+        return [_int96_to_micros(mat)]
+    hi, lo = _flba_to_limbs(mat, flen)
+    return [lo] if post == "dec64" else [hi, lo]
 
 
 # ----------------------------------------------------------------------------
@@ -699,16 +780,59 @@ def _decode_chunk_inner(buf: bytes, col_meta, optional: bool) -> _Chunk:
     return chunk
 
 
-def _merge_runs(run_parts):
-    kinds = np.concatenate([p[0] for p in run_parts])
-    counts = np.concatenate([p[1] for p in run_parts])
-    values = np.concatenate([p[2] for p in run_parts])
-    packed_lens = [p[4].shape[0] for p in run_parts]
-    offs = np.concatenate(([0], np.cumsum(packed_lens)[:-1]))
-    bitoffs = np.concatenate([p[3] + o * 8
-                              for p, o in zip(run_parts, offs)])
-    packed = np.concatenate([p[4] for p in run_parts])
-    return kinds, counts, values, bitoffs, packed
+def _run_words(parts):
+    """The run table `_unpack_runs` reads, for one column chunk's stream in
+    slot order: `parts` are (runs, bit width, values) of its pages, `runs`
+    `_rle_runs`' five arrays (bit offsets local to the part's packed bytes)
+    or None for `values` zeros (a one-entry dictionary's width-0 page).
+    Pages of any widths share the table. Returns (ends int32[Rb], table
+    uint32[3, Rb], words uint32[Pb]): per run its exclusive end slot and
+    three words, the bit position of slot 0 were the run to start there
+    (bit offset - first slot * width, modulo 2^32, so that a slot's is
+    `base + j * width`), the repeated value (0 in a packed run) and the
+    width (0 in a repeated run); the packed bytes of every part one after
+    the other as little-endian words. Rb and Pb are power-of-two buckets
+    (padding runs hold nothing and end where the last real one does), so
+    repeated row groups share one program's shapes."""
+    kinds, counts, values, bitoffs, widths, packed = [], [], [], [], [], []
+    nbytes = 0
+    for runs, width, n in parts:
+        if runs is None:
+            if not n:
+                continue
+            runs = (np.zeros(1, np.uint8), np.full(1, n, np.int64),
+                    np.zeros(1, np.uint32), np.zeros(1, np.int64),
+                    np.zeros(0, np.uint8))
+        k, c, v, b, pk = runs
+        kinds.append(k)
+        counts.append(c)
+        values.append(v)
+        bitoffs.append(b + 8 * nbytes)
+        widths.append(np.where(k == 1, width, 0))
+        packed.append(pk)
+        nbytes += pk.shape[0]
+    r = sum(len(c) for c in counts)
+    rb = _pow2(max(r, 1))
+    ends_b = np.zeros(rb, np.int32)
+    table = np.zeros((3, rb), np.uint32)
+    if r:
+        count = np.concatenate(counts).astype(np.int64)
+        ends = np.cumsum(count)
+        if ends[-1] >= 2 ** 31 or nbytes * 8 >= 2 ** 31:
+            raise DeviceDecodeUnsupported("run table past 2^31 slots or bits")
+        w = np.concatenate(widths).astype(np.int64)
+        packed_run = w > 0
+        table[0, :r] = np.where(
+            packed_run, np.concatenate(bitoffs) - (ends - count) * w, 0) \
+            & 0xFFFFFFFF
+        table[1, :r] = np.where(packed_run, 0, np.concatenate(values))
+        table[2, :r] = w
+        ends_b[:] = ends[-1]
+        ends_b[:r] = ends
+    words = np.zeros(_pow2(max(-(-nbytes // 4), 1)) * 4, np.uint8)
+    if nbytes:
+        words[:nbytes] = np.concatenate(packed)
+    return ends_b, table, words.view("<u4")
 
 
 _OK_ENCODINGS = {"PLAIN", "RLE", "BIT_PACKED", "PLAIN_DICTIONARY",
@@ -862,24 +986,6 @@ def _pow2(n: int) -> int:
     return p
 
 
-def _pad_runs(runs):
-    """Pad a run table to power-of-two lengths (zero-count runs append
-    harmlessly past the cumsum; packed pads with dead bytes) so repeated
-    row groups hit the same fused-program shape instead of retracing."""
-    kinds, counts, values, bitoffs, packed = runs
-    rb = _pow2(max(len(kinds), 1))
-    pad = rb - len(kinds)
-    if pad:
-        kinds = np.pad(kinds, (0, pad))
-        counts = np.pad(counts, (0, pad))
-        values = np.pad(values, (0, pad))
-        bitoffs = np.pad(bitoffs, (0, pad))
-    pb = _pow2(max(len(packed), 1))
-    if pb > len(packed):
-        packed = np.pad(packed, (0, pb - len(packed)))
-    return kinds, counts, values, bitoffs, packed
-
-
 def _host_phase(pf, f, rg: int, schema, host_cols=None):
     """HOST half of a row-group decode: chunk reads, page parsing,
     decompression and RLE run scans — numpy/bytes only, no device work.
@@ -940,13 +1046,16 @@ def _host_phase(pf, f, rg: int, schema, host_cols=None):
         w.chunk = _decode_chunk(buf, cm, w.optional)
         if w.chunk.total != nrows:
             raise DeviceDecodeUnsupported("page/row-group mismatch")
-        if w.chunk.def_runs_merged is not None:
-            w.defruns = _pad_runs(w.chunk.def_runs_merged)
+        if sum(p.ndef for p in w.chunk.pages) == nrows:
+            # every row defined (a required column, or one that could
+            # hold nulls and holds none): no def-level table, and each
+            # value's slot is its rank
+            w.defruns = None
+        elif w.chunk.def_runs_merged is not None:
+            w.defruns = _run_words([(w.chunk.def_runs_merged, 1, nrows)])
         else:
-            run_parts = [p.runs for p in w.chunk.pages
-                         if p.runs is not None]
-            w.defruns = _pad_runs(_merge_runs(run_parts)) \
-                if w.optional and run_parts else None
+            w.defruns = _run_words([(p.runs, 1, p.num_values)
+                                    for p in w.chunk.pages])
         w.ship = w.meta = None
         if w.spec.kind == "prim":
             prepped = _prep_fixed(w.chunk, w.phys)
@@ -963,10 +1072,12 @@ def _host_phase(pf, f, rg: int, schema, host_cols=None):
     return {w.name: w for w in works}, nrows
 
 
-def _device_phase(pf, rg: int, schema, works, nrows: int, host_cols=None):
+def _device_phase(pf, rg: int, schema, works, nrows: int, host_cols=None,
+                  note=None):
     """DEVICE half: ship every column's control-plane arrays in ONE
     batched transfer (a transfer costs per call more than per byte), then
-    run the jitted expansion kernels."""
+    run the jitted expansion kernels. `note`, if given, is called with the
+    box (`_Tally`'s two counts) of each decode program executed."""
     import jax
     import jax.numpy as jnp
     from ..columnar.batch import ColumnarBatch
@@ -992,6 +1103,8 @@ def _device_phase(pf, rg: int, schema, works, nrows: int, host_cols=None):
         outs = program(np.int64(nrows), *_ship(flat))
         # one buffer per flat array + the nrows scalar + one program
         _note_dispatches(len(flat) + 2)
+        if note is not None:
+            note(program.msgs_box)
         for w, (data, validity) in zip(fused, outs):
             fused_cols[w.name] = Column(w.dt, data, validity)
 
@@ -1099,32 +1212,20 @@ def _expand_indices(page: _Page, dict_count: int):
     import jax.numpy as jnp
     if page.bw == 0 or page.payload is None:
         return jnp.zeros(page.ndef, jnp.uint32)
-    kinds, counts, values, bitoffs, packed = page.payload
-    idx = _expand_rle_u32(jnp.asarray(kinds), jnp.asarray(counts),
-                          jnp.asarray(values), jnp.asarray(bitoffs),
-                          jnp.asarray(packed), row_bucket(page.ndef),
-                          int(page.bw))[:page.ndef]
+    ends, table, words = _run_words([(page.payload, page.bw, page.ndef)])
+    idx = _expand_rle_u32(jnp.asarray(ends), jnp.asarray(table),
+                          jnp.asarray(words),
+                          row_bucket(page.ndef))[:page.ndef]
     return jnp.clip(idx, 0, max(dict_count - 1, 0))
 
 
-def _dict_segments(pages, dict_count: int):
-    """Consecutive equal-bit-width dict pages -> [(bw, ndef, runs|None)]
-    with runs the 5 merged numpy arrays (None for bw==0)."""
-    segs = []
-    for p in pages:
-        bw = 0 if p.payload is None else int(p.bw)
-        if segs and segs[-1][0] == bw:
-            segs[-1][1].append(p)
-        else:
-            segs.append((bw, [p]))
-    out = []
-    for bw, ps in segs:
-        ndef = sum(p.ndef for p in ps)
-        if ndef == 0:
-            continue
-        runs = _merge_runs([p.payload for p in ps]) if bw else None
-        out.append((bw, ndef, runs))
-    return out
+def _index_runs(pages):
+    """A chunk's dictionary-coded pages -> (the values they hold, their one
+    run table, `_run_words`, or None when they hold none)."""
+    n = sum(p.ndef for p in pages)
+    if not n:
+        return 0, None
+    return n, _run_words([(p.payload, p.bw, p.ndef) for p in pages])
 
 
 def _prep_fast_path(chunk: _Chunk, meta: dict, build_dict_vals,
@@ -1145,18 +1246,16 @@ def _prep_fast_path(chunk: _Chunk, meta: dict, build_dict_vals,
             not all(k == "plain" for k in kinds_seq[ndict:]):
         return None
     ship: List[np.ndarray] = []
-    meta.update({"segs": [], "dict_count": chunk.dict_count,
+    meta.update({"ndict": 0, "dict_count": chunk.dict_count,
                  "has_dict_vals": False, "has_plain": False})
     if ndict:
         if chunk.dict_raw is None or not chunk.dict_count:
             raise DeviceDecodeUnsupported("dict page missing values")
         ship.append(build_dict_vals(chunk))
         meta["has_dict_vals"] = True
-        for bw, ndef, runs in _dict_segments(chunk.pages[:ndict],
-                                             chunk.dict_count):
-            meta["segs"].append((bw, ndef, runs is not None))
-            if runs is not None:
-                ship.extend(_pad_runs(runs))
+        meta["ndict"], runs = _index_runs(chunk.pages[:ndict])
+        if runs is not None:
+            ship.extend(runs)
     plain_pages = [p for p in chunk.pages[ndict:] if p.ndef]
     if plain_pages:
         total = sum(p.ndef for p in plain_pages)
@@ -1240,36 +1339,40 @@ def _prep_flba(chunk: _Chunk, flen: int):
 # costing ~18 eager dispatches per column (the round-4 verdict's
 # "merge per-column programs into one jitted multi-column decode"). The
 # program is cached by structural signature; run tables pad to
-# power-of-two shapes (_pad_runs) so uniform row groups share one trace.
+# power-of-two shapes (_run_words) so uniform row groups share one trace.
 
 def _col_sig(w):
     m = w.meta
     return (w.spec.kind, w.phys, w.spec.post, w.spec.flen,
             w.defruns is not None, m["has_dict_vals"], m["dict_count"],
-            tuple(m["segs"]), m["has_plain"],
+            m["ndict"], m["has_plain"],
             str(w.dt.np_dtype) if w.spec.kind == "prim" else "",
             isinstance(w.dt, T.DateType))
 
 
-def _read_idx_traced(it, segs):
-    """Dictionary-index expansion over a column's segments (traced);
-    None when the column has no dictionary-coded values. Shared by the
-    full decode, the deferred string-span decode and the pushdown
-    predicate path — one copy of the segs/bit-width handling."""
-    import jax.numpy as jnp
-    idx_parts = []
-    for bw, ndef, has_runs in segs:
-        if not has_runs:
-            idx_parts.append(jnp.zeros(ndef, jnp.uint32))
-            continue
-        runs = [next(it) for _ in range(5)]
-        idx_parts.append(_expand_rle_u32(*runs, row_bucket(ndef), bw)[:ndef])
-    if not idx_parts:
+def _read_idx_traced(it, ndict: int, tally: _Tally):
+    """Dictionary-index expansion of a column chunk's one run table
+    (traced); None when the chunk has no dictionary-coded values. Shared by
+    the full decode, the deferred string-span decode and the pushdown
+    predicate path."""
+    if not ndict:
         return None
-    return idx_parts[0] if len(idx_parts) == 1 else jnp.concatenate(idx_parts)
+    ends, table, words = next(it), next(it), next(it)
+    tally.stacked += 2
+    return _expand_rle_u32(ends, table, words, row_bucket(ndict))[:ndict]
 
 
-def _traced_decode_col(colsig, cap: int, nrows, it):
+def _traced_defined(has_def: bool, cap: int, nrows, it, tally: _Tally):
+    """A column chunk's defined mask (traced): its def-level table
+    expanded, or, with none, every slot below the row count."""
+    import jax.numpy as jnp
+    if has_def:
+        tally.stacked += 2
+        return _expand_def_levels(next(it), next(it), next(it), cap)
+    return jnp.arange(cap) < nrows
+
+
+def _traced_decode_col(colsig, cap: int, nrows, it, tally: _Tally):
     """Decode ONE column (traced) from the ship-order array iterator `it`.
     Shared by the per-row-group fused program and the packed multi-chunk
     program. `colsig` is `_col_sig`'s tuple for prim/flba columns or
@@ -1277,44 +1380,43 @@ def _traced_decode_col(colsig, cap: int, nrows, it):
     (data, validity, lengths_or_None)."""
     import jax.numpy as jnp
     if colsig[0] == "string":
-        return _traced_decode_string(colsig, cap, nrows, it)
+        return _traced_decode_string(colsig, cap, nrows, it, tally)
     (kind, phys, post, flen, has_def, has_dict, dict_count,
-     segs, has_plain, np_dt_str, is_date) = colsig
-    if has_def:
-        runs = [next(it) for _ in range(5)]
-        defined = _expand_def_levels(*runs, cap)
-    else:
-        defined = jnp.arange(cap) < nrows
+     ndict, has_plain, np_dt_str, is_date) = colsig
+    defined = _traced_defined(has_def, cap, nrows, it, tally)
     is_bool = phys == "BOOLEAN"
     dict_vals = next(it) if has_dict else None
-    idx = _read_idx_traced(it, segs)
-    pieces = []
+    idx = _read_idx_traced(it, ndict, tally)
     if idx is not None:
         idx = jnp.clip(idx, 0, max(dict_count - 1, 0))
-        dv = dict_vals[idx]
+    if kind == "flba":
+        # the bytes become values before the dictionary gather: each
+        # dictionary entry is converted once, and values are gathered,
+        # not rows of bytes
+        parts = []
+        if idx is not None:
+            parts.append(_dict_gather(_flba_values(dict_vals, post, flen),
+                                      idx))
+        if has_plain:
+            parts.append(_flba_values(next(it), post, flen))
+        if not parts:
+            parts.append(_flba_values(jnp.zeros((0, flen), jnp.uint8),
+                                      post, flen))
+        outs = []
+        for comp in zip(*parts):
+            v = comp[0] if len(comp) == 1 else jnp.concatenate(comp)
+            if v.shape[0] < cap:
+                v = jnp.pad(v, (0, cap - v.shape[0]))
+            outs.append(_to_slots(v[:cap], defined, has_def, tally))
+        if len(outs) == 1:
+            return outs[0][0], outs[0][1], None
+        return jnp.stack([d for d, _ in outs], axis=1), outs[0][1], None
+    pieces = []
+    if idx is not None:
+        dv, = _dict_gather([dict_vals], idx)
         pieces.append(dv.astype(np.bool_) if is_bool else dv)
     if has_plain:
         pieces.append(next(it))
-    if kind == "flba":
-        if pieces:
-            mat = pieces[0] if len(pieces) == 1 \
-                else jnp.concatenate(pieces)
-        else:
-            mat = jnp.zeros((0, flen), jnp.uint8)
-        if mat.shape[0] < cap:
-            mat = jnp.pad(mat, ((0, cap - mat.shape[0]), (0, 0)))
-        mat = mat[:cap]
-        if post == "int96":
-            data, validity = _scatter_values(
-                _int96_to_micros(mat), defined)
-            return data, validity, None
-        hi, lo = _flba_to_limbs(mat, flen)
-        if post == "dec64":
-            data, validity = _scatter_values(lo, defined)
-            return data, validity, None
-        hi_s, validity = _scatter_values(hi, defined)
-        lo_s, _ = _scatter_values(lo, defined)
-        return jnp.stack([hi_s, lo_s], axis=1), validity, None
     np_dt = np.dtype(np_dt_str)
     if pieces:
         vals = pieces[0] if len(pieces) == 1 \
@@ -1324,7 +1426,7 @@ def _traced_decode_col(colsig, cap: int, nrows, it):
                          else np.dtype(_PHYS_TO_NP[phys]))
     if vals.shape[0] < cap:
         vals = jnp.pad(vals, (0, cap - vals.shape[0]))
-    data, validity = _scatter_values(vals[:cap], defined)
+    data, validity = _to_slots(vals[:cap], defined, has_def, tally)
     if is_date:
         data = data.astype(jnp.int32)
     elif data.dtype != np_dt:
@@ -1334,7 +1436,7 @@ def _traced_decode_col(colsig, cap: int, nrows, it):
     return data, validity, None
 
 
-def _traced_decode_string(colsig, cap: int, nrows, it):
+def _traced_decode_string(colsig, cap: int, nrows, it, tally: _Tally):
     """String fast path (traced): dictionary-index expansion gathers
     per-value (start, len) spans out of the dictionary span tables, the
     plain suffix's spans arrive host-scanned; one `_gather_strings` builds
@@ -1342,22 +1444,19 @@ def _traced_decode_string(colsig, cap: int, nrows, it):
     `_assemble_strings`, restricted to the dict-prefix + plain-suffix page
     layout the fast path accepts."""
     import jax.numpy as jnp
-    (_, has_def, has_dict, dict_count, segs, has_plain,
+    (_, has_def, has_dict, dict_count, ndict, has_plain,
      plain_ndef, width) = colsig
-    if has_def:
-        runs = [next(it) for _ in range(5)]
-        defined = _expand_def_levels(*runs, cap)
-    else:
-        defined = jnp.arange(cap) < nrows
+    defined = _traced_defined(has_def, cap, nrows, it, tally)
     st_parts, ln_parts = [], []
     if has_dict:
         dst = next(it)
         dln = next(it)
-        idx = _read_idx_traced(it, segs)
+        idx = _read_idx_traced(it, ndict, tally)
         if idx is not None:
             idx = jnp.clip(idx, 0, max(dict_count - 1, 0))
-            st_parts.append(dst[idx])
-            ln_parts.append(dln[idx])
+            st, ln = _dict_gather([dst, dln], idx)
+            st_parts.append(st)
+            ln_parts.append(ln)
     if has_plain:
         st_parts.append(next(it))
         ln_parts.append(next(it))
@@ -1373,8 +1472,10 @@ def _traced_decode_string(colsig, cap: int, nrows, it):
     if starts.shape[0] < cap:
         starts = jnp.pad(starts, (0, cap - starts.shape[0]))
         lens = jnp.pad(lens, (0, cap - lens.shape[0]))
+    if not has_def:
+        tally.elided += 2       # the spans' gathers by rank
     matrix, lengths = _gather_strings(blob, starts[:cap], lens[:cap],
-                                      defined, width)
+                                      defined, width, not has_def)
     return matrix, defined, lengths
 
 
@@ -1384,19 +1485,24 @@ def _fused_decode_program(sig_tuple, cap: int):
     Takes the (traced) logical row count plus the flat array list in
     _device_phase's ship order and returns (data, validity) per column.
     nrows rides as a traced scalar so varied tail-row-group sizes share
-    one compiled program per (signature, capacity bucket)."""
+    one compiled program per (signature, capacity bucket). Its box holds
+    the program's `_Tally`."""
+    box = [0, 0]
 
     def fn(nrows, *arrays):
         it = iter(arrays)
+        tally = _Tally()
         outs = []
         for colsig in sig_tuple:
-            data, validity, _ = _traced_decode_col(colsig, cap, nrows, it)
+            data, validity, _ = _traced_decode_col(colsig, cap, nrows, it,
+                                                   tally)
             outs.append((data, validity))
+        box[:] = [tally.stacked, tally.elided]
         return tuple(outs)
 
     from ..compile import sjit
     return sjit(fn, op="io.parquet.fused_decode",
-                key=repr((sig_tuple, cap)))
+                key=repr((sig_tuple, cap)), msgs_box=box)
 
 
 def _assemble_fixed(chunk: _Chunk, phys: str, dt, defined, cap: int,
@@ -1691,7 +1797,7 @@ def _assemble_long_strings(jnp, dt, blob, starts, lens, defined, cap: int):
 
 def _string_sig_from(meta: dict, w) -> tuple:
     return ("string", w.defruns is not None, meta["has_dict_vals"],
-            meta["dict_count"], tuple(meta["segs"]), meta["has_plain"],
+            meta["dict_count"], meta["ndict"], meta["has_plain"],
             meta["plain_ndef"], meta["width"])
 
 
@@ -1721,7 +1827,7 @@ def _prep_string(chunk: _Chunk):
     dict_base = base
     max_len = 1
     ship: List[np.ndarray] = []
-    meta = {"segs": [], "dict_count": chunk.dict_count,
+    meta = {"ndict": 0, "dict_count": chunk.dict_count,
             "has_dict_vals": False, "has_plain": False, "plain_ndef": 0}
     if ndict:
         if chunk.dict_raw is None or not chunk.dict_count:
@@ -1737,11 +1843,9 @@ def _prep_string(chunk: _Chunk):
         ship.append((dst + dict_base).astype(np.int64))
         ship.append(dln.astype(np.int32))
         meta["has_dict_vals"] = True
-        for bw, ndef, runs in _dict_segments(chunk.pages[:ndict],
-                                             chunk.dict_count):
-            meta["segs"].append((bw, ndef, runs is not None))
-            if runs is not None:
-                ship.extend(_pad_runs(runs))
+        meta["ndict"], runs = _index_runs(chunk.pages[:ndict])
+        if runs is not None:
+            ship.extend(runs)
     if plain_pages:
         st_parts, ln_parts = [], []
         for p, pb in zip(plain_pages, plain_bases):
@@ -1766,12 +1870,20 @@ def _prep_string(chunk: _Chunk):
     return ship, meta
 
 
+# Byte alignment of each array in the packed buffer. Arrays at odd offsets
+# cost the v5e compiler minutes: `star.q3`'s fact program over two
+# 524,288-row chunks took 471 s to compile with them, 15.5 s with every
+# array at a multiple of 512 (sandbox v5e compiler, PR 38).
+_PACK_ALIGN = 512
+
+
 def _pack_arrays(arrays: List[np.ndarray]):
     """Flatten heterogeneous host arrays into ONE contiguous uint8 buffer
-    (one H2D instead of one per array). Returns (packed uint8[n], metas)
-    where each meta is (dtype str, shape, byte offset) — static, so it
-    rides the program signature and the device side reconstructs each
-    array with slices + bitcasts."""
+    (one H2D instead of one per array), each at a multiple of
+    `_PACK_ALIGN` bytes. Returns (packed uint8[n], metas) where each meta
+    is (dtype str, shape, byte offset) — static, so it rides the program
+    signature and the device side reconstructs each array with slices +
+    bitcasts."""
     metas = []
     parts = []
     off = 0
@@ -1779,6 +1891,9 @@ def _pack_arrays(arrays: List[np.ndarray]):
         a = np.ascontiguousarray(a)
         raw = a.view(np.uint8).reshape(-1) if a.dtype != np.bool_ \
             else a.astype(np.uint8).reshape(-1)
+        if off % _PACK_ALIGN:
+            parts.append(np.zeros(_PACK_ALIGN - off % _PACK_ALIGN, np.uint8))
+            off += _PACK_ALIGN - off % _PACK_ALIGN
         metas.append((str(a.dtype), a.shape, off))
         parts.append(raw)
         off += raw.size
@@ -1825,55 +1940,86 @@ def _merged_slot_source(nrows_arr, caps, cap_total: int):
 
 
 @functools.lru_cache(maxsize=64)
-def _fused_multi_program(groups_sig, caps, cap_total: int):
+def _fused_multi_program(groups_sig, caps, cap_total: int, full_lead: bool):
     """One compiled program decoding SEVERAL row-group chunks and merging
     them into one batch. `groups_sig` is, per chunk, (per-column sig
     tuple, packed-array metas); `caps` the per-chunk capacity buckets.
     Takes (nrows int64[nchunks], packed uint8) — two buffers, one program:
     the whole dispatch group costs 3 dispatch events regardless of column
-    or chunk count. Chunk results merge by a rank gather: global row j
-    reads the slot `_merged_slot_source` gives it from the traced row
-    counts, so tail chunks of any size share the program. The program
-    holds no loop: every slot -> run and slot -> chunk map is a prefix
-    sum or a compare, never a search (tests/test_parquet_device.py holds
-    that line on the lowering)."""
+    or chunk count. `full_lead`: every chunk but the last fills its
+    capacity (the host read it in the footer), so global row j is slot j
+    of the chunks' outputs one after the other, and the merge cuts or pads
+    that to `cap_total` and gathers nothing. Otherwise row j reads the slot
+    `_merged_slot_source` gives it from the traced row counts, so tail
+    chunks of any size share the program, and every column's arrays move
+    by it together, in stacked word matrices (`rowops.gather_arrays`). The
+    program holds no loop: every slot -> run and slot -> chunk map is a
+    prefix sum or a compare, never a search (tests/test_parquet_device.py
+    holds that line on the lowering). Its box holds the program's
+    `_Tally`."""
     import jax.numpy as jnp
+    from jax import lax
+    from ..ops.rowops import GatherTally, gather_arrays
     nchunks = len(groups_sig)
     ncols = len(groups_sig[0][0])
+    box = [0, 0]
+
+    def fit(a):
+        n = a.shape[0]
+        if n >= cap_total:
+            return a[:cap_total]
+        return jnp.pad(a, ((0, cap_total - n),) + ((0, 0),) * (a.ndim - 1))
 
     def fn(nrows_arr, packed):
+        tally = _Tally()
         per_col = [[] for _ in range(ncols)]
         for c_i, (colsigs, metas) in enumerate(groups_sig):
-            arrays = [_unpack_traced(packed, m) for m in metas]
+            # one barrier over the chunk's arrays: left to fuse into the
+            # gathers that read them, one 512K-row int64 column took the
+            # v5e compiler 109 s where it takes 6 (sandbox, PR 38)
+            arrays = lax.optimization_barrier(
+                [_unpack_traced(packed, m) for m in metas])
             it = iter(arrays)
             for ci, colsig in enumerate(colsigs):
                 per_col[ci].append(_traced_decode_col(
-                    colsig, caps[c_i], nrows_arr[c_i], it))
-        src, live = _merged_slot_source(nrows_arr, caps, cap_total)
-        outs = []
+                    colsig, caps[c_i], nrows_arr[c_i], it, tally))
+        merged = []     # per column: data, valid and lens, if any
         for ci in range(ncols):
             datas = [d for d, _, _ in per_col[ci]]
-            valids = [v for _, v, _ in per_col[ci]]
-            lens = [l for _, _, l in per_col[ci]]
             if datas[0].ndim == 2:
                 w = max(d.shape[1] for d in datas)
                 datas = [jnp.pad(d, ((0, 0), (0, w - d.shape[1])))
                          if d.shape[1] < w else d for d in datas]
-            data = jnp.concatenate(datas) if nchunks > 1 else datas[0]
-            valid = jnp.concatenate(valids) if nchunks > 1 else valids[0]
-            gsrc = jnp.clip(src, 0, data.shape[0] - 1)
-            d = data[gsrc]
-            v = valid[gsrc] & live
-            if lens[0] is not None:
-                ln = jnp.concatenate(lens) if nchunks > 1 else lens[0]
-                outs.append((d, v, jnp.where(live, ln[gsrc], 0)))
-            else:
-                outs.append((d, v, None))
+            parts = [datas, [v for _, v, _ in per_col[ci]]]
+            if per_col[ci][0][2] is not None:
+                parts.append([l for _, _, l in per_col[ci]])
+            merged.append([jnp.concatenate(p) if nchunks > 1 else p[0]
+                           for p in parts])
+        flat = [a for col in merged for a in col]
+        if full_lead:
+            moved = [fit(a) for a in flat]
+            tally.elided += len(flat)
+        else:
+            src, _ = _merged_slot_source(nrows_arr, caps, cap_total)
+            gt = GatherTally()
+            moved = gather_arrays(
+                flat, jnp.clip(src, 0, flat[0].shape[0] - 1), gt)
+            tally.stacked += gt.matrices
+        live = jnp.arange(cap_total, dtype=jnp.int32) < \
+            jnp.sum(nrows_arr).astype(jnp.int32)
+        outs = []
+        it = iter(moved)
+        for col in merged:
+            d, v = next(it), next(it) & live
+            ln = jnp.where(live, next(it), 0) if len(col) == 3 else None
+            outs.append((d, v, ln))
+        box[:] = [tally.stacked, tally.elided]
         return tuple(outs)
 
     from ..compile import sjit
     return sjit(fn, op="io.parquet.fused_multi_decode",
-                key=repr((groups_sig, caps, cap_total)))
+                key=repr((groups_sig, caps, cap_total, full_lead)),
+                msgs_box=box)
 
 
 def _read_chunks(pf, f, rgs, schema, host_cols=None):
@@ -1941,7 +2087,7 @@ def _group_signatures(chunks, dev_names):
     return groups_sig, caps, packed, str_blob_offs
 
 
-def decode_row_groups_fused(pf, f, rgs, schema, host_cols=None):
+def decode_row_groups_fused(pf, f, rgs, schema, host_cols=None, note=None):
     """Decode SEVERAL row groups as one dispatch group -> list of
     (device ColumnarBatch, rows). When every device column of every chunk
     takes a fast-path prep (prim/flba ship or the string span-table prep)
@@ -1954,12 +2100,13 @@ def decode_row_groups_fused(pf, f, rgs, schema, host_cols=None):
     (malformed row groups, host-column read errors) raise
     DeviceDecodeUnsupported for the caller's pyarrow fallback.
     Host-fallback columns decode once via pyarrow's read_row_groups and
-    merge at the total capacity."""
+    merge at the total capacity. `note` as `_device_phase` takes it."""
     chunks, total = _read_chunks(pf, f, rgs, schema, host_cols)
-    return _decode_chunks_fused(pf, rgs, schema, chunks, total, host_cols)
+    return _decode_chunks_fused(pf, rgs, schema, chunks, total, host_cols,
+                                note)
 
 
-def _per_rg_batches(pf, schema, chunks, host_cols):
+def _per_rg_batches(pf, schema, chunks, host_cols, note=None):
     """Per-row-group decode from the SAME works — no second host
     phase. String works keep ship=None here, so `_device_phase`
     routes them through the eager assembles."""
@@ -1967,12 +2114,13 @@ def _per_rg_batches(pf, schema, chunks, host_cols):
     out = []
     for rg, works, nrows in chunks:
         out.append(_device_phase(pf, rg, schema, works, nrows,
-                                 host_cols))
+                                 host_cols, note))
         TaskMetrics.get().scan_chunks += 1
     return out
 
 
-def _decode_chunks_fused(pf, rgs, schema, chunks, total, host_cols=None):
+def _decode_chunks_fused(pf, rgs, schema, chunks, total, host_cols=None,
+                         note=None):
     """DEVICE half of decode_row_groups_fused over pre-read chunks."""
     import jax
     import jax.numpy as jnp
@@ -1983,19 +2131,23 @@ def _decode_chunks_fused(pf, rgs, schema, chunks, total, host_cols=None):
     host_set = set(host_cols or ())
     dev_names = [n for n in schema.names if n not in host_set]
     if not dev_names or total == 0:
-        return _per_rg_batches(pf, schema, chunks, host_cols)
+        return _per_rg_batches(pf, schema, chunks, host_cols, note)
     cap_total = row_bucket(total, op="scan.parquet")
 
     with spans.span("scan.pack", kind=spans.KIND_IO):
         sig = _group_signatures(chunks, dev_names)
     if sig is None:
-        return _per_rg_batches(pf, schema, chunks, host_cols)
+        return _per_rg_batches(pf, schema, chunks, host_cols, note)
     groups_sig, caps, packed, _ = sig
 
-    program = _fused_multi_program(groups_sig, tuple(caps), cap_total)
-    nrows_arr = np.asarray([n for _, _, n in chunks], np.int64)
-    outs = program(nrows_arr, _ship(packed))
+    nrows = [n for _, _, n in chunks]
+    full_lead = nrows[:-1] == caps[:-1]
+    program = _fused_multi_program(groups_sig, tuple(caps), cap_total,
+                                   full_lead)
+    outs = program(np.asarray(nrows, np.int64), _ship(packed))
     _note_dispatches(3)  # nrows buffer + packed buffer + one program
+    if note is not None:
+        note(program.msgs_box)
     TaskMetrics.get().scan_chunks += len(rgs)
 
     host_decoded = {}
@@ -2043,20 +2195,20 @@ def _decode_chunks_fused(pf, rgs, schema, chunks, total, host_cols=None):
 def _colsig_array_count(colsig) -> int:
     """How many packed arrays one column consumes in ship order."""
     if colsig[0] == "string":
-        (_, has_def, has_dict, _dc, segs, has_plain, _pn, _w) = colsig
-        n = 5 if has_def else 0
+        (_, has_def, has_dict, _dc, ndict, has_plain, _pn, _w) = colsig
+        n = 3 if has_def else 0
         if has_dict:
             n += 2
-        n += 5 * sum(1 for _, _, hr in segs if hr)
+        n += 3 if ndict else 0
         if has_plain:
             n += 2
         return n + 1  # + blob
-    (_kind, _phys, _post, _flen, has_def, has_dict, _dc, segs, has_plain,
+    (_kind, _phys, _post, _flen, has_def, has_dict, _dc, ndict, has_plain,
      _np_dt, _is_date) = colsig
-    n = 5 if has_def else 0
+    n = 3 if has_def else 0
     if has_dict:
         n += 1
-    n += 5 * sum(1 for _, _, hr in segs if hr)
+    n += 3 if ndict else 0
     if has_plain:
         n += 1
     return n
@@ -2069,7 +2221,7 @@ def _engine_values(colsig, arr):
     FLBA limb/INT96 conversion) so predicate evaluation sees exactly what
     the full decode would have produced."""
     import jax.numpy as jnp
-    (kind, _phys, post, flen, _hd, _hdict, _dc, _segs, _hp, np_dt_str,
+    (kind, _phys, post, flen, _hd, _hdict, _dc, _nd, _hp, np_dt_str,
      is_date) = colsig
     if kind == "flba":
         if post == "int96":
@@ -2104,7 +2256,8 @@ def _eval_pushed_leaf(expr, dt, data, lengths=None):
     return (res.data & res.validity).astype(jnp.bool_)
 
 
-def _dense_to_rows(pieces, cap: int, defined):
+def _dense_to_rows(pieces, cap: int, defined, has_def: bool,
+                   tally: _Tally):
     """Dense per-value bool verdicts -> per-row is-true (null rows false),
     the boolean analog of the value scatter."""
     import jax.numpy as jnp
@@ -2114,12 +2267,12 @@ def _dense_to_rows(pieces, cap: int, defined):
         dense = jnp.zeros(0, jnp.bool_)
     if dense.shape[0] < cap:
         dense = jnp.pad(dense, (0, cap - dense.shape[0]))
-    row_bool, _ = _scatter_values(dense[:cap], defined)
+    row_bool, _ = _to_slots(dense[:cap], defined, has_def, tally)
     return row_bool & defined
 
 
 def _traced_predicate_col(colsig, dt, cap: int, nrows, arrays, leaves,
-                          lit_w: int = 1):
+                          tally: _Tally, lit_w: int = 1):
     """Evaluate this column's pushed predicate leaves on the COMPRESSED
     representation: the dictionary is tested ONCE (per leaf) and the
     verdict gathered over the expanded indices; plain value streams are
@@ -2129,16 +2282,12 @@ def _traced_predicate_col(colsig, dt, cap: int, nrows, arrays, leaves,
     it = iter(arrays)
     is_string = colsig[0] == "string"
     if is_string:
-        (_, has_def, has_dict, dict_count, segs, has_plain, plain_ndef,
+        (_, has_def, has_dict, dict_count, ndict, has_plain, plain_ndef,
          _w) = colsig
     else:
-        (_kind, _phys, _post, _flen, has_def, has_dict, dict_count, segs,
+        (_kind, _phys, _post, _flen, has_def, has_dict, dict_count, ndict,
          has_plain, _np_dt, _is_date) = colsig
-    if has_def:
-        runs = [next(it) for _ in range(5)]
-        defined = _expand_def_levels(*runs, cap)
-    else:
-        defined = jnp.arange(cap) < nrows
+    defined = _traced_defined(has_def, cap, nrows, it, tally)
     out = {}
     if not leaves:
         return defined, out
@@ -2147,7 +2296,7 @@ def _traced_predicate_col(colsig, dt, cap: int, nrows, arrays, leaves,
         if has_dict:
             dst = next(it)
             dln = next(it)
-        idx = _read_idx_traced(it, segs)
+        idx = _read_idx_traced(it, ndict, tally)
         pst = pln = None
         if has_plain:
             pst = next(it)
@@ -2174,10 +2323,10 @@ def _traced_predicate_col(colsig, dt, cap: int, nrows, arrays, leaves,
             if plain_vals is not None:
                 pieces.append(_eval_pushed_leaf(expr, dt, plain_vals[0],
                                                 plain_vals[1]))
-            out[li] = _dense_to_rows(pieces, cap, defined)
+            out[li] = _dense_to_rows(pieces, cap, defined, has_def, tally)
         return defined, out
     dict_raw = next(it) if has_dict else None
-    idx = _read_idx_traced(it, segs)
+    idx = _read_idx_traced(it, ndict, tally)
     plain_raw = next(it) if has_plain else None
     dict_vals = _engine_values(colsig, dict_raw) \
         if dict_raw is not None and idx is not None else None
@@ -2189,28 +2338,24 @@ def _traced_predicate_col(colsig, dt, cap: int, nrows, arrays, leaves,
             pieces.append(verdict[jnp.clip(idx, 0, max(dict_count - 1, 0))])
         if plain_vals is not None:
             pieces.append(_eval_pushed_leaf(expr, dt, plain_vals))
-        out[li] = _dense_to_rows(pieces, cap, defined)
+        out[li] = _dense_to_rows(pieces, cap, defined, has_def, tally)
     return defined, out
 
 
-def _traced_string_spans(colsig, cap: int, nrows, it):
+def _traced_string_spans(colsig, cap: int, nrows, it, tally: _Tally):
     """Deferred string decode: per-ROW (start, len) spans + defined mask,
     WITHOUT the byte-matrix gather — the pushdown gather program runs that
     single big gather only over surviving rows, straight out of the packed
     buffer. Start offsets are blob-relative; the caller adds the blob's
     static byte offset inside the packed buffer."""
     import jax.numpy as jnp
-    (_, has_def, has_dict, dict_count, segs, has_plain, _pn, _w) = colsig
-    if has_def:
-        runs = [next(it) for _ in range(5)]
-        defined = _expand_def_levels(*runs, cap)
-    else:
-        defined = jnp.arange(cap) < nrows
+    (_, has_def, has_dict, dict_count, ndict, has_plain, _pn, _w) = colsig
+    defined = _traced_defined(has_def, cap, nrows, it, tally)
     st_parts, ln_parts = [], []
     if has_dict:
         dst = next(it)
         dln = next(it)
-        idx = _read_idx_traced(it, segs)
+        idx = _read_idx_traced(it, ndict, tally)
         if idx is not None:
             idxc = jnp.clip(idx, 0, max(dict_count - 1, 0))
             st_parts.append(dst[idxc])
@@ -2230,8 +2375,8 @@ def _traced_string_spans(colsig, cap: int, nrows, it):
     if starts.shape[0] < cap:
         starts = jnp.pad(starts, (0, cap - starts.shape[0]))
         lens = jnp.pad(lens, (0, cap - lens.shape[0]))
-    st_row, _ = _scatter_values(starts[:cap], defined)
-    ln_row, _ = _scatter_values(lens[:cap], defined)
+    st_row, _ = _to_slots(starts[:cap], defined, has_def, tally)
+    ln_row, _ = _to_slots(lens[:cap], defined, has_def, tally)
     return st_row, ln_row, defined
 
 
@@ -2323,6 +2468,7 @@ def _pushdown_select_program(groups_sig, caps, cap_total: int, dev,
     cap1 = row_bucket(1)
 
     def fn(nrows_arr, packed):
+        tally = _Tally()
         keeps = []
         chunk_vals = []   # per chunk: {col: (data, validity)}
         chunk_defs = []   # per chunk: {col: defined} (count-only columns)
@@ -2334,7 +2480,7 @@ def _pushdown_select_program(groups_sig, caps, cap_total: int, dev,
                 cs, arrs = cols[name]
                 d, lb = _traced_predicate_col(
                     cs, dt_by_name[name], caps[c_i], nrows_arr[c_i], arrs,
-                    tuple(leaves_by_col.get(name, ())),
+                    tuple(leaves_by_col.get(name, ())), tally,
                     str_w.get(name, 1))
                 defined_by[name] = d
                 leaf_bools.update(lb)
@@ -2349,7 +2495,7 @@ def _pushdown_select_program(groups_sig, caps, cap_total: int, dev,
                 for name in agg_full_cols:
                     cs, arrs = cols[name]
                     data, validity, _ = _traced_decode_col(
-                        cs, caps[c_i], nrows_arr[c_i], iter(arrs))
+                        cs, caps[c_i], nrows_arr[c_i], iter(arrs), tally)
                     vals[name] = (data, validity)
                 defs = {}
                 for name in agg_count_cols:
@@ -2359,7 +2505,7 @@ def _pushdown_select_program(groups_sig, caps, cap_total: int, dev,
                         cs, arrs = cols[name]
                         d, _ = _traced_predicate_col(
                             cs, dt_by_name[name], caps[c_i],
-                            nrows_arr[c_i], arrs, ())
+                            nrows_arr[c_i], arrs, (), tally)
                         defs[name] = d
                 chunk_vals.append(vals)
                 chunk_defs.append(defs)
@@ -2449,18 +2595,19 @@ def _pushdown_gather_program(groups_sig, caps, cap_total: int, out_cap: int,
         base = jnp.where(c_of > 0, cum[jnp.maximum(c_of - 1, 0)], 0)
         src_row = jnp.asarray(chunk_base)[c_of] + (sel - base)
         per_src = {n: [] for n in need}
+        tally = _Tally()
         for c_i, (colsigs, metas) in enumerate(groups_sig):
             cols = _col_array_slices(colsigs, metas, dev_names, packed)
             for name in need:
                 cs, arrs = cols[name]
                 if name in str_cols:
                     st, ln, d = _traced_string_spans(
-                        cs, caps[c_i], nrows_arr[c_i], iter(arrs))
+                        cs, caps[c_i], nrows_arr[c_i], iter(arrs), tally)
                     per_src[name].append(
                         (st + blob_offs[(c_i, name)], ln, d))
                 else:
                     data, validity, _ = _traced_decode_col(
-                        cs, caps[c_i], nrows_arr[c_i], iter(arrs))
+                        cs, caps[c_i], nrows_arr[c_i], iter(arrs), tally)
                     per_src[name].append((data, validity))
         merged = {}
         for name in need:

@@ -200,6 +200,15 @@ class TpuFileScanExec(_TpuExec):
                                                        M.MODERATE)
             self.orc_varint_bytes = self.metrics.create("orcVarintBytes",
                                                         M.MODERATE)
+        if plan.format_name == "parquet":
+            # the parquet decode programs' per-slot gathers, per executed
+            # dispatch group: stacked gathers lowered into them, and
+            # gathers by an index known to be the slot itself not made
+            # (`parquet_device._Tally`)
+            self.parquet_stacked_gathers = self.metrics.create(
+                "parquetStackedGathers", M.MODERATE)
+            self.parquet_gathers_elided = self.metrics.create(
+                "parquetGathersElided", M.MODERATE)
         # decode/read wall time per produced batch (host or device path)
         self.read_time = self.metrics.create(M.READ_TIME, M.MODERATE)
 
@@ -673,6 +682,11 @@ class TpuFileScanExec(_TpuExec):
             telemetry.inc("tpu_scan_rowgroups_pruned_total", n)
         return kept
 
+    def _note_decode(self, box) -> None:
+        """`note` of the parquet decode: one executed program's box."""
+        self.parquet_stacked_gathers.add(box[0])
+        self.parquet_gathers_elided.add(box[1])
+
     def _decode_rgs_pipelined(self, pf, path, rgs, host_cols, scan,
                               scan_names):
         """Stream row groups, one dispatch group live at a time. With
@@ -730,7 +744,8 @@ class TpuFileScanExec(_TpuExec):
                 elif len(chunk_rgs) > 1:
                     try:
                         outs = decode_row_groups_fused(
-                            pf, f, chunk_rgs, scan.output, host_cols)
+                            pf, f, chunk_rgs, scan.output, host_cols,
+                            self._note_decode)
                     except (DeviceDecodeUnsupported, OSError,
                             struct_error):
                         pass  # per-row-group decode below
@@ -746,7 +761,8 @@ class TpuFileScanExec(_TpuExec):
                             works, nrows = _host_phase(
                                 pf, f, rg, scan.output, host_cols)
                         b, nrows = _device_phase(pf, rg, scan.output,
-                                                 works, nrows, host_cols)
+                                                 works, nrows, host_cols,
+                                                 self._note_decode)
                         tm.scan_batches += 1
                     except (DeviceDecodeUnsupported, OSError,
                             struct_error):

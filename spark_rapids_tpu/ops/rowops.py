@@ -224,6 +224,51 @@ def gather_vecs(xp, vecs: Sequence[Vec], idx,
             for v in vecs]
 
 
+def gather_arrays(arrays: Sequence, idx,
+                  tally: Optional[GatherTally] = None) -> List:
+    """Device arrays of one leading length gathered by one index vector,
+    moved together as `gather_vecs` moves a batch's columns (`_RowMover`)."""
+    mover = _RowMover(idx, tally)
+    for a in arrays:
+        mover.add(a)
+    mover.move()
+    return [mover.got(a) for a in arrays]
+
+
+# The v5e compiler gathers out of a table of under ~2 MB another way, with
+# 1 GB of temporaries for 2,097,152 indices, and that way costs by the row:
+# 37.9 ms for the date column's expansion, whose 7-row table has 8,192 runs,
+# against 18.0 with the table zero-padded to 524,288, and 37.8 against 22.0
+# for the 8 MB varint stream's two rows of 262,144; but two rows of 65,536 or
+# 131,072 are 2.5 ms cheaper left short (sandbox v5e compiler and my chip
+# runs, PERF.md, PR 37). So a short table is padded, on the device, to this
+# many columns before a long gather unless it is two rows of under a megabyte.
+_GATHER_MIN_COLS = 1 << 19
+_GATHER_SHORT_OK = (2, 1 << 17)      # at most (rows, columns)
+
+
+def gather_rows(rows, idx):
+    """uint32[K, len(idx)]: K <= 8 uint32 rows of one length (a list of
+    them, or a (K, n) matrix) gathered along it by one index vector,
+    stacked so that the chip pays per index and not per row
+    (`ops/rowops.PACK_ROWS`; PERF.md price list)."""
+    import jax.numpy as jnp
+    m = jnp.stack(rows) if isinstance(rows, (list, tuple)) else rows
+    assert m.shape[0] <= PACK_ROWS, m.shape
+    k, n = m.shape
+    short = min(_GATHER_MIN_COLS, idx.shape[0]) - n
+    if short > 0 and not (k <= _GATHER_SHORT_OK[0]
+                          and n <= _GATHER_SHORT_OK[1]):
+        m = jnp.pad(m, ((0, 0), (0, short)))
+    return m[:, jnp.clip(idx, 0, n - 1)]
+
+
+def ahead(words, k: int):
+    """`words` read `k` places ahead (zeros past the end)."""
+    import jax.numpy as jnp
+    return jnp.concatenate([words[k:], jnp.zeros(k, words.dtype)])
+
+
 def stable_lexsort(xp, keys: Sequence):
     """Stable sort permutation over `keys`, MOST-significant first.
 

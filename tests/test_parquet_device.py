@@ -489,7 +489,7 @@ class TestDecimalTimestampDeviceDecode:
 
 
 def _run_table_cases():
-    """(id, counts, cap) for `_run_of_slot`: the run tables a parquet chunk
+    """(id, counts, cap) for `_slot_runs`: the run tables a parquet chunk
     hands the decoder, and the ones it must not trip over."""
     r = np.random.default_rng(30)
     big = r.integers(0, 40, 65536)
@@ -526,18 +526,18 @@ class TestRunOfSlot:
         "counts,cap", [c[1:] for c in _RUN_TABLES],
         ids=[c[0] for c in _RUN_TABLES])
     def test_equals_searchsorted(self, counts, cap, dtype):
+        """`dtype` is the ends' (the host ships int32, ORC's tables too)."""
         import jax
         import jax.numpy as jnp
-        from spark_rapids_tpu.io.parquet_device import _run_of_slot
-        counts = np.asarray(counts, dtype)
-        run, ends = jax.jit(_run_of_slot, static_argnums=1)(
-            jnp.asarray(counts), cap)
-        want_ends = np.cumsum(counts.astype(np.int64))
+        from spark_rapids_tpu.io.parquet_device import _slot_runs
+        counts = np.asarray(counts, np.int64)
+        want_ends = np.cumsum(counts)
+        run = jax.jit(_slot_runs, static_argnums=1)(
+            jnp.asarray(want_ends.astype(dtype)), cap)
         want = np.clip(np.searchsorted(want_ends, np.arange(cap),
                                        side="right"), 0, len(counts) - 1)
-        assert run.dtype == jnp.int32 and ends.dtype == jnp.int32
+        assert run.dtype == jnp.int32
         assert run.shape == (cap,)
-        assert np.array_equal(np.asarray(ends), want_ends)
         assert np.array_equal(np.asarray(run), want)
         # and it is jnp.searchsorted's own answer too, not only numpy's
         got = jnp.clip(jnp.searchsorted(jnp.cumsum(jnp.asarray(counts)),
@@ -612,14 +612,14 @@ class TestRunOfSlot:
             chunks, total = pd._read_chunks(pf, f, [0, 1], schema)
         groups_sig, caps, packed, _ = pd._group_signatures(
             chunks, list(schema.names))
-        widths = {bw for colsigs, _ in groups_sig for cs in colsigs
-                  for bw, _, has_runs in (cs[4] if cs[0] == "string"
-                                          else cs[7]) if has_runs}
+        widths = {p.bw for _, works, _ in chunks for w in works.values()
+                  for p in w.chunk.pages if p.payload is not None
+                  and p.kind == "dict"}
         assert len(widths) > 1, widths      # the file is what it says
         assert any(cs[1] if cs[0] == "string" else cs[4]
                    for cs in groups_sig[0][0])      # def levels present
         program = pd._fused_multi_program(
-            groups_sig, tuple(caps), pd.row_bucket(total))
+            groups_sig, tuple(caps), pd.row_bucket(total), False)
         nrows = np.asarray([n for _, _, n in chunks], np.int64)
         text = program.direct.lower(nrows, packed).as_text()
         assert "stablehlo.while" not in text
@@ -657,3 +657,339 @@ class TestRunOfSlot:
             got = [int(d) if v else None for d, v in
                    zip(np.asarray(data)[:nrows], np.asarray(validity)[:nrows])]
             assert got == want, w.name
+
+
+# -- one run table per column chunk (PR 38) -----------------------------------
+
+def _varint(x: int) -> bytes:
+    out = bytearray()
+    while True:
+        b, x = x & 0x7F, x >> 7
+        out.append(b | 0x80 if x else b)
+        if not x:
+            return bytes(out)
+
+
+def _hybrid(values, width: int, rng, empty_runs: bool) -> bytes:
+    """Parquet's RLE/bit-packed hybrid encoding of `values`: eight or more
+    equal values as a repeated run, the rest as bit-packed groups of eight,
+    LSB first, 1-5 groups a run, the last group padded; `empty_runs` writes
+    a repeated run of no values after every repeated run."""
+    vbytes = (width + 7) // 8
+    out = bytearray()
+    i, n = 0, len(values)
+    while i < n:
+        j = i
+        while j < n and values[j] == values[i]:
+            j += 1
+        if j - i >= 8:
+            out += _varint((j - i) << 1)
+            out += int(values[i]).to_bytes(vbytes, "little")
+            if empty_runs:
+                out += _varint(0) + bytes(vbytes)
+            i = j
+            continue
+        m = min(8 * int(rng.integers(1, 6)), n - i)
+        groups = -(-m // 8)
+        acc = 0
+        for k, v in enumerate(values[i:i + m]):
+            acc |= int(v) << (k * width)
+        out += _varint(groups << 1 | 1)
+        out += acc.to_bytes(groups * width, "little")
+        i += m
+    return bytes(out)
+
+
+def _hybrid_values(rng, n: int, width: int):
+    """Repeats of 8-40 values between stretches of 1-59 random ones, every
+    value under 2**width, the largest among them."""
+    top = (1 << width) - 1
+    parts, total = [], 0
+    while total < n:
+        if rng.random() < 0.4:
+            parts.append(np.full(int(rng.integers(8, 41)),
+                                 rng.integers(0, top, endpoint=True),
+                                 np.uint64))
+        else:
+            parts.append(rng.integers(0, top, int(rng.integers(1, 60)),
+                                      endpoint=True, dtype=np.uint64))
+        total += len(parts[-1])
+    out = np.concatenate(parts)[:n]
+    out[n // 2] = top
+    return out
+
+
+def _runs_of(stream: bytes, n: int, width: int, walk: str, monkeypatch):
+    """`_rle_runs` over `stream` by the native scan or the python loop."""
+    from spark_rapids_tpu.io.parquet_device import _rle_runs
+    from spark_rapids_tpu.native import runtime as R
+    if walk == "native":
+        if not R.available():
+            pytest.skip("native lib not built")
+    else:
+        R.available()
+        monkeypatch.setattr(R, "_LIB", None)
+    return _rle_runs(memoryview(stream), n, width)
+
+
+def _expand_host(parts):
+    """The values of `_run_words`' parts, decoded on the host."""
+    out = []
+    for runs, width, n in parts:
+        if runs is None:
+            out.append(np.zeros(n, np.uint64))
+            continue
+        kinds, counts, values, bitoffs, packed = runs
+        bits = np.unpackbits(np.asarray(packed), bitorder="little")
+        for k, c, v, bo in zip(kinds, counts, values, bitoffs):
+            c = int(c)
+            if k == 0:
+                out.append(np.full(c, int(v), np.uint64))
+            else:
+                sl = bits[bo:bo + c * width].reshape(c, width)
+                out.append((sl.astype(np.uint64) << np.arange(
+                    width, dtype=np.uint64)).sum(axis=1, dtype=np.uint64))
+    return np.concatenate(out) if out else np.zeros(0, np.uint64)
+
+
+class TestRunTables:
+    """`_run_words` + `_unpack_runs`: a column chunk's dictionary-index or
+    def-level stream, pages of any bit widths in one table, expanded by two
+    stacked gathers a slot; against the values that were encoded."""
+
+    @pytest.mark.parametrize("walk", ["native", "python"])
+    @pytest.mark.parametrize("width", range(1, 33))
+    def test_every_width_equals_the_encoded_values(self, width, walk,
+                                                   monkeypatch):
+        from spark_rapids_tpu.io import parquet_device as P
+        r = np.random.default_rng(width)
+        n = 3001
+        want = _hybrid_values(r, n, width)
+        stream = _hybrid(want, width, r, empty_runs=width % 3 == 0)
+        runs = _runs_of(stream, n, width, walk, monkeypatch)
+        assert set(np.asarray(runs[0]).tolist()) == {0, 1}  # both kinds
+        ends, table, words = P._run_words([(runs, width, n)])
+        cap = P.row_bucket(n)
+        got = np.asarray(P._expand_rle_u32(ends, table, words, cap))
+        assert np.array_equal(got[:n], want.astype(np.uint32))
+        assert not got[n:].any()
+        if width == 1:
+            d = np.asarray(P._expand_def_levels(ends, table, words, cap))
+            assert np.array_equal(d[:n], want == 1) and not d[n:].any()
+
+    @pytest.mark.parametrize("layout", [
+        "widths_grow_mid_chunk", "width_falls_back", "one_entry_pages",
+        "empty_pages_and_empty_runs", "power_of_two_runs"])
+    def test_pages_of_many_widths_share_one_table(self, layout, rng,
+                                                  monkeypatch):
+        """A chunk's pages as `_index_runs` hands them over: widths that
+        change page by page, width-0 pages (a one-entry dictionary), pages
+        and runs of no values, and a run count that needs no padding."""
+        from spark_rapids_tpu.io import parquet_device as P
+        spec = {"widths_grow_mid_chunk": [(3, 700), (5, 900), (9, 1200),
+                                          (17, 2000), (20, 333)],
+                "width_falls_back": [(12, 800), (2, 800), (32, 300)],
+                "one_entry_pages": [(0, 500), (4, 64), (0, 7), (0, 1000)],
+                "empty_pages_and_empty_runs": [(6, 0), (6, 600), (0, 0),
+                                               (11, 431), (6, 0)],
+                "power_of_two_runs": [(1, 8 * 64)]}[layout]
+        parts, want = [], []
+        for width, n in spec:
+            if width == 0 or n == 0:
+                parts.append((None, width, n))
+                want.append(np.zeros(n, np.uint64))
+                continue
+            vals = _hybrid_values(rng, n, width)
+            if layout == "power_of_two_runs":
+                vals = rng.integers(0, 2, n, dtype=np.uint64)  # 64 groups
+            stream = _hybrid(vals, width, rng, empty_runs=True)
+            parts.append((_runs_of(stream, n, width, "native", monkeypatch),
+                          width, n))
+            want.append(vals)
+        want = np.concatenate(want)
+        assert np.array_equal(_expand_host(parts), want)
+        ends, table, words = P._run_words(parts)
+        runs = sum(len(p[0][0]) if p[0] is not None else int(p[2] > 0)
+                   for p in parts)
+        assert ends.shape[0] == P._pow2(runs) and table.shape == (3, P._pow2(
+            runs))
+        cap = P.row_bucket(len(want))
+        got = np.asarray(P._expand_rle_u32(ends, table, words, cap))
+        assert np.array_equal(got[:len(want)], want.astype(np.uint32))
+        assert not got[len(want):].any()
+
+    def test_bits_past_int32_are_refused(self):
+        from spark_rapids_tpu.io import parquet_device as P
+        runs = (np.ones(1, np.uint8), np.full(1, 8, np.int64),
+                np.zeros(1, np.uint32), np.full(1, 2 ** 31 - 8, np.int64),
+                np.zeros(8, np.uint8))
+        P._run_words([(runs, 8, 8)])     # bits by offset: the bytes count
+        with pytest.raises(P.DeviceDecodeUnsupported):
+            P._run_words([((np.zeros(1, np.uint8), np.full(1, 2 ** 31,
+                                                           np.int64),
+                            np.zeros(1, np.uint32), np.zeros(1, np.int64),
+                            np.zeros(1, np.uint8)), 1, 2 ** 31)])
+
+
+def _scan_metrics(session):
+    def find(node):
+        if node.name == "TpuFileScanExec(parquet)":
+            return node
+        for c in node.children:
+            hit = find(c)
+            if hit is not None:
+                return hit
+        return None
+    return find(session.last_plan).metrics.snapshot()
+
+
+class TestAgainstPyarrow:
+    """The whole decode of dictionary-coded chunks against pyarrow's: bit
+    widths as dictionaries of 2 to 70,000 values give them, required and
+    nullable columns with and without nulls, widths that change mid-chunk,
+    and dispatch groups whose leading row groups do or do not fill their
+    capacity."""
+
+    @pytest.mark.parametrize("nulls", ["required", "no_nulls", "with_nulls"])
+    @pytest.mark.parametrize("distinct", [2, 3, 100, 5000, 70000])
+    def test_dictionary_widths(self, session, tmp_path, distinct, nulls):
+        from spark_rapids_tpu.io import parquet_device as pd
+        r = np.random.default_rng(distinct)
+        rows = max(2 * distinct, 20000)
+        vals = r.integers(0, distinct, rows)
+        vals[:distinct] = r.permutation(distinct)
+        mask = r.random(rows) < 0.1 if nulls == "with_nulls" else None
+        field = pa.field("k", pa.int64(), nullable=nulls != "required")
+        t = pa.table({"k": pa.array(vals * 3 - 7, mask=mask)},
+                     schema=pa.schema([field]))
+        path = str(tmp_path / "w.parquet")
+        pq.write_table(t, path, use_dictionary=True, row_group_size=rows)
+        df = session.read_parquet(path)
+        got = df.collect()
+        assert got.column("k").to_pylist() == \
+            pq.read_table(path).column("k").to_pylist()
+        pf = pd.file_supported(path, df.plan.output)
+        with open(path, "rb") as f:
+            works, _ = pd._host_phase(pf, f, 0, df.plan.output)
+        w = works["k"]
+        assert max(p.bw for p in w.chunk.pages) == \
+            max((distinct - 1).bit_length(), 1)
+        assert (w.defruns is not None) == (nulls == "with_nulls")
+        snap = _scan_metrics(session)
+        assert snap["parquetStackedGathers"] == \
+            2 * (1 + (nulls == "with_nulls"))
+        assert snap["parquetGathersElided"] == (nulls != "with_nulls")
+
+    @pytest.mark.parametrize("page_size", [256, 512, 4096])
+    def test_widths_change_mid_chunk(self, session, tmp_path, page_size):
+        r = np.random.default_rng(page_size)
+        n = 12000
+        grow = np.minimum(np.arange(n), r.integers(0, n, n))
+        t = pa.table({"k": pa.array(grow * 5, mask=r.random(n) < 0.05),
+                      "s": pa.array(["v%d" % (g % 997) for g in grow])})
+        path = str(tmp_path / "grow.parquet")
+        pq.write_table(t, path, use_dictionary=True, row_group_size=n,
+                       data_page_size=page_size)
+        pf = pq.ParquetFile(path)
+        enc = pf.metadata.row_group(0).column(0).encodings
+        assert "PLAIN_DICTIONARY" in enc or "RLE_DICTIONARY" in enc
+        got = session.read_parquet(path).collect()
+        want = pq.read_table(path)
+        for name in ("k", "s"):
+            assert got.column(name).to_pylist() == \
+                want.column(name).to_pylist(), name
+
+    @staticmethod
+    def _group_file(tmp_path, lead):
+        """Three row groups, the first two full of their 4,096-row bucket
+        or short of it; both columns hold nulls."""
+        r = np.random.default_rng(4096)
+        rg = 4096 if lead == "full" else 3000
+        n = 2 * rg + 300
+        t = pa.table({
+            "k": pa.array(r.integers(0, 900, n), mask=r.random(n) < 0.1),
+            "s": pa.array(["w%d" % (i % 37) for i in range(n)],
+                          mask=r.random(n) < 0.1)})
+        path = str(tmp_path / f"{lead}.parquet")
+        pq.write_table(t, path, use_dictionary=True, row_group_size=rg)
+        return path, t
+
+    @pytest.mark.parametrize("lead", ["full", "short"])
+    def test_merge_of_a_dispatch_group(self, session, tmp_path, lead):
+        """Full leading row groups merge by concatenating, with no gather;
+        a short one keeps the merge's gather, in stacked matrices."""
+        path, t = self._group_file(tmp_path, lead)
+        got = session.read_parquet(path).collect()
+        for name in t.schema.names:
+            assert got.column(name).to_pylist() == \
+                t.column(name).to_pylist(), name
+        snap = _scan_metrics(session)
+        tables = 3 * 2 * 2      # row groups x columns x (def, index)
+        if lead == "full":
+            assert snap["parquetStackedGathers"] == 2 * tables
+            assert snap["parquetGathersElided"] == 2 + 3    # k; s + lengths
+        else:
+            assert snap["parquetStackedGathers"] > 2 * tables
+            assert snap["parquetGathersElided"] == 0
+
+    def test_counts_come_back_with_a_cached_program(self, session,
+                                                    tmp_path):
+        """The counts are taken while the program is traced; a program the
+        compile service hands back from its cache brings them along."""
+        from spark_rapids_tpu.io import parquet_device as pd
+        path, _ = self._group_file(tmp_path, "full")
+        df = session.read_parquet(path)
+        df.collect()
+        first = _scan_metrics(session)
+        pd._fused_multi_program.cache_clear()   # a fresh, untraced box
+        df.collect()
+        again = _scan_metrics(session)
+        for name in ("parquetStackedGathers", "parquetGathersElided"):
+            assert again[name] == first[name] > 0, name
+
+    def test_full_chunks_lower_no_merge_gather(self, session, tmp_path):
+        """Two full 1,048,576-row chunks: the program's gathers are two a
+        run table, the dictionaries' and the null scatters' rank gathers,
+        and nothing for the merge; and no loop."""
+        import jax
+        from spark_rapids_tpu.io import parquet_device as pd
+        n = 1 << 20
+        r = np.random.default_rng(38)
+        t = pa.table({
+            "k": pa.array(r.integers(0, 5000, 2 * n),
+                          mask=r.random(2 * n) < 0.05),
+            "q": pa.array(r.integers(1, 51, 2 * n).astype(np.int32))})
+        path = str(tmp_path / "full.parquet")
+        pq.write_table(t, path, use_dictionary=True, row_group_size=n)
+        df = session.read_parquet(path)
+        schema = df.plan.output
+        pf = pd.file_supported(path, schema)
+        with open(path, "rb") as f:
+            chunks, total = pd._read_chunks(pf, f, [0, 1], schema)
+        groups_sig, caps, packed, _ = pd._group_signatures(
+            chunks, list(schema.names))
+        assert caps == [n, n] and [c[2] for c in chunks] == [n, n]
+        program = pd._fused_multi_program(
+            groups_sig, tuple(caps), pd.row_bucket(total), True)
+        nrows = np.asarray([n, n], np.int64)
+        tables = dicts = ranks = 0
+        for colsigs, _ in groups_sig:
+            for cs in colsigs:
+                has_def, ndict = cs[4], cs[7]
+                tables += has_def + (ndict > 0)
+                dicts += ndict > 0
+                ranks += has_def
+        assert (tables, dicts, ranks) == (6, 4, 2)  # `q`: no def table
+
+        def gathers(jaxpr):
+            found = 0
+            for eqn in jaxpr.eqns:
+                found += eqn.primitive.name == "gather"
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    found += gathers(sub)
+            return found
+        jaxpr = jax.make_jaxpr(program.direct)(nrows, packed)
+        assert gathers(jaxpr.jaxpr) == 2 * tables + dicts + ranks
+        assert program.msgs_box == [2 * tables, 2 + 2 + 2]   # merge; `q`
+        text = program.direct.lower(nrows, packed).as_text()
+        assert "stablehlo.while" not in text
