@@ -1870,10 +1870,11 @@ def _prep_string(chunk: _Chunk):
     return ship, meta
 
 
-# Byte alignment of each array in the packed buffer. Arrays at odd offsets
-# cost the v5e compiler minutes: `star.q3`'s fact program over two
-# 524,288-row chunks took 471 s to compile with them, 15.5 s with every
-# array at a multiple of 512 (sandbox v5e compiler, PR 38).
+# Byte alignment of each array in the packed buffer. Sliced out at odd
+# offsets inside the decode program, they cost the v5e compiler minutes:
+# `star.q3`'s fact program over two 524,288-row chunks took 471 s to
+# compile, 15.5 s with every array at a multiple of 512 (sandbox v5e
+# compiler, PR 38; the program now takes them from `_unpack_program`).
 _PACK_ALIGN = 512
 
 
@@ -1940,13 +1941,28 @@ def _merged_slot_source(nrows_arr, caps, cap_total: int):
 
 
 @functools.lru_cache(maxsize=64)
+def _unpack_program(metas):
+    """The packed transfer buffer -> its arrays (`_unpack_traced`), as a
+    program of its own: a copy of the buffer on the device, and the decode
+    program then takes the arrays as arguments."""
+
+    def fn(packed):
+        return tuple(_unpack_traced(packed, m) for m in metas)
+
+    from ..compile import sjit
+    return sjit(fn, op="io.parquet.unpack", key=repr(metas))
+
+
+@functools.lru_cache(maxsize=64)
 def _fused_multi_program(groups_sig, caps, cap_total: int, full_lead: bool):
     """One compiled program decoding SEVERAL row-group chunks and merging
     them into one batch. `groups_sig` is, per chunk, (per-column sig
     tuple, packed-array metas); `caps` the per-chunk capacity buckets.
-    Takes (nrows int64[nchunks], packed uint8) — two buffers, one program:
-    the whole dispatch group costs 3 dispatch events regardless of column
-    or chunk count. `full_lead`: every chunk but the last fills its
+    Takes (nrows int64[nchunks], *arrays), the chunks' arrays in ship
+    order as `_unpack_program` cuts them out of the one packed transfer:
+    sliced out of it inside this program, the cells' fact program took
+    the v5e compiler 770 s at two 1,048,576-row chunks, 20 s as arguments
+    (sandbox v5e compiler, PR 38). `full_lead`: every chunk but the last fills its
     capacity (the host read it in the footer), so global row j is slot j
     of the chunks' outputs one after the other, and the merge cuts or pads
     that to `cap_total` and gathers nothing. Otherwise row j reads the slot
@@ -1958,7 +1974,6 @@ def _fused_multi_program(groups_sig, caps, cap_total: int, full_lead: bool):
     holds that line on the lowering). Its box holds the program's
     `_Tally`."""
     import jax.numpy as jnp
-    from jax import lax
     from ..ops.rowops import GatherTally, gather_arrays
     nchunks = len(groups_sig)
     ncols = len(groups_sig[0][0])
@@ -1970,16 +1985,11 @@ def _fused_multi_program(groups_sig, caps, cap_total: int, full_lead: bool):
             return a[:cap_total]
         return jnp.pad(a, ((0, cap_total - n),) + ((0, 0),) * (a.ndim - 1))
 
-    def fn(nrows_arr, packed):
+    def fn(nrows_arr, *arrays):
         tally = _Tally()
         per_col = [[] for _ in range(ncols)]
+        it = iter(arrays)
         for c_i, (colsigs, metas) in enumerate(groups_sig):
-            # one barrier over the chunk's arrays: left to fuse into the
-            # gathers that read them, one 512K-row int64 column took the
-            # v5e compiler 109 s where it takes 6 (sandbox, PR 38)
-            arrays = lax.optimization_barrier(
-                [_unpack_traced(packed, m) for m in metas])
-            it = iter(arrays)
             for ci, colsig in enumerate(colsigs):
                 per_col[ci].append(_traced_decode_col(
                     colsig, caps[c_i], nrows_arr[c_i], it, tally))
@@ -2142,10 +2152,12 @@ def _decode_chunks_fused(pf, rgs, schema, chunks, total, host_cols=None,
 
     nrows = [n for _, _, n in chunks]
     full_lead = nrows[:-1] == caps[:-1]
+    arrays = _unpack_program(tuple(m for _, ms in groups_sig for m in ms))(
+        _ship(packed))
     program = _fused_multi_program(groups_sig, tuple(caps), cap_total,
                                    full_lead)
-    outs = program(np.asarray(nrows, np.int64), _ship(packed))
-    _note_dispatches(3)  # nrows buffer + packed buffer + one program
+    outs = program(np.asarray(nrows, np.int64), *arrays)
+    _note_dispatches(4)  # nrows + packed buffers, unpack + decode programs
     if note is not None:
         note(program.msgs_box)
     TaskMetrics.get().scan_chunks += len(rgs)

@@ -612,6 +612,8 @@ class TestRunOfSlot:
             chunks, total = pd._read_chunks(pf, f, [0, 1], schema)
         groups_sig, caps, packed, _ = pd._group_signatures(
             chunks, list(schema.names))
+        arrays = pd._unpack_program(
+            tuple(m for _, ms in groups_sig for m in ms))(packed)
         widths = {p.bw for _, works, _ in chunks for w in works.values()
                   for p in w.chunk.pages if p.payload is not None
                   and p.kind == "dict"}
@@ -621,7 +623,7 @@ class TestRunOfSlot:
         program = pd._fused_multi_program(
             groups_sig, tuple(caps), pd.row_bucket(total), False)
         nrows = np.asarray([n for _, _, n in chunks], np.int64)
-        text = program.direct.lower(nrows, packed).as_text()
+        text = program.direct.lower(nrows, *arrays).as_text()
         assert "stablehlo.while" not in text
         assert "stablehlo.scatter" in text      # the marks, one per table
         # and the batch it decodes is still pyarrow's
@@ -968,6 +970,8 @@ class TestAgainstPyarrow:
             chunks, total = pd._read_chunks(pf, f, [0, 1], schema)
         groups_sig, caps, packed, _ = pd._group_signatures(
             chunks, list(schema.names))
+        arrays = pd._unpack_program(
+            tuple(m for _, ms in groups_sig for m in ms))(packed)
         assert caps == [n, n] and [c[2] for c in chunks] == [n, n]
         program = pd._fused_multi_program(
             groups_sig, tuple(caps), pd.row_bucket(total), True)
@@ -988,8 +992,8 @@ class TestAgainstPyarrow:
                 for sub in jax.core.jaxprs_in_params(eqn.params):
                     found += gathers(sub)
             return found
-        jaxpr = jax.make_jaxpr(program.direct)(nrows, packed)
+        jaxpr = jax.make_jaxpr(program.direct)(nrows, *arrays)
         assert gathers(jaxpr.jaxpr) == 2 * tables + dicts + ranks
         assert program.msgs_box == [2 * tables, 2 + 2 + 2]   # merge; `q`
-        text = program.direct.lower(nrows, packed).as_text()
+        text = program.direct.lower(nrows, *arrays).as_text()
         assert "stablehlo.while" not in text
