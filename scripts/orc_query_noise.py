@@ -1,9 +1,9 @@
 """Where a warm query's seconds vary: one process of a benchmark cell, its
 set-up as `benchmark/run.py` makes it, then N `collect()`s, each with what
 the process spent beside its wall time (CPU seconds of all threads, the cyclic
-collector's pauses, the engine's own waits from the ring of recent queries)
-and the scan's `readTime`. Prints one JSON line a
-query and a summary. Not a run of the benchmark.
+collector's pauses, the host's time by stage from the ring of recent queries:
+every field of `utils/tracing.STAGES`) and the scan's `readTime`. Prints one
+JSON line a query and a summary. Not a run of the benchmark.
 
     python3 scripts/orc_query_noise.py --workload lineitem.q1_orc --seed 7 -n 40"""
 
@@ -57,6 +57,8 @@ def main(argv=None) -> int:
                            time.perf_counter() - began[info["generation"]]))
     gc.callbacks.append(collector)
     from spark_rapids_tpu.plugin import TpuSession
+    from spark_rapids_tpu.utils.tracing import STAGES
+    kept = set(STAGES.values()) | {"prefetch_stall_ns", "semaphore_wait_ns"}
     for i in range(args.n + 2):
         del pauses[:]
         c0 = time.process_time()
@@ -68,9 +70,7 @@ def main(argv=None) -> int:
                "gc_ms": round(1e3 * sum(p[1] for p in pauses), 1),
                "gc_gen": max((p[0] for p in pauses), default=-1),
                **{k[:-3] + "_ms": round(v / 1e6, 1) for k, v in
-                  TpuSession.recent_queries()[-1][2].items()
-                  if k in ("h2d_ns", "d2h_ns", "host_sync_ns",
-                           "prefetch_stall_ns", "semaphore_wait_ns")},
+                  TpuSession.recent_queries()[-1][2].items() if k in kept},
                "compiles": rec.get("compiles"), "faults": rec["faults"]}
         print(json.dumps(row), flush=True)
         if i >= 2:
@@ -78,8 +78,8 @@ def main(argv=None) -> int:
     print(json.dumps({k: {"mean": round(statistics.mean(r[k] for r in rows), 4),
                           "median": statistics.median(r[k] for r in rows),
                           "sd": round(statistics.pstdev(r[k] for r in rows), 4)}
-                      for k in ("s", "scan_s", "cpu_s", "gc_ms", "d2h_ms",
-                                "host_sync_ms")}),
+                      for k in ("s", "scan_s", "cpu_s", "gc_ms",
+                                *sorted(f[:-3] + "_ms" for f in kept))}),
           flush=True)
     return 0
 

@@ -109,8 +109,7 @@ class ColumnarBatch:
         n = self.num_rows
         if not isinstance(n, jax.Array):
             return int(n)
-        with spans.timed("sync.row_count", "host_sync_ns",
-                         add={"host_sync_count": 1}):
+        with spans.span("sync.row_count", add={"host_sync_count": 1}):
             return int(n)
 
     def row_mask(self) -> jnp.ndarray:

@@ -333,9 +333,6 @@ class CompileService:
         self._enabled = True
         self._max_programs = 512
         self._dir = ""
-        # per-invocation kernel spans (spark.rapids.tpu.metrics.spans.
-        # kernel.enabled): off by default — one span per batch per kernel
-        self._kernel_spans = False
         self.stats = CompileStats()
         self._warned_persist = False
         self._tier = None  # utils/durable.DurableTier once a dir is set
@@ -365,8 +362,6 @@ class CompileService:
             self._max_programs = int(
                 conf.get("spark.rapids.tpu.compile.cache.maxPrograms"))
             self._dir = conf.get("spark.rapids.tpu.compile.cache.dir") or ""
-            self._kernel_spans = bool(conf.get(
-                "spark.rapids.tpu.metrics.spans.kernel.enabled"))
         if self._dir:
             # durable-tier discipline (utils/durable.py): any IO failure —
             # here or on a later store/load — degrades the persistent tier
@@ -409,9 +404,9 @@ class CompileService:
 
     # ------------------------------------------------------------------
     def call(self, sj: ServiceJit, args: tuple):
-        # digest, lookup and launch, on the profiler's clock only: one per
-        # batch per kernel is too many for a query profile, where the
-        # opt-in `kernel:<op>` span below stays what it was
+        # digest, lookup and launch: an annotation on the profiler's clock
+        # that charges `TaskMetrics.dispatch_ns` (`tracing.STAGES`), and no
+        # `Span`: one per batch per kernel is too many for a query profile
         with trace_range(f"dispatch.{sj.op}"):
             return self._call(sj, args)
 
@@ -444,10 +439,6 @@ class CompileService:
                 return sj.direct(*args)
         self._restore_boxes(entry, boxes)
         try:
-            if self._kernel_spans:
-                with spans.span(f"kernel:{sj.op}", kind=spans.KIND_KERNEL,
-                                op=sj.op):
-                    return entry.compiled(*dyn)
             return entry.compiled(*dyn)
         except Exception as e:
             # a stale/poisoned executable must never fail the query: evict

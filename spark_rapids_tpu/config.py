@@ -126,10 +126,6 @@ register("spark.rapids.tpu.metrics.profile.enabled", "bool", False,
          "Collect the in-memory query profile (span tree + per-operator "
          "metric deltas, TpuSession.explain_profile()) without writing an "
          "event log. Implied by spark.rapids.tpu.metrics.eventLog.dir.")
-register("spark.rapids.tpu.metrics.spans.kernel.enabled", "bool", False,
-         "Also record one span per compiled-kernel invocation (kind="
-         "'kernel'). High-cardinality: one record per batch per kernel; "
-         "meant for deep dives, not steady-state profiling.")
 register("spark.rapids.tpu.metrics.eventLog.maxBytes", "bytes", 0,
          "Size cap for the live per-process event-log file: an append "
          "that would push it past this rotates the file to '.1' "

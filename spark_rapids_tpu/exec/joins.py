@@ -37,6 +37,7 @@ from ..expr.hashing import hash_vecs
 from ..expr.predicates import string_equal
 from ..ops.rowops import compact_vecs, gather_vecs, stable_lexsort
 from ..utils import metrics as M
+from ..utils import spans
 from .base import (StaticExpr as _StaticExpr, TpuExec, batch_vecs,
                    device_ctx, vecs_to_batch)
 from .coalesce import concat_batches
@@ -486,8 +487,11 @@ class TpuShuffledHashJoinExec(TpuExec):
         with self.join_time.timed():
             counts, lo, order, pvalid, bvalid = _probe_counts(
                 probe, build, self._lk_ix, self._rk_ix)
-            total = int(jnp.sum(_slot_counts(
-                jnp, counts, probe.row_mask(), self.join_type)))
+            total = jnp.sum(_slot_counts(
+                jnp, counts, probe.row_mask(), self.join_type))
+            # the host blocks here until the probe has run
+            with spans.span("sync.join_size", add={"host_sync_count": 1}):
+                total = int(total)
             if self.join_type in ("semi", "anti", "existence"):
                 out_cap = max(row_bucket(max(total, 1), op="join"), probe.capacity)
             else:

@@ -71,7 +71,7 @@ def device_batch_to_host(b: ColumnarBatch) -> HostBatch:
     """The sink's device -> host copy of one batch, under the `sink.d2h`
     span and `TaskMetrics.d2h_ns`. Its own row-count sync is part of it, so
     it reads `num_rows` directly and adds nothing to `host_sync_ns`."""
-    with spans.timed("sink.d2h", "d2h_ns", kind=spans.KIND_IO):
+    with spans.span("sink.d2h", kind=spans.KIND_IO):
         n = int(b.num_rows)
         # every flat column's copies are started before the first is waited
         # for, so they wait beside each other, and a string column's matrix,

@@ -70,6 +70,7 @@ from .. import types as T
 from ..columnar.padding import row_bucket
 from ..ops.rowops import PACK_ROWS, ahead, gather_rows
 from ..utils import spans
+from ..utils.metrics import TaskMetrics
 from .parquet_device import (DeviceDecodeUnsupported, _host_cols_to_device,
                              _note_dispatches, _pow2, _prefix_sum_i32, _ship,
                              _slot_runs, _string_matrix_tail)
@@ -1485,10 +1486,14 @@ def decode_stripe(info: OrcFileInfo, f, si: int, schema, host_cols=None,
                                             cap, nrows)
     with spans.span("scan.walk", kind=spans.KIND_IO):
         directory = _stripe_footer(info, f, st)
+    tm = TaskMetrics.get()
 
     def walk(name, dt):
         cid, col_stats = info.col_ids[name], _ScanStats()
-        with spans.span("scan.walk", kind=spans.KIND_IO):
+        # a walker thread outlives the query: its walk counts in the query's
+        # TaskMetrics, and nothing after it does
+        with TaskMetrics.adopted(tm), \
+                spans.span("scan.walk", kind=spans.KIND_IO):
             cs = _column_streams(info, f, st, directory, cid)
             return _column_plan(cs, info.col_kinds[cid], dt, nrows, cap,
                                 directory[2], col_stats), col_stats

@@ -93,8 +93,8 @@ def _ship(buffers):
     import jax
     nbytes = sum(a.nbytes for a in buffers) if isinstance(buffers, list) \
         else buffers.nbytes
-    with spans.timed("scan.h2d", "h2d_ns", kind=spans.KIND_IO,
-                     add={"h2d_bytes": nbytes}, bytes=nbytes):
+    with spans.span("scan.h2d", kind=spans.KIND_IO,
+                    add={"h2d_bytes": nbytes}, bytes=nbytes):
         return jax.device_put(buffers)
 
 

@@ -246,7 +246,8 @@ class TpuSession:
         PROCESS finished last, oldest first, at most
         `utils.metrics.RECENT_QUERIES` (64) of them: with profiling off too,
         what says whether a slow query waited on the chip (`host_sync_ns`,
-        `d2h_ns`) or on the host. A longer window is covered in its last 64
+        `d2h_ns`) or on the host, and in which stage (every field of
+        `utils.tracing.STAGES`). A longer window is covered in its last 64
         only."""
         from .utils import metrics
         return metrics.recent_queries()

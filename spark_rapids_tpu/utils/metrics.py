@@ -255,19 +255,36 @@ class TaskMetrics:
         self.device_dispatches = 0
         self.fused_stages = 0
         self.fused_ops = 0
-        # where the host waited for the device or moved bytes, counted at
-        # the seams that open the spans of the same name (utils/spans.timed,
-        # always on): `ColumnarBatch.row_count()` on a device scalar
-        # (`sync.row_count`: the host blocks until every program queued
-        # before it has run), the scan's batched transfers (`scan.h2d`) and
-        # the sink's device -> host copies (`sink.d2h`, its own row-count
-        # sync included). A prefetch producer shares its consumer's
-        # instance, so overlapping waits of the two threads both count.
+        # the host's time by stage, charged by the stage spans themselves
+        # (`utils/tracing.STAGES`, always on): the chunk or stripe walk
+        # (`scan.walk`), signatures and packing (`scan.pack`), the scan's
+        # batched transfers (`scan.h2d`), the compile service's digest,
+        # lookup and launch (`dispatch.<op>`; a launch that compiles holds
+        # its compile), the plan rewrite (`plan.rewrite`), the sink's
+        # device -> host copies (`sink.d2h`, its own row-count sync
+        # included) and the host's reads of a device count (`sync.row_count`
+        # in `ColumnarBatch.row_count()`, `sync.join_size` in a join: the
+        # host blocks until every program queued before it has run).
+        # Thread-seconds: the threads that do one query's work share its
+        # instance (a prefetch producer, the ORC walkers), so stages that
+        # run side by side add up and may exceed the query's wall time.
+        self.scan_walk_ns = 0
+        self.scan_pack_ns = 0
+        self.dispatch_ns = 0
+        self.plan_rewrite_ns = 0
         self.host_sync_ns = 0
         self.host_sync_count = 0
         self.h2d_ns = 0
         self.h2d_bytes = 0
         self.d2h_ns = 0
+
+    _add_mu = threading.Lock()
+
+    def add(self, field: str, delta: int) -> None:
+        """`field += delta`, safe when the threads that share this instance
+        charge it at once."""
+        with TaskMetrics._add_mu:
+            setattr(self, field, getattr(self, field) + delta)
 
     @classmethod
     def get(cls) -> "TaskMetrics":
@@ -280,6 +297,19 @@ class TaskMetrics:
     @classmethod
     def reset(cls) -> None:
         cls._tls.metrics = TaskMetrics()
+
+    @classmethod
+    @contextlib.contextmanager
+    def adopted(cls, tm: "TaskMetrics"):
+        """This thread counts into `tm` for the scope and into its own
+        instance again after it: a pooled thread that lives longer than the
+        query it works for."""
+        prev = getattr(cls._tls, "metrics", None)
+        cls._tls.metrics = tm
+        try:
+            yield tm
+        finally:
+            cls._tls.metrics = prev
 
     def explain_string(self) -> str:
         """Retry/recovery summary for explain output; empty when the task

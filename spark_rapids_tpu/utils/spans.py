@@ -23,9 +23,10 @@ Three layers:
 One primitive, two clocks: `span()` always opens a profiler annotation
 (`utils/tracing.trace_range`, name prefixed `SPAN_PREFIX`), so every engine
 span reaches a `jax.profiler` trace on the device trace's clock, and records
-a `Span` into the `QueryProfile` only when one is active. `timed()` is the
-same span with its wall time added to a `TaskMetrics` counter, profile or
-not: the always-on counters live at the seams the spans mark.
+a `Span` into the `QueryProfile` only when one is active. A span named in
+`tracing.STAGES` adds its wall time to the stage's `TaskMetrics` field,
+profile or not, and `add=` counts (bytes, calls) ride any span: the
+always-on counters live at the seams the spans mark.
 
 Disabled-path contract: when no profile is active, `span()` yields the
 shared no-op span (no `Span` is allocated) and, with no profiler session,
@@ -48,7 +49,6 @@ from .metrics import TaskMetrics
 from .tracing import SPAN_PREFIX, trace_range
 
 __all__ = ["SCHEMA_VERSION", "SPAN_PREFIX", "Span", "QueryProfile", "span",
-           "timed",
            "current_profile", "begin_profile", "end_profile",
            "write_event_log", "validate_record", "task_metrics_dict",
            "new_trace_id", "current_trace", "trace_scope",
@@ -164,15 +164,15 @@ def _stack() -> list:
 
 
 class _Scope:
-    """One open `span()` or `timed()`: the profiler annotation, a real Span
-    inside the active profile when there is one, and for `timed()` the
-    wall time into a TaskMetrics counter."""
+    """One open `span()`: the profiler annotation (which charges a stage's
+    TaskMetrics field), a real Span inside the active profile when there is
+    one, and the `add` counts."""
 
     __slots__ = ("_range", "_prof", "_name", "_kind", "_attrs", "_span",
-                 "_field", "_add", "_t0")
+                 "_add")
 
     def __init__(self, prof: Optional["QueryProfile"], name: str, kind: str,
-                 attrs: Dict[str, Any], field: Optional[str] = None,
+                 attrs: Dict[str, Any],
                  add: Optional[Dict[str, int]] = None):
         self._range = trace_range(name)
         self._prof = prof
@@ -180,13 +180,9 @@ class _Scope:
         self._kind = kind
         self._attrs = attrs
         self._span: Optional[Span] = None
-        self._field = field
         self._add = add
-        self._t0 = 0
 
     def __enter__(self):
-        if self._field is not None:
-            self._t0 = time.perf_counter_ns()
         self._range.__enter__()
         if self._prof is None:
             return NOOP_SPAN
@@ -213,12 +209,10 @@ class _Scope:
             if hook is not None:  # telemetry flight recorder (late-bound)
                 hook(sp, self._prof)
         self._range.__exit__(*exc)
-        if self._field is not None:
+        if self._add:
             tm = TaskMetrics.get()
-            setattr(tm, self._field, getattr(tm, self._field)
-                    + time.perf_counter_ns() - self._t0)
-            for field, delta in (self._add or {}).items():
-                setattr(tm, field, getattr(tm, field) + delta)
+            for field, delta in self._add.items():
+                tm.add(field, delta)
         return False
 
 
@@ -229,21 +223,16 @@ def _active_profile() -> Optional["QueryProfile"]:
     return prof
 
 
-def span(name: str, kind: str = KIND_PHASE, **attrs) -> _Scope:
-    """Open a span: always the profiler annotation `SPAN_PREFIX + name`, and
-    a `Span` under the active query profile when there is one (else the
-    `with` yields the shared no-op). Usage:
+def span(name: str, kind: str = KIND_PHASE,
+         add: Optional[Dict[str, int]] = None, **attrs) -> _Scope:
+    """Open a span: always the profiler annotation `SPAN_PREFIX + name`
+    (which adds its wall time to the stage's TaskMetrics field where
+    `tracing.STAGES` names `name`), and a `Span` under the active query
+    profile when there is one (else the `with` yields the shared no-op).
+    `add` maps further TaskMetrics counters to their increments (a byte
+    count, a call count), profile or not. Usage:
     ``with span("spill:to_host", kind="spill") as sp: ...``"""
-    return _Scope(_active_profile(), name, kind, attrs)
-
-
-def timed(name: str, ns_field: str, kind: str = KIND_PHASE,
-          add: Optional[Dict[str, int]] = None, **attrs) -> _Scope:
-    """`span()` whose wall time is also added to `TaskMetrics.<ns_field>`,
-    with or without a profile; `add` maps further TaskMetrics counters to
-    their increments (a byte count, a call count). One code path for the
-    span and the counter that mark one seam."""
-    return _Scope(_active_profile(), name, kind, attrs, ns_field, add)
+    return _Scope(_active_profile(), name, kind, attrs, add)
 
 
 def suppress_in_thread() -> None:
