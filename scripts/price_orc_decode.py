@@ -237,7 +237,7 @@ def main(argv=None) -> int:
               lambda e, t, w: O._expand_rlev2(e, t, w, cap, signed, wide),
               *jax.device_put([ends, table, words]), check=same)
         price(f"{label}.slot_runs_alone",
-              lambda e: O._slot_runs(e, cap), jax.device_put(ends))
+              lambda e: R.slot_runs(e, cap), jax.device_put(ends))
         if ends.shape[0] < R._GATHER_MIN_COLS:
             # the compiler gathers out of a table of under ~2 MB another
             # way, with 1 GB of temporaries at 2,097,152 slots (sandbox

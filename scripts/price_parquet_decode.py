@@ -33,7 +33,8 @@ import jax.numpy as jnp  # noqa: E402
 
 import spark_rapids_tpu  # noqa: E402,F401  (x64 on)
 from spark_rapids_tpu.io import parquet_device as P  # noqa: E402
-from spark_rapids_tpu.ops.rowops import ahead, gather_rows  # noqa: E402
+from spark_rapids_tpu.ops.rowops import (ahead, gather_rows,  # noqa: E402
+                                         prefix_sum)
 
 OUT = []
 
@@ -65,7 +66,7 @@ def parent_run_of_slot(counts, cap: int):
     ends = jnp.cumsum(counts.astype(jnp.int32))
     marks = jnp.zeros(cap, jnp.int32).at[ends].add(
         1, mode="drop", indices_are_sorted=True)
-    run = jnp.clip(P._prefix_sum_i32(marks), 0, counts.shape[0] - 1)
+    run = jnp.clip(prefix_sum(marks), 0, counts.shape[0] - 1)
     return lax.optimization_barrier((run, ends))
 
 
@@ -130,7 +131,7 @@ def telescoped(ends, table, words, cap: int):
         d = jax.lax.bitcast_convert_type(diff[k], jnp.int32)
         at = jnp.zeros(cap, jnp.int32).at[first].add(
             d, mode="drop", indices_are_sorted=True)
-        rows.append(jax.lax.bitcast_convert_type(P._prefix_sum_i32(at), u32))
+        rows.append(jax.lax.bitcast_convert_type(prefix_sum(at), u32))
     base, value, width = rows
     bitpos = base + jnp.arange(cap, dtype=u32) * width
     r = bitpos & u32(31)

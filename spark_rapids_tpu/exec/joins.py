@@ -5,14 +5,18 @@ TPU lowering (ARCHITECTURE.md #4): equi-joins run as hash-sorted probe —
   1. hash the build-side keys (Spark murmur3), sort build rows by hash, and give
      each sorted position the length of the run of equal hashes it starts
      (elementwise + one reversed running minimum over the small build side);
-  2. per probe row, locate the start of its candidate range with ONE search
-     (`side=left`) of the 32-bit hashes; the range's length is the run length
-     at that position if the hash there is the probe's, else 0;
+  2. per probe row, locate the start of its candidate range (`side=left` in
+     the sorted 32-bit hashes) by a merge: one stable sort of the probe and
+     build hashes together and a prefix sum over the build entries' marks;
+     the range's length is the run length at that position if the hash there
+     is the probe's, else 0;
   3. expand matches into (probe_idx, build_idx) pairs at a host-chosen output
      capacity (the JoinGatherer chunking analog: counts are computed on device,
      summed, synced once to pick the bucket — data-dependent sizes never reach
-     XLA). Phase 2 consumes phase 1's arrays (counts, range starts, build
-     order, validity masks): nothing of the probe is computed twice;
+     XLA); each output slot's probe row comes from one mark per probe row and
+     a prefix sum (`rowops.slot_runs`). Phase 2 consumes phase 1's arrays
+     (counts, range starts, build order, validity masks): nothing of the probe
+     is computed twice;
   4. gather both sides, verify true key equality (hash collisions + null keys),
      compact away false positives.
 Left/right/full outer rows are emitted via the unmatched masks; semi/anti reduce the
@@ -35,7 +39,8 @@ from ..compile import sjit
 from ..expr.base import Expression, Vec, bind_references
 from ..expr.hashing import hash_vecs
 from ..expr.predicates import string_equal
-from ..ops.rowops import compact_vecs, gather_vecs, stable_lexsort
+from ..ops.rowops import (compact_vecs, gather_vecs, prefix_sum, slot_runs,
+                          stable_lexsort)
 from ..utils import metrics as M
 from ..utils import spans
 from .base import (StaticExpr as _StaticExpr, TpuExec, batch_vecs,
@@ -91,14 +96,43 @@ def _run_lengths(xp, keys_sorted, n_valid):
     return xp.maximum(xp.minimum(nxt, n_valid) - idx, 0)
 
 
+def _merge_rank(queries, keys_sorted):
+    """int32[len(queries)]: `searchsorted(keys_sorted, queries, side="left")`
+    by counting, not searching (device only). The queries and the sorted
+    keys are sorted together, stably and queries first, so at equal values a
+    query lands before the keys and its rank counts only the smaller ones;
+    a query's rank is then the number of key entries before it, an exclusive
+    prefix sum over their marks, and one more sort by source position puts
+    the ranks back in query order. `jnp.searchsorted`'s scan is a `while`
+    loop of log2(n) dependent gathers that XLA fuses nothing into; its sort
+    method does two argsorts and two scatters and took the v5e compiler 31 s
+    for the probe at 2M rows (PERF.md)."""
+    nq = queries.shape[0]
+    src = stable_lexsort(jnp, [jnp.concatenate([queries, keys_sorted])])
+    is_key = (src >= nq).astype(np.int32)
+    before = prefix_sum(is_key) - is_key
+    _, rank = lax.sort((src, before), num_keys=1)
+    return rank[:nq]
+
+
 @sjit(op="exec.join.probe_counts", static_argnums=(2, 3))
 def _probe_counts(probe: ColumnarBatch, build: ColumnarBatch,
                   probe_key_ix: Tuple[int, ...], build_key_ix: Tuple[int, ...],
                   hash_rows=None):
     """Phase 1: per-probe candidate counts and range starts (by hash) in the
-    sorted build order. One binary search per probe row; the range's length
-    comes from the build side's run lengths. `hash_rows` stands in for
-    `hash_vecs` (bit-identical; the fused stage's Pallas row hash)."""
+    sorted build order; the range's length comes from the build side's run
+    lengths. `hash_rows` stands in for `hash_vecs` (bit-identical; the fused
+    stage's Pallas row hash).
+
+    A range start is `searchsorted(bh_sorted, ph, side="left")`, taken as
+    a merge rank (`_merge_rank`): the program holds no loop and four sorts,
+    two for the build side's two-key order and the merge's two. One way
+    for every shape (v5e, median of 8, `scripts/price_join_search.py`;
+    PERF.md): 2,097,152 probe hashes take the merge 9.4 ms against 131,072
+    build rows and the scan search 271 ms. The scan wins only out of a
+    table of at most 64 entries (1.7 ms), which no batch is: at the least
+    capacity a batch has, 128, this program took 6.5 ms merged and 23.3 ms
+    searched for 262,144 probe rows."""
     xp = jnp
     hash_rows = hash_rows or hash_vecs
     pvecs = batch_vecs(probe)
@@ -124,7 +158,7 @@ def _probe_counts(probe: ColumnarBatch, build: ColumnarBatch,
     bh_sorted = xp.where(xp.arange(bcap, dtype=np.int32) < n_valid,
                          bh[order], _HASH_MAX)
     run_len = _run_lengths(xp, bh_sorted, n_valid)
-    lo = xp.searchsorted(bh_sorted, ph, side="left").astype(np.int32)
+    lo = _merge_rank(ph, bh_sorted)
     at = xp.minimum(lo, bcap - 1)
     hit = pvalid & (bh_sorted[at] == ph)
     counts = xp.where(hit, run_len[at], 0).astype(np.int32)
@@ -151,13 +185,12 @@ def _expand_join(probe: ColumnarBatch, build: ColumnarBatch,
     bcap = build.capacity
 
     outer_left = join_type in ("left", "full")
-    offsets = xp.cumsum(_slot_counts(xp, counts, pmask, join_type))
+    offsets = prefix_sum(_slot_counts(xp, counts, pmask, join_type))
     total = offsets[-1] if pcap > 0 else xp.asarray(0, np.int32)
     j = xp.arange(out_cap, dtype=np.int32)
     live = j < total
-    # probe row for output slot j
-    pi = xp.searchsorted(offsets, j, side="right").astype(np.int32)
-    pi = xp.clip(pi, 0, pcap - 1)
+    # probe row for output slot j: the rows whose slots end at or before j
+    pi = slot_runs(offsets, out_cap)
     base = xp.where(pi > 0, offsets[xp.maximum(pi - 1, 0)], 0)
     k = j - base
     bidx_sorted = xp.clip(lo[pi] + k, 0, bcap - 1)

@@ -32,7 +32,7 @@ reloads from the persistent cache:
       file's exact counts.
   device (the actual data work):
     * RLEv2 expansion: output slot -> run by one mark per run and a prefix
-      sum (`parquet_device._slot_runs`), the run's words by one stacked
+      sum (`rowops.slot_runs`), the run's words by one stacked
       gather, packed runs unpacked from two or three big-endian 32-bit
       words a slot by one more, zigzag undone with vector ops;
     * DECIMAL (precision <= 18): the zigzag base-128 varint mantissas fold
@@ -68,12 +68,13 @@ import numpy as np
 
 from .. import types as T
 from ..columnar.padding import row_bucket
-from ..ops.rowops import PACK_ROWS, ahead, gather_rows
+from ..ops.rowops import (PACK_ROWS, ahead, gather_rows, prefix_sum,
+                          slot_runs)
 from ..utils import spans
 from ..utils.metrics import TaskMetrics
 from .parquet_device import (DeviceDecodeUnsupported, _host_cols_to_device,
-                             _note_dispatches, _pow2, _prefix_sum_i32, _ship,
-                             _slot_runs, _string_matrix_tail)
+                             _note_dispatches, _pow2, _ship,
+                             _string_matrix_tail)
 
 __all__ = ["OrcFileInfo", "columns_supported", "decode_stripe",
            "device_decode_file", "file_supported"]
@@ -823,7 +824,7 @@ def _unzigzag(u):
 
 def _expand_rlev2(ends, table, words, cap: int, signed: bool, wide: bool):
     """RLEv2 run table (`_RunTable.device_arrays`) -> int64[cap] values.
-    Per slot: its run by `_slot_runs`, the run's seven words by one stacked
+    Per slot: its run by `slot_runs`, the run's seven words by one stacked
     gather, then either `base + within * step` (SHORT_REPEAT, fixed DELTA) or
     the run's `width` bits at `offs + within * width` of the big-endian
     packed stream (DIRECT; PATCHED_BASE and literal DELTA runs, which the
@@ -834,7 +835,7 @@ def _expand_rlev2(ends, table, words, cap: int, signed: bool, wide: bool):
     import jax
     import jax.numpy as jnp
     u32, u64 = jnp.uint32, jnp.uint64
-    run = _slot_runs(ends, cap)
+    run = slot_runs(ends, cap)
     start, blo, bhi, slo, shi, offs, wk = gather_rows(table, run)
     within = jnp.arange(cap, dtype=jnp.int32) - start.astype(jnp.int32)
     arith = _u64(blo, bhi) + within.astype(u64) * _u64(slo, shi)
@@ -880,7 +881,7 @@ def _varint_zigzag(words, cap: int):
     value. `words` is the stream as little-endian uint32 words, zero-padded
     to a bucket of whole 32-byte blocks (a padding byte is a value 0 past
     the live ones). Per block the 32 terminator bits and their count, the
-    counts' prefix sum, `_slot_runs` for the block that ends value v, one
+    counts' prefix sum, `slot_runs` for the block that ends value v, one
     stacked gather for that block's bits and first value, `_select_bit` for
     the byte; a value starts after the one before it. Its up to nine bytes
     (18 digits zigzag into 61 bits) come out of three words by one more
@@ -898,8 +899,8 @@ def _varint_zigzag(words, cap: int):
     mask = jnp.sum(nib << (u32(4) * jnp.arange(8, dtype=u32))[None, :],
                    axis=1, dtype=u32)
     counts = jax.lax.population_count(mask).astype(jnp.int32)
-    ends = _prefix_sum_i32(counts)
-    blk = _slot_runs(ends, cap)
+    ends = prefix_sum(counts)
+    blk = slot_runs(ends, cap)
     m, first = gather_rows([mask, (ends - counts).astype(u32)], blk)
     v = jnp.arange(cap, dtype=jnp.int32)
     end = blk * 32 + _select_bit(m, v - first.astype(jnp.int32)).astype(
@@ -924,7 +925,7 @@ def _varint_zigzag(words, cap: int):
 def _expand_byte_rle(ends, table, blob, cap: int):
     """Byte-RLE run table (`_byte_rle_device`) -> uint8[cap] bytes."""
     import jax.numpy as jnp
-    run = _slot_runs(ends, cap)
+    run = slot_runs(ends, cap)
     start, vk, offs = gather_rows(table, run)
     within = jnp.arange(cap, dtype=jnp.int32) - start.astype(jnp.int32)
     lit = blob[jnp.clip(offs.astype(jnp.int32) + within, 0,
@@ -1016,7 +1017,7 @@ def _traced_column(sig, cap: int, nrows, it):
         ends, table, blob = next(it), next(it), next(it)
         defined = _bits_msb_first(
             _expand_byte_rle(ends, table, blob, cap // 8)) & live
-        rank = jnp.clip(_prefix_sum_i32(defined.astype(jnp.int32)) - 1,
+        rank = jnp.clip(prefix_sum(defined.astype(jnp.int32)) - 1,
                         0, cap - 1)
     else:
         defined, rank = live, None

@@ -22,8 +22,8 @@ encodings default pyarrow/Spark output actually uses:
   device (the actual data work):
     * def-level + index expansion (`_unpack_runs`): output slot -> run by
       one mark per run scattered into the slots and a prefix sum over them
-      (`_slot_runs`: the slots are all queried, in order, so no table is
-      searched), the run's words by one stacked gather and the two packed
+      (`rowops.slot_runs`: the slots are all queried, in order, so no table
+      is searched), the run's words by one stacked gather and the two packed
       words that hold the slot's bits by one more (1-bit def levels,
       up-to-32-bit dictionary indices) — values never exist row-wise on
       the host;
@@ -69,7 +69,7 @@ import numpy as np
 
 from .. import types as T
 from ..columnar.padding import LANE, row_bucket
-from ..ops.rowops import ahead, gather_rows
+from ..ops.rowops import ahead, gather_rows, slot_runs
 from ..utils import spans
 
 __all__ = ["DeviceDecodeUnsupported", "columns_supported",
@@ -299,52 +299,6 @@ def _rle_runs(payload: memoryview, num_values: int, bit_width: int = 1):
 # Device kernels
 # ----------------------------------------------------------------------------
 
-def _prefix_sum_i32(x):
-    """Inclusive prefix sum of int32[n]: log2(128) shifted adds inside rows
-    of a (n / 128, 128) view, then the row totals the same way. The same
-    function as `jnp.cumsum`, which costs the v5e compiler 24-33 s for each
-    1M-slot call (its reduce-window) where this costs it half a second and
-    runs as fast (0.66 against 0.81 ms; PERF.md, PR 30). A decode program
-    holds one of these per run table."""
-    import jax.numpy as jnp
-    n = x.shape[0]
-    rows = jnp.pad(x, (0, -n % LANE)).reshape(-1, LANE)
-    step = 1
-    while step < min(n, LANE):
-        rows = rows + jnp.pad(rows[:, :-step], ((0, 0), (step, 0)))
-        step *= 2
-    if rows.shape[0] > 1:
-        before = jnp.pad(_prefix_sum_i32(rows[:, -1])[:-1], (1, 0))
-        rows = rows + before[:, None]
-    return rows.reshape(-1)[:n]
-
-
-def _slot_runs(ends, cap: int):
-    """run int32[cap]: for every output slot the run of the table that holds
-    it, `searchsorted(ends, j, side="right")` clipped to R - 1, from the
-    runs' exclusive end slots, which the host has summed. The query is every
-    slot in order, so nothing is searched: a run ends before slot j exactly
-    when its end marks a slot <= j, which is one mark per RUN scattered into
-    the slots and one prefix sum over them. Zero-count runs (real empty runs,
-    and padding runs, which end where the last real one does) stack their
-    marks on one slot and are stepped over as side="right" steps over them;
-    ends at or past `cap` mark no slot. Shared with `orc_device`.
-
-    The barrier makes the map one array, computed once. The searches it
-    replaced were loops, which XLA fuses nothing into; without them it
-    fuses the prefix sum into each gather that reads the map, and the
-    decode programs' code, which lives in device memory, grows from 182 to
-    279 MB (`star.q3`) and from 189 to 500 MB (`lineitem.q1`; sandbox v5e
-    compiler, PR 30)."""
-    import jax.numpy as jnp
-    from jax import lax
-    assert 0 < cap < 2 ** 31, cap
-    marks = jnp.zeros(cap, jnp.int32).at[ends].add(
-        1, mode="drop", indices_are_sorted=True)
-    run = jnp.clip(_prefix_sum_i32(marks), 0, ends.shape[0] - 1)
-    return lax.optimization_barrier(run)
-
-
 class _Tally:
     """What a decode program's per-slot gathers are, counted while it is
     traced: `stacked`, the stacked gathers lowered into it (two a run
@@ -362,7 +316,7 @@ class _Tally:
 
 def _unpack_runs(ends, table, words, cap: int):
     """Run table (`_run_words`) -> uint32[cap], traced. Per slot: its run
-    by `_slot_runs`, the run's three words (bit base, repeated value,
+    by `slot_runs`, the run's three words (bit base, repeated value,
     width) by one stacked gather, then the two 32-bit words of the
     LSB-first packed stream that hold its bits by one more: a packed
     run's slot reads `width` bits at `base + j * width`, a repeated run's
@@ -371,7 +325,7 @@ def _unpack_runs(ends, table, words, cap: int):
     run or the last run: the callers mask them."""
     import jax.numpy as jnp
     u32 = jnp.uint32
-    run = _slot_runs(ends, cap)
+    run = slot_runs(ends, cap)
     base, value, width = gather_rows(table, run)
     bitpos = base + jnp.arange(cap, dtype=u32) * width
     r = bitpos & u32(31)

@@ -414,8 +414,8 @@ def prefix_sum(x):
     (n,) or (K, n): log2(128) shifted adds inside rows of a (n / 128, 128)
     view, then the row totals the same way. Device only. The same function
     as `jnp.cumsum`, whose reduce-window costs the v5e compiler 24-33 s for
-    each 1M-slot call (PERF.md, PR 30) where this costs it half a second;
-    `io/parquet_device._prefix_sum_i32` is the int32 (n,) case of it."""
+    each 1M-slot call (PERF.md) where this costs it half a second and
+    runs as fast (0.66 against 0.81 ms at 1M int32 slots on a v5e)."""
     import jax.numpy as jnp
     n = x.shape[-1]
     if n == 0:
@@ -431,6 +431,34 @@ def prefix_sum(x):
         before = jnp.pad(prefix_sum(rows[..., -1])[..., :-1], lead + [(1, 0)])
         rows = rows + before[..., None]
     return rows.reshape(x.shape[:-1] + (-1,))[..., :n]
+
+
+def slot_runs(ends, cap: int):
+    """run int32[cap]: for every output slot the run of the table that holds
+    it, `searchsorted(ends, j, side="right")` clipped to R - 1, from the
+    runs' exclusive end slots (non-decreasing int32[R]). Device only. The
+    query is every slot in order, so nothing is searched: a run ends before
+    slot j exactly when its end marks a slot <= j, which is one mark per RUN
+    scattered into the slots and one prefix sum over them. Zero-count runs
+    (real empty runs, and padding runs, which end where the last real one
+    does) stack their marks on one slot and are stepped over as side="right"
+    steps over them; ends at or past `cap` mark no slot. The parquet and ORC
+    decoders map slots to RLE runs with it, the join's expand output slots
+    to probe rows.
+
+    The barrier makes the map one array, computed once. The searches it
+    replaced were loops, which XLA fuses nothing into; without them it
+    fuses the prefix sum into each gather that reads the map, and the
+    decode programs' code, which lives in device memory, grows from 182 to
+    279 MB (`star.q3`) and from 189 to 500 MB (`lineitem.q1`), by the v5e
+    compiler's count."""
+    import jax.numpy as jnp
+    from jax import lax
+    assert 0 < cap < 2 ** 31, cap
+    marks = jnp.zeros(cap, jnp.int32).at[ends].add(
+        1, mode="drop", indices_are_sorted=True)
+    run = jnp.clip(prefix_sum(marks), 0, ends.shape[0] - 1)
+    return lax.optimization_barrier(run)
 
 
 def group_ids_from_sorted(xp, key_vecs: Sequence[Vec], row_mask):

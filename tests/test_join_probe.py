@@ -1,10 +1,12 @@
-"""The equi-join's probe (exec/joins.py `_probe_counts` / `_expand_join`): one
-binary search per probe batch, phase 2 fed by phase 1's arrays.
+"""The equi-join's probe (exec/joins.py `_probe_counts` / `_expand_join`):
+positions found by counting, phase 2 fed by phase 1's arrays.
 
-Three guards: the lowered programs' loop and sort counts (the recompute of the
-probe inside the expand must not come back), `counts`/`lo`/`order` value for
-value against a numpy statement of the two-search probe they replaced, and all
-seven join types end to end against pandas on the same data."""
+Four guards: the lowered programs' loop and sort counts (no `while`: the
+searches are a merge rank and a slot map; the recompute of the probe inside
+the expand must not come back), `counts`/`lo`/`order` value for value against
+a numpy statement of the two-search probe they replaced, the merge rank and
+the expand's slot map against `np.searchsorted`, and all seven join types end
+to end against pandas on the same data."""
 
 import numpy as np
 import pandas as pd
@@ -18,8 +20,10 @@ from spark_rapids_tpu.columnar import batch_from_arrow
 from spark_rapids_tpu.columnar.batch import empty_batch
 from spark_rapids_tpu.exec import joins
 from spark_rapids_tpu.exec.base import batch_vecs
-from spark_rapids_tpu.exec.joins import _expand_join, _probe_counts
+from spark_rapids_tpu.exec.joins import (_expand_join, _merge_rank,
+                                         _probe_counts, _slot_counts)
 from spark_rapids_tpu.expr.hashing import hash_vecs
+from spark_rapids_tpu.ops.rowops import prefix_sum, slot_runs
 from spark_rapids_tpu.plugin import TpuSession
 
 INT32_MAX = np.iinfo(np.int32).max
@@ -53,13 +57,16 @@ def lowering_inputs():
 
 
 def test_probe_lowers_to_one_search(lowering_inputs):
+    """The range starts' one search is a merge now: no loop, and four sorts,
+    two for the build side's two-key stable_lexsort and two for the merge
+    (the probe and build hashes together, then back to probe order), at a
+    build capacity of 128, the least a batch has."""
     probe, build, _ = lowering_inputs
     text = _lowered(_probe_counts.fn, _probe_counts.static_argnums,
                     probe, build, (0,), (0,))
-    # jnp.searchsorted (method="scan") is the only loop; the build side's
-    # two-key stable_lexsort the only sorts
-    assert text.count("stablehlo.while") == 1
-    assert text.count("stablehlo.sort") == 2
+    assert build.capacity == 128
+    assert text.count("stablehlo.while") == 0
+    assert text.count("stablehlo.sort") == 4
 
 
 @pytest.mark.parametrize("how", ["inner", "left", "full", "semi"])
@@ -67,9 +74,10 @@ def test_expand_does_not_probe_again(lowering_inputs, how):
     probe, build, phase1 = lowering_inputs
     text = _lowered(_expand_join.fn, _expand_join.static_argnums,
                     probe, build, *phase1, (0,), (0,), 4096, how, None, False)
-    # its own slot search and its own compaction, nothing of phase 1: a
-    # second copy of the probe would add a loop and the build's two sorts
-    assert text.count("stablehlo.while") == 1
+    # its own slot map (marks and a prefix sum, no loop) and its own
+    # compaction, nothing of phase 1: a second copy of the probe would add
+    # the build's two sorts and the merge's two
+    assert text.count("stablehlo.while") == 0
     assert text.count("stablehlo.sort") == 1
 
 
@@ -124,6 +132,15 @@ def _case(name):
     if name in ("every_hash_equal", "hash_int32_max"):
         return (_batch(rng.integers(0, 10, 200), nulls={1, 50}),
                 _batch(rng.integers(0, 10, 40), nulls={0, 7, 39}))
+    if name == "probe_capacity_below_build":  # 20 probes, 300 build rows
+        return (_batch(rng.integers(0, 60, 20), nulls={4}),
+                _batch(rng.integers(0, 60, 300), nulls={0, 299}))
+    if name == "every_probe_ties_a_build_hash":
+        build = rng.integers(0, 1000, 64)
+        return (_batch(rng.choice(build, 400)), _batch(build))
+    if name == "all_probe_keys_null":
+        return (_batch(rng.integers(0, 9, 40), nulls=set(range(40))),
+                _batch(rng.integers(0, 9, 30)))
     if name == "hash_int32_max_no_valid_build_hit":
         # probes hash to INT32_MAX (0, 3, 6, 9); the only build rows under
         # that word are the exiled ones (null keys)
@@ -141,7 +158,8 @@ _HASHES = {"every_hash_equal": _hash_constant,
     "duplicate_build_keys", "null_keys_both_sides", "all_build_keys_null",
     "padding_rows", "empty_build", "build_of_one_row",
     "build_of_one_null_row", "every_hash_equal", "hash_int32_max",
-    "hash_int32_max_no_valid_build_hit"])
+    "hash_int32_max_no_valid_build_hit", "probe_capacity_below_build",
+    "every_probe_ties_a_build_hash", "all_probe_keys_null"])
 def test_phase1_equals_two_search_oracle(monkeypatch, name):
     probe, build = _case(name)
     hash_rows = _HASHES.get(name, hash_vecs)
@@ -157,6 +175,118 @@ def test_phase1_equals_two_search_oracle(monkeypatch, name):
         assert probe.capacity > 130 and build.capacity > 17
     if name == "every_hash_equal":  # the whole valid build is one run
         assert set(counts[pvalid]) == {int(bvalid.sum())}
+    if name == "probe_capacity_below_build":
+        assert probe.capacity < build.capacity
+    if name == "every_probe_ties_a_build_hash":
+        assert pvalid.sum() == 400 and (counts[pvalid] > 0).all()
+
+
+# ---- the merge rank and the expand's slot map against np.searchsorted ------
+
+def _rank_case(name):
+    rng = np.random.default_rng(40)
+    top = np.int64(INT32_MAX)
+    if name == "ties":
+        k = np.sort(rng.integers(0, 50, 200))
+        return rng.integers(-5, 55, 700), k
+    if name == "int32_max_both_sides":
+        k = np.concatenate([np.sort(rng.integers(0, 99, 60)),
+                            np.full(40, top)])
+        return np.where(rng.random(300) < 0.3, top,
+                        rng.integers(0, 120, 300)), k
+    if name == "int32_min_both_sides":
+        low = -top - 1
+        k = np.concatenate([np.full(5, low), np.sort(rng.integers(0, 9, 20))])
+        return np.where(rng.random(90) < 0.5, low, rng.integers(-3, 12, 90)), k
+    if name == "fewer_queries_than_keys":
+        return rng.integers(0, 1000, 7), np.sort(rng.integers(0, 1000, 900))
+    if name == "one_key":
+        return rng.integers(0, 3, 50), np.array([1])
+    if name == "every_key_equal":
+        return rng.integers(5, 8, 130), np.full(64, 6)
+    if name == "full_range_hashes":
+        k = np.sort(rng.integers(-top - 1, top + 1, 4096))
+        q = rng.integers(-top - 1, top + 1, 20000)
+        q[::3] = rng.choice(k, q[::3].shape[0])
+        return q, k
+    raise AssertionError(name)
+
+
+@pytest.mark.parametrize("name", [
+    "ties", "int32_max_both_sides", "int32_min_both_sides",
+    "fewer_queries_than_keys", "one_key", "every_key_equal",
+    "full_range_hashes"])
+def test_merge_rank_equals_searchsorted_left(name):
+    q, k = (np.asarray(a, np.int32) for a in _rank_case(name))
+    got = jax.jit(_merge_rank)(jnp.asarray(q), jnp.asarray(k))
+    assert got.dtype == np.int32 and got.shape == q.shape
+    np.testing.assert_array_equal(np.asarray(got),
+                                  np.searchsorted(k, q, side="left"))
+
+
+def _slot_case(name):
+    rng = np.random.default_rng(41)
+    mid = rng.integers(0, 4, 300)
+    if name == "zero_rows_at_both_ends_total_below_cap":
+        return np.concatenate([np.zeros(9), mid, np.zeros(13)]), 1024
+    if name == "zero_rows_at_both_ends_total_above_cap":
+        return np.concatenate([np.zeros(9), mid, np.zeros(13)]), 200
+    if name == "total_equals_cap":
+        return np.array([0, 3, 0, 0, 5, 0]), 8
+    if name == "every_row_zero":
+        return np.zeros(50), 16
+    if name == "one_row_fills_past_cap":
+        return np.array([0, 0, 70, 0]), 64
+    if name == "cap_no_multiple_of_a_lane":
+        return rng.integers(0, 3, 5000), 3001
+    raise AssertionError(name)
+
+
+@pytest.mark.parametrize("name", [
+    "zero_rows_at_both_ends_total_below_cap",
+    "zero_rows_at_both_ends_total_above_cap", "total_equals_cap",
+    "every_row_zero", "one_row_fills_past_cap", "cap_no_multiple_of_a_lane"])
+def test_expand_slot_map_equals_searchsorted_right(name):
+    """The expand's probe row for every output slot, as `_expand_join`
+    makes it: the slot counts' prefix sum, then `slot_runs`."""
+    counts, out_cap = _slot_case(name)
+    counts = np.asarray(counts, np.int32)
+    offsets = np.cumsum(counts)
+    got = jax.jit(lambda c: slot_runs(prefix_sum(c), out_cap))(counts)
+    want = np.clip(np.searchsorted(offsets, np.arange(out_cap), "right"),
+                   0, counts.shape[0] - 1)
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
+def _searching_expand(monkeypatch):
+    """`_expand_join` as it was: a flat cumsum and the scan search."""
+    monkeypatch.setattr(joins, "prefix_sum", jnp.cumsum)
+    monkeypatch.setattr(joins, "slot_runs", lambda offsets, cap: jnp.clip(
+        jnp.searchsorted(offsets, jnp.arange(cap, dtype=np.int32),
+                         side="right").astype(np.int32),
+        0, offsets.shape[0] - 1))
+
+
+@pytest.mark.parametrize("how", ALL_TYPES)
+def test_expand_equals_the_searching_expand(monkeypatch, lowering_inputs,
+                                            how):
+    """Every output array, its order and its count, against the expand with
+    the search put back, on the probe's own phase-1 arrays."""
+    probe, build, phase1 = lowering_inputs
+    total = int(jnp.sum(_slot_counts(jnp, phase1[0], probe.row_mask(), how)))
+    out_cap = max(total, probe.capacity)
+
+    def expand():
+        out = _expand_join.fn(probe, build, *phase1, (0,), (0,), out_cap,
+                              how, None, False)
+        return [np.asarray(x) for x in jax.tree_util.tree_leaves(out[:3])]
+
+    got = expand()
+    _searching_expand(monkeypatch)
+    want = expand()
+    assert len(got) == len(want)
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(a, b)
 
 
 # ---- seven join types end to end against pandas ----------------------------

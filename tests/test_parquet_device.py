@@ -489,7 +489,7 @@ class TestDecimalTimestampDeviceDecode:
 
 
 def _run_table_cases():
-    """(id, counts, cap) for `_slot_runs`: the run tables a parquet chunk
+    """(id, counts, cap) for `rowops.slot_runs`: the run tables a parquet chunk
     hands the decoder, and the ones it must not trip over."""
     r = np.random.default_rng(30)
     big = r.integers(0, 40, 65536)
@@ -529,10 +529,10 @@ class TestRunOfSlot:
         """`dtype` is the ends' (the host ships int32, ORC's tables too)."""
         import jax
         import jax.numpy as jnp
-        from spark_rapids_tpu.io.parquet_device import _slot_runs
+        from spark_rapids_tpu.ops.rowops import slot_runs
         counts = np.asarray(counts, np.int64)
         want_ends = np.cumsum(counts)
-        run = jax.jit(_slot_runs, static_argnums=1)(
+        run = jax.jit(slot_runs, static_argnums=1)(
             jnp.asarray(want_ends.astype(dtype)), cap)
         want = np.clip(np.searchsorted(want_ends, np.arange(cap),
                                        side="right"), 0, len(counts) - 1)
@@ -549,9 +549,9 @@ class TestRunOfSlot:
                                    (1 << 20) + 3])
     def test_prefix_sum_equals_cumsum(self, n):
         import jax
-        from spark_rapids_tpu.io.parquet_device import _prefix_sum_i32
+        from spark_rapids_tpu.ops.rowops import prefix_sum
         x = np.random.default_rng(n).integers(0, 4, n).astype(np.int32)
-        got = jax.jit(_prefix_sum_i32)(x)
+        got = jax.jit(prefix_sum)(x)
         assert got.dtype == np.int32
         assert np.array_equal(np.asarray(got), np.cumsum(x, dtype=np.int32))
 
