@@ -29,7 +29,7 @@ def main(argv=None) -> int:
     ap.add_argument("-n", type=int, default=40)
     ap.add_argument("--rehearse-rows", type=int, default=0)
     ap.add_argument("--serial-walk", action="store_true",
-                    help="an ORC stripe's columns walked one at a time")
+                    help="a scan's column chunks walked one at a time")
     ap.add_argument("--no-settle", action="store_true",
                     help="without `settle_host_heap` after a compile")
     args = ap.parse_args(argv)
@@ -40,8 +40,8 @@ def main(argv=None) -> int:
         import spark_rapids_tpu
         spark_rapids_tpu.settle_host_heap = lambda: False
     if args.serial_walk:
-        from spark_rapids_tpu.io import orc_device
-        orc_device.walker_pool = lambda: None
+        from spark_rapids_tpu.io import walkers
+        walkers.walker_pool = lambda: None
     _, _, clients = R.deal(env, args.seed)
     session, frames = clients[0]
     name = env["traffic"]["queries"][0]

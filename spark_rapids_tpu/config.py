@@ -481,10 +481,11 @@ register("spark.rapids.tpu.pipeline.enabled", "bool", True,
          "upstream batches at the scan, coalesce-input and result-sink "
          "seams (host-side work overlaps device execution) plus the "
          "fused multi-chunk parquet scan decode and the side-by-side walk "
-         "of an ORC stripe's columns. Off restores the strictly "
-         "serial pre-pipeline paths — zero prefetch threads, one decode "
-         "dispatch group per row-group chunk, one ORC column walked at a "
-         "time on the scan's thread.")
+         "of a parquet dispatch group's column chunks and of an ORC "
+         "stripe's columns. Off restores the strictly serial "
+         "pre-pipeline paths — zero prefetch threads, one decode "
+         "dispatch group per row-group chunk, one column chunk walked at "
+         "a time on the scan's thread.")
 register("spark.rapids.tpu.pipeline.prefetch.depth", "int", 2,
          "Max batches a pipeline prefetch thread may run ahead of its "
          "consumer. Prefetched batches are parked as spillable (budget-"

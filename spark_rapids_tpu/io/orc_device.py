@@ -12,8 +12,8 @@ reloads from the persistent cache:
 
   host (cheap, control-plane), a column at a time under `scan.walk`, the
   stripe's columns side by side on the process's few walkers
-  (`walker_pool`) where execution is pipelined, so the chip is handed the
-  first column as soon as it is walked and waits for no other:
+  (`walkers.walker_pool`) where execution is pipelined, so the chip is
+  handed the first column as soon as it is walked and waits for no other:
     * postscript/footer/stripe-footer via a minimal protobuf wire parser;
     * compressed-stream deframing (3-byte block headers; zlib "deflate"
       blocks via zlib, snappy via pyarrow using the block's own varint
@@ -1429,25 +1429,6 @@ def _column_plan(cs: _ColStreams, kind: int, dt, nrows: int, cap: int,
     if kind in (_K_STRING, _K_VARCHAR, _K_CHAR):
         return plan(*_string_plan(cs, ndef, cap, stats))
     raise DeviceDecodeUnsupported(f"ORC kind {kind}")
-
-
-_WALKERS: Optional[cf.ThreadPoolExecutor] = None
-_WALKERS_LOCK = threading.Lock()
-
-
-def walker_pool() -> cf.ThreadPoolExecutor:
-    """The process's few threads that walk a stripe's columns side by side
-    (`decode_stripe`). They live as long as the process: a thread keeps its
-    allocator arena, so a query's walk finds the buffers the last one's
-    left, where a fresh thread for every query faults its pages in anew
-    (PERF.md, fault 22)."""
-    global _WALKERS
-    with _WALKERS_LOCK:
-        if _WALKERS is None:
-            _WALKERS = cf.ThreadPoolExecutor(
-                max_workers=max(1, min(4, len(os.sched_getaffinity(0)) - 1)),
-                thread_name_prefix="srtpu-orc-walk")
-        return _WALKERS
 
 
 def decode_stripe(info: OrcFileInfo, f, si: int, schema, host_cols=None,

@@ -71,6 +71,7 @@ from .. import types as T
 from ..columnar.padding import LANE, row_bucket
 from ..ops.rowops import ahead, gather_rows, slot_runs
 from ..utils import spans
+from .walkers import walk_all
 
 __all__ = ["DeviceDecodeUnsupported", "columns_supported",
            "decode_row_group", "decode_row_groups_fused",
@@ -940,12 +941,22 @@ def _pow2(n: int) -> int:
     return p
 
 
-def _host_phase(pf, f, rg: int, schema, host_cols=None):
-    """HOST half of a row-group decode: chunk reads, page parsing,
-    decompression and RLE run scans — numpy/bytes only, no device work.
-    Columns prepare SERIALLY: this image runs on a single CPU core, where
-    thread pools and prefetch threads measured as pure context-switch
-    overhead (the C++ walk already minimizes the python-side cost)."""
+def _fileno(f) -> Optional[int]:
+    """`f`'s file descriptor, or None for BytesIO and friends (the cache
+    exec), which seek instead."""
+    try:
+        return f.fileno()
+    except (OSError, ValueError, AttributeError):
+        return None
+
+
+def _chunk_walks(pf, f, rg: int, schema, host_cols=None):
+    """One row group's host phase, cut into its columns -> (row count,
+    [(name, walk)]): `walk()` reads one column chunk, parses its pages,
+    decompresses them and scans their RLE runs into the column's
+    `_ColWork` — numpy/bytes only, no device work. The footer is read here,
+    on the caller; a walk touches only its chunk's bytes, at offsets of its
+    own, so the walks may run side by side."""
     meta = pf.metadata
     pq_schema = meta.schema
     col_index = {pq_schema.column(i).path: i
@@ -961,13 +972,9 @@ def _host_phase(pf, f, rg: int, schema, host_cols=None):
             # file changed on disk since the footer support check
             raise DeviceDecodeUnsupported(f"column {name} missing from file")
         cis[name] = ci
-    try:
-        fd = f.fileno()
-    except (OSError, ValueError, AttributeError):
-        fd = None  # BytesIO and friends (the cache exec) seek instead
+    fd = _fileno(f)
 
-    def read_chunk(ci):
-        cm = rgm.column(ci)
+    def read_chunk(cm):
         start = cm.dictionary_page_offset or cm.data_page_offset
         want = cm.total_compressed_size
         if fd is not None:
@@ -985,11 +992,8 @@ def _host_phase(pf, f, rg: int, schema, host_cols=None):
         f.seek(start)
         return f.read(want)
 
-    def prep(name, dt) -> _ColWork:
-        ci = cis[name]
-        buf = read_chunk(ci)
-        cm = rgm.column(ci)
-        pqcol = pq_schema.column(ci)
+    def prep(name, dt, cm, pqcol) -> _ColWork:
+        buf = read_chunk(cm)
         w = _ColWork()
         w.name, w.dt = name, dt
         w.spec = _column_spec(pqcol, dt)
@@ -1022,8 +1026,46 @@ def _host_phase(pf, f, rg: int, schema, host_cols=None):
         return w
 
     by_name = dict(zip(schema.names, schema.types))
-    works = [prep(nm, by_name[nm]) for nm in dev_names]
-    return {w.name: w for w in works}, nrows
+    return nrows, [(nm, functools.partial(prep, nm, by_name[nm],
+                                          rgm.column(cis[nm]),
+                                          pq_schema.column(cis[nm])))
+                   for nm in dev_names]
+
+
+def _read_chunks(pf, f, rgs, schema, host_cols=None, walkers=None):
+    """HOST phase for a dispatch group: parse every row group's chunks
+    once -> ([(rg, works, nrows)], total rows). On `walkers` (where
+    execution is pipelined) every column chunk of the group is walked at
+    once, so the chip waits for the longest chunk and not for all of them;
+    without, or on a handle with no file descriptor, one after the other
+    on this thread. Either way the products are the same."""
+    with spans.span("scan.walk", kind=spans.KIND_IO):
+        plans = [(rg, *_chunk_walks(pf, f, rg, schema, host_cols))
+                 for rg in rgs]
+        walks = [walk for _, _, ws in plans for _, walk in ws]
+        side_by_side = walkers is not None and _fileno(f) is not None
+        if not side_by_side:
+            works = [walk() for walk in walks]
+    if side_by_side:
+        # each walk charges a `scan.walk` span of its own, outside this one
+        works = walk_all(walkers, walks)
+    done = iter(works)
+    chunks = [(rg, {name: next(done) for name, _ in ws}, nrows)
+              for rg, nrows, ws in plans]
+    return chunks, sum(nrows for _, nrows, _ in plans)
+
+
+def _host_phase(pf, f, rg: int, schema, host_cols=None, walkers=None):
+    """HOST half of a row-group decode -> ({name: _ColWork}, row count):
+    chunk reads, page parsing, decompression and RLE run scans, one row
+    group's `_read_chunks`. Its columns were once walked one after the
+    other because the image it was tuned on had one CPU core, where a
+    thread pool was pure context switching; the chip's host has a dozen,
+    and a walk's read (`os.pread`) and native page walk release the GIL, so
+    on `walkers` the columns are walked side by side."""
+    ((_, works, nrows),), _ = _read_chunks(pf, f, [rg], schema, host_cols,
+                                           walkers)
+    return works, nrows
 
 
 def _device_phase(pf, rg: int, schema, works, nrows: int, host_cols=None,
@@ -1109,8 +1151,7 @@ def decode_row_group(pf, f, rg: int, schema, host_cols=None):
     (pf.read_row_group) — per-row-group granularity keeps the stream lazy
     (one device batch live at a time, the reference's chunked-reader
     discipline) with no double decode."""
-    with spans.span("scan.walk", kind=spans.KIND_IO):
-        works, nrows = _host_phase(pf, f, rg, schema, host_cols)
+    works, nrows = _host_phase(pf, f, rg, schema, host_cols)
     out = _device_phase(pf, rg, schema, works, nrows, host_cols)
     from ..utils.metrics import TaskMetrics
     TaskMetrics.get().scan_chunks += 1
@@ -1986,19 +2027,6 @@ def _fused_multi_program(groups_sig, caps, cap_total: int, full_lead: bool):
                 msgs_box=box)
 
 
-def _read_chunks(pf, f, rgs, schema, host_cols=None):
-    """HOST phase for a dispatch group: parse every row group's chunks
-    once -> ([(rg, works, nrows)], total rows)."""
-    chunks = []
-    total = 0
-    with spans.span("scan.walk", kind=spans.KIND_IO):
-        for rg in rgs:
-            works, nrows = _host_phase(pf, f, rg, schema, host_cols)
-            chunks.append((rg, works, nrows))
-            total += nrows
-    return chunks, total
-
-
 def _group_signatures(chunks, dev_names):
     """Fast-path prep for a whole dispatch group: per-chunk column sigs +
     the single packed transfer buffer. Returns (groups_sig, caps, packed,
@@ -2051,7 +2079,8 @@ def _group_signatures(chunks, dev_names):
     return groups_sig, caps, packed, str_blob_offs
 
 
-def decode_row_groups_fused(pf, f, rgs, schema, host_cols=None, note=None):
+def decode_row_groups_fused(pf, f, rgs, schema, host_cols=None, note=None,
+                            walkers=None):
     """Decode SEVERAL row groups as one dispatch group -> list of
     (device ColumnarBatch, rows). When every device column of every chunk
     takes a fast-path prep (prim/flba ship or the string span-table prep)
@@ -2064,8 +2093,9 @@ def decode_row_groups_fused(pf, f, rgs, schema, host_cols=None, note=None):
     (malformed row groups, host-column read errors) raise
     DeviceDecodeUnsupported for the caller's pyarrow fallback.
     Host-fallback columns decode once via pyarrow's read_row_groups and
-    merge at the total capacity. `note` as `_device_phase` takes it."""
-    chunks, total = _read_chunks(pf, f, rgs, schema, host_cols)
+    merge at the total capacity. `note` as `_device_phase` takes it;
+    `walkers` as `_read_chunks` takes them."""
+    chunks, total = _read_chunks(pf, f, rgs, schema, host_cols, walkers)
     return _decode_chunks_fused(pf, rgs, schema, chunks, total, host_cols,
                                 note)
 
@@ -2609,7 +2639,8 @@ def _pushdown_gather_program(groups_sig, caps, cap_total: int, out_cap: int,
                           dev.key)))
 
 
-def decode_row_groups_pushdown(pf, f, rgs, schema, host_cols, dev):
+def decode_row_groups_pushdown(pf, f, rgs, schema, host_cols, dev,
+                               walkers=None):
     """Pushdown-aware dispatch-group decode. `schema` is the scan's RAW
     column schema; `dev` a plan.scan_pushdown.DevicePushdown. Evaluates
     the pushed predicate on the compressed representation and emits only
@@ -2619,13 +2650,13 @@ def decode_row_groups_pushdown(pf, f, rgs, schema, host_cols, dev):
     never a silently different result. Returns a list of
     (batch, out_rows, in_rows, rows_kept, bytes_materialized); malformed
     groups raise DeviceDecodeUnsupported for the caller's per-row-group
-    net."""
+    net. `walkers` as `_read_chunks` takes them."""
     import jax
     import jax.numpy as jnp
     from ..columnar.batch import ColumnarBatch
     from ..columnar.column import Column
     from ..utils.metrics import TaskMetrics
-    chunks, total = _read_chunks(pf, f, rgs, schema, host_cols)
+    chunks, total = _read_chunks(pf, f, rgs, schema, host_cols, walkers)
     host_set = set(host_cols or ())
     dev_names = [n for n in schema.names if n not in host_set]
     sig = None

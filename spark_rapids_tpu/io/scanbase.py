@@ -486,8 +486,8 @@ class TpuFileScanExec(_TpuExec):
         from ..columnar.batch import batch_from_arrow
         from ..utils.metrics import TaskMetrics
         from .orc_device import (DeviceDecodeUnsupported, _ScanStats,
-                                 columns_supported, decode_stripe,
-                                 walker_pool)
+                                 columns_supported, decode_stripe)
+        from .walkers import walker_pool
         scan = self.cpu_scan
         tm = TaskMetrics.get()
         # pipelined: a stripe's columns are walked side by side, ahead of
@@ -696,17 +696,20 @@ class TpuFileScanExec(_TpuExec):
         O(1) dispatches per scan batch); a group the fast path declines,
         and pipeline-off entirely, take the per-row-group path. Host- or
         device-phase surprises fall just that row group back to pyarrow —
-        the same narrow net as before."""
+        the same narrow net as before. Pipelined, every path walks its
+        column chunks side by side on the process's walkers; off, one
+        after the other on this thread."""
         from ..columnar.batch import batch_from_arrow
-        from ..utils import spans
         from ..utils.metrics import TaskMetrics
         from .parquet_device import (DeviceDecodeUnsupported, _device_phase,
                                      _host_phase, decode_row_groups_fused)
+        from .walkers import walker_pool
         tm = TaskMetrics.get()
-        group = 1
+        group, walkers = 1, None
         if self.conf.get("spark.rapids.tpu.pipeline.enabled"):
             group = max(self.conf.get(
                 "spark.rapids.tpu.pipeline.scan.chunksPerDispatch"), 1)
+            walkers = walker_pool()
 
         def host_fallback(rg):
             t = scan._postprocess(pf.read_row_group(rg,
@@ -730,7 +733,8 @@ class TpuFileScanExec(_TpuExec):
                     from .parquet_device import decode_row_groups_pushdown
                     try:
                         outs = decode_row_groups_pushdown(
-                            pf, f, chunk_rgs, scan.output, host_cols, dev)
+                            pf, f, chunk_rgs, scan.output, host_cols, dev,
+                            walkers)
                     except (DeviceDecodeUnsupported, OSError,
                             struct_error):
                         pass  # per-row-group decode below
@@ -745,7 +749,7 @@ class TpuFileScanExec(_TpuExec):
                     try:
                         outs = decode_row_groups_fused(
                             pf, f, chunk_rgs, scan.output, host_cols,
-                            self._note_decode)
+                            self._note_decode, walkers)
                     except (DeviceDecodeUnsupported, OSError,
                             struct_error):
                         pass  # per-row-group decode below
@@ -757,9 +761,8 @@ class TpuFileScanExec(_TpuExec):
                         continue
                 for rg in chunk_rgs:
                     try:
-                        with spans.span("scan.walk", kind=spans.KIND_IO):
-                            works, nrows = _host_phase(
-                                pf, f, rg, scan.output, host_cols)
+                        works, nrows = _host_phase(
+                            pf, f, rg, scan.output, host_cols, walkers)
                         b, nrows = _device_phase(pf, rg, scan.output,
                                                  works, nrows, host_cols,
                                                  self._note_decode)

@@ -297,7 +297,7 @@ def test_the_columns_are_walked_side_by_side_where_execution_is_pipelined(
     session = TpuSession({"spark.rapids.tpu.pipeline.enabled": pipelined})
     got = q.scans(session, paths)["lineitem"].collect()
     assert len(where) == 7
-    assert all(n.startswith("srtpu-orc-walk") == pipelined for n in where)
+    assert all(n.startswith("srtpu-walk") == pipelined for n in where)
     assert TaskMetrics.get().scan_host_decoded == 0
     want = orc.read_table(paths["lineitem"], columns=got.column_names)
     assert got.cast(want.schema).equals(want)

@@ -17,6 +17,7 @@ from spark_rapids_tpu.columnar.batch import ColumnarBatch
 from spark_rapids_tpu.config import _REGISTRY
 from spark_rapids_tpu.expr import Sum, col, lit
 from spark_rapids_tpu.io import orc_device, parquet_device
+from spark_rapids_tpu.io.walkers import walker_pool
 from spark_rapids_tpu.plugin import TpuSession
 from spark_rapids_tpu.utils import spans
 from spark_rapids_tpu.utils.metrics import TaskMetrics
@@ -149,13 +150,13 @@ def test_a_prefetch_producers_walk_charges_the_consumers_query(
     pq.write_table(pa.table({"a": np.arange(3000, dtype=np.int64)}),
                    str(tmp_path / "one.parquet"))
     walkers = []
-    real = parquet_device._host_phase
+    real = parquet_device._chunk_walks
 
     def slow(*a, **kw):
         walkers.append(threading.current_thread().name)
         time.sleep(SLEEP_NS / 1e9)
         return real(*a, **kw)
-    monkeypatch.setattr(parquet_device, "_host_phase", slow)
+    monkeypatch.setattr(parquet_device, "_chunk_walks", slow)
     got = session.read_parquet(str(tmp_path / "one.parquet")).collect()
     assert got.num_rows == 3000
     assert walkers and all(w.startswith("srtpu-scan-") for w in walkers)
@@ -193,14 +194,14 @@ def test_the_orc_walkers_charge_the_query_that_ran_them(session, orc_file,
         rec, tm = _last_record(), TaskMetrics.get()
         assert rec["scan_host_decoded"] == 0 and rec["scan_walk_ns"] > 0
         assert len(seen) == 3
-        assert all(name.startswith("srtpu-orc-walk") and owner is tm
+        assert all(name.startswith("srtpu-walk") and owner is tm
                    for name, owner in seen)
         queries.append((rec, tm))
     (rec_a, tm_a), (rec_b, tm_b) = queries
     assert tm_a is not tm_b
     # nothing of the second query's walks reached the first's counters
     assert tm_a.scan_walk_ns == rec_a["scan_walk_ns"]
-    pool = orc_device.walker_pool()
+    pool = walker_pool()
     n = pool._max_workers
     gate = threading.Barrier(n)
 
